@@ -1,0 +1,346 @@
+#!/usr/bin/env python3
+"""The quickest proof that the system still starts on the chip.
+
+One process, three phases, through the entry points a user calls:
+
+1. ``kernel``: the fused Pallas LayerNorm-GRU step, compiled, at the two row
+   counts the S train program sends it (16 in the dynamic scan, 1024 in
+   imagination), value and gradient against ``ln_gru_step_reference``.
+2. ``train``: ``sheeprl_tpu.cli.run`` on Dreamer-V3 at the S preset the repo
+   ships (``exp=dreamer_v3_100k_ms_pacman``: dense 512, recurrent 512, 32x32
+   latents, CNN multiplier 32, batch 16 x sequence 64, horizon 15, 64x64x3
+   uint8 frames, float32) with the seeded pixel dummy env in place of ALE,
+   which is not installed. Nothing about the model or the batch is cut; only
+   the prefill, the run length, the replay capacity and logging are shortened,
+   so the run prefills, compiles and takes ``GRAD_STEPS`` gradient steps.
+3. ``serve``: ``sheeprl_tpu.cli.serve`` answers ``SESSIONS`` sessions from the
+   checkpoint that run wrote, through the donated slot-step program.
+
+Every check reads what the run itself recorded (its telemetry stream, its
+checkpoint) or what JAX reports; a failed check raises, so any failed phase is a
+non-zero exit. ``python chip_smoke.py`` takes no argument and demands the chip.
+The phases are functions of their overrides and the expected platform so that
+tier-1 (tests/test_chip_smoke.py) runs the same code at tiny widths with
+``platform="cpu"``. Nothing here is a benchmark: compile seconds and the like
+are printed as information about the start-up, not as metrics.
+
+The last line of stdout is one JSON object with exactly two keys,
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``; the
+full result is the ``[chip-smoke] result:`` line before it and ``result.json``.
+"""
+
+from __future__ import annotations
+
+import glob
+import importlib.metadata
+import json
+import os
+import shutil
+import sys
+import time
+from typing import Any, Dict, Sequence
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+# run directories, the checkpoint and the lowered programs: large, stay on the machine
+WORK_DIR = os.path.join(REPO, "chip_smoke_out")
+# what is worth bringing back from the chip: the result and the two telemetry streams
+REPORT_DIR = os.path.join(REPO, "chiprun_out", "chip_smoke")
+
+GRAD_STEPS = 16
+LEARNING_STARTS = 128  # >= the 64-step sequences the S batch samples
+SESSIONS = 4
+
+S_TRAIN_OVERRIDES = [
+    "exp=dreamer_v3_100k_ms_pacman",
+    # ALE is not installed: the seeded pixel dummy env, rgb keys only
+    "env=dummy",
+    "env.id=discrete_dummy",
+    "env.capture_video=False",
+    "algo.cnn_keys.encoder=[rgb]",
+    "algo.cnn_keys.decoder=[rgb]",
+    "algo.mlp_keys.encoder=[]",
+    "algo.mlp_keys.decoder=[]",
+    "fabric.accelerator=tpu",
+    "fabric.devices=1",
+    # the only things shortened: prefill, run length, replay capacity, logging
+    f"algo.learning_starts={LEARNING_STARTS}",
+    f"algo.total_steps={LEARNING_STARTS + GRAD_STEPS - 1}",
+    "buffer.size=4096",
+    "metric.log_level=0",
+]
+S_SERVE_OVERRIDES = [
+    "serve.slots=4",
+    f"serve.sessions={SESSIONS}",
+    "serve.max_session_steps=64",
+]
+
+
+def _check(cond: bool, message: str) -> None:
+    if not cond:
+        raise AssertionError(message)
+
+
+def _one(events: Sequence[Dict[str, Any]], kind: str) -> Dict[str, Any]:
+    found = [e for e in events if e["event"] == kind]
+    _check(len(found) == 1, f"expected one {kind!r} event, found {len(found)}")
+    return found[0]
+
+
+def device_report(platform: str) -> Dict[str, Any]:
+    """What JAX found, checked against what was asked for. Raises before any
+    phase runs when the default device is not ``platform``."""
+    import jax
+    import jaxlib
+
+    from sheeprl_tpu.utils.compile_cache import enable_compile_cache
+
+    devices = jax.devices()
+    found = {"platform": devices[0].platform, "kind": devices[0].device_kind, "count": len(devices)}
+    _check(
+        found["platform"] == platform,
+        f"chip_smoke needs platform {platform!r}; JAX found {found} "
+        f"(JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r})",
+    )
+    try:
+        libtpu = importlib.metadata.version("libtpu")
+    except importlib.metadata.PackageNotFoundError:  # a CPU-only install has none to name
+        libtpu = None
+    report = {
+        **found,
+        "jax": jax.__version__,
+        "jaxlib": jaxlib.__version__,
+        "libtpu": libtpu,
+        "cache_dir": enable_compile_cache(),
+    }
+    print(
+        f"[chip-smoke] platform={report['platform']} device_kind={report['kind']} "
+        f"count={report['count']} jax={report['jax']} jaxlib={report['jaxlib']} "
+        f"libtpu={report['libtpu']} cache_dir={report['cache_dir']}",
+        flush=True,
+    )
+    return report
+
+
+def kernel_phase(platform: str, rows: Sequence[int] = (16, 1024), K: int = 1024, H: int = 512) -> Dict[str, Any]:
+    """The fused GRU step against the XLA reference, value and gradient, at the
+    S cell (``[rows, K] x [K, 3H]``). Compiled by Mosaic on a TPU; the Pallas
+    interpreter elsewhere. The kernel's dot is one bf16 pass (``DEFAULT``), so
+    the reference is taken at the same precision."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from sheeprl_tpu.ops.gru import fused_ln_gru_step, ln_gru_step_reference, pallas_gru_applicable
+
+    _check(pallas_gru_applicable(K, H), f"the S cell K={K} H={H} must take the fused kernel")
+    interpret = platform != "tpu"
+    out: Dict[str, Any] = {"compiled": not interpret, "rows": {}}
+    for B in rows:
+        ks = jax.random.split(jax.random.PRNGKey(B), 6)
+        args = (
+            jax.random.normal(ks[0], (B, K)),
+            jax.random.normal(ks[1], (B, H)),
+            jax.random.normal(ks[2], (K, 3 * H)) / np.sqrt(K),
+            0.1 * jax.random.normal(ks[3], (3 * H,)),
+            1.0 + 0.1 * jax.random.normal(ks[4], (3 * H,)),
+            0.1 * jax.random.normal(ks[5], (3 * H,)),
+        )
+
+        def fused_loss(*a):
+            return jnp.sum(jnp.square(fused_ln_gru_step(*a, interpret=interpret)))
+
+        def reference_loss(*a):
+            return jnp.sum(jnp.square(ln_gru_step_reference(*a)))
+
+        with jax.default_matmul_precision("default"):
+            value = jax.jit(lambda *a: fused_ln_gru_step(*a, interpret=interpret))(*args)
+            reference = jax.jit(ln_gru_step_reference)(*args)
+            grads = jax.jit(jax.grad(fused_loss, argnums=tuple(range(6))))(*args)
+            ref_grads = jax.jit(jax.grad(reference_loss, argnums=tuple(range(6))))(*args)
+        _check(value.shape == (B, H) and value.devices() == {jax.devices()[0]}, "kernel output misplaced")
+        value_err = float(jnp.max(jnp.abs(value - reference)))
+        grad_err = max(
+            float(jnp.max(jnp.abs(g - r)) / (jnp.max(jnp.abs(r)) + 1e-9)) for g, r in zip(grads, ref_grads)
+        )
+        # h' is a convex mix of tanh and h: O(1) values, so 1e-4 absolute is
+        # float32 reduction-order noise, far below one bf16 ulp of a wrong branch
+        _check(value_err < 1e-4, f"fused GRU value off the reference at rows={B}: {value_err:.3e}")
+        _check(grad_err < 1e-3, f"fused GRU gradient off the reference at rows={B}: {grad_err:.3e}")
+        out["rows"][str(B)] = {"value_max_abs_err": value_err, "grad_max_rel_err": grad_err}
+    print(f"[chip-smoke] kernel: {json.dumps(out)}", flush=True)
+    return out
+
+
+def train_phase(overrides: Sequence[str], *, platform: str, grad_steps: int, out_dir: str) -> Dict[str, Any]:
+    """``cli.run`` into ``out_dir`` with the telemetry stream on, then read the
+    run back: where it ran, how many gradient steps it took, whether every loss
+    of the last step is finite, what it compiled."""
+    import jax
+    import numpy as np
+
+    from sheeprl_tpu.cli import run
+    from sheeprl_tpu.obs.jsonl import read_events
+
+    run_dir = os.path.join(out_dir, "train")
+    ir_dir = os.path.join(out_dir, "ir")
+    # the StableHLO of every program the run lowers, to name the GRU branch the
+    # train program was compiled with (platform_dependent picks it at lowering)
+    jax.config.update("jax_dump_ir_to", ir_dir)
+    t0 = time.perf_counter()
+    try:
+        run(
+            list(overrides)
+            + [
+                f"hydra.run.dir={run_dir}",
+                "metric.telemetry.enabled=true",
+                # one window per policy step: the last window is the last gradient step
+                "metric.telemetry.every=1",
+            ]
+        )
+    finally:
+        jax.config.update("jax_dump_ir_to", None)
+    wall = time.perf_counter() - t0
+
+    (stream,) = glob.glob(os.path.join(run_dir, "version_*", "telemetry.jsonl"))
+    events = read_events(stream)
+    start, summary = _one(events, "start"), _one(events, "summary")
+    _check(
+        start["platform"] == platform,
+        f"the train state was built on {start['platform']!r} ({start['device_kind']}), not {platform!r}",
+    )
+    _check(summary["clean_exit"] is True, "the training run did not exit cleanly")
+    _check(
+        summary["train_units"] == grad_steps,
+        f"asked for {grad_steps} gradient steps, the run took {summary['train_units']}",
+    )
+    trained = [e for e in events if e["event"] == "window" and e["train_units"] > 0]
+    losses = {k: v for k, v in trained[-1]["learning"]["stats"].items() if k.startswith("loss/")}
+    _check(len(losses) >= 3, f"expected world-model, actor and critic losses, got {sorted(losses)}")
+    _check(all(np.isfinite(v) for v in losses.values()), f"non-finite loss in the last step: {losses}")
+    # the run's own non-finite-loss guard, as of its last window
+    _check(summary["health"] == "ok", f"the run's loss guard ended on {summary['health']!r}")
+
+    program = _one(events, "program")
+    _check("error" not in program, f"program analysis failed: {program.get('error')}")
+    hbm = trained[-1].get("hbm")
+    if platform != "cpu":
+        # the allocator's word for it: between steps EVERY mesh device holds at
+        # least the donated train state (params, optimizer state, moments)
+        state_bytes = int(program["memory"]["alias_bytes"])
+        per_device = (hbm or {}).get("per_device") or [hbm]
+        _check(
+            state_bytes > 0 and all(d and d["bytes_in_use"] >= state_bytes for d in per_device),
+            f"device memory in use {hbm} does not cover the {state_bytes} B train state on every device",
+        )
+
+    ir_files = glob.glob(os.path.join(ir_dir, "*jit_train_step*compile*"))
+    _check(len(ir_files) >= 1, f"no lowered train_step program under {ir_dir}")
+    with open(max(ir_files, key=os.path.getsize)) as fh:
+        gru_branch = "pallas (tpu_custom_call)" if "tpu_custom_call" in fh.read() else "xla"
+
+    (checkpoint,) = glob.glob(os.path.join(run_dir, "version_*", "checkpoint", "*.ckpt"))
+    result = {
+        "telemetry": stream,
+        "checkpoint": checkpoint,
+        "device_kind": start["device_kind"],
+        "grad_steps": summary["train_units"],
+        "first_step_world_model_loss": trained[0]["learning"]["stats"]["loss/world_model"],
+        "last_step_losses": losses,
+        "gru_branch": gru_branch,
+        "hbm_bytes_in_use": (hbm or {}).get("bytes_in_use"),
+        "train_step_flops": program.get("flops"),
+        # information about the start-up, not metrics
+        "compile": summary["compile"],
+        "train_step_analysis_compile_seconds": program.get("compile_seconds"),
+        "wall_seconds": round(wall, 1),
+    }
+    print(f"[chip-smoke] train: {json.dumps(result)}", flush=True)
+    return result
+
+
+def serve_phase(checkpoint: str, overrides: Sequence[str], *, platform: str, sessions: int, out_dir: str) -> Dict[str, Any]:
+    """``cli.serve`` from ``checkpoint`` (whose config.yaml names the model and
+    the accelerator), then read the serving stream back."""
+    from sheeprl_tpu.cli import serve
+    from sheeprl_tpu.obs.jsonl import read_events
+
+    serve_dir = os.path.join(out_dir, "serve")
+    t0 = time.perf_counter()
+    rc = serve([f"checkpoint_path={checkpoint}", f"serve.log_dir={serve_dir}", *overrides])
+    wall = time.perf_counter() - t0
+    _check(rc == 0, f"serve exited {rc}: a session failed or the server crashed")
+
+    stream = os.path.join(serve_dir, "telemetry.jsonl")
+    events = read_events(stream)
+    start, summary = _one(events, "start"), _one(events, "summary")
+    _check(
+        start["platform"] == platform,
+        f"the slot table was built on {start['platform']!r} ({start['device_kind']}), not {platform!r}",
+    )
+    _check(summary["clean_exit"] is True, "the server did not close cleanly")
+    served = summary["serve"]
+    _check(
+        served["sessions_started"] == served["sessions_finished"] == sessions,
+        f"asked for {sessions} sessions: {served['sessions_started']} started, "
+        f"{served['sessions_finished']} finished",
+    )
+    _check(
+        served["sessions_shed"] == served["sessions_drained"] == served["deadline_missed"] == 0,
+        f"sessions were lost: {served}",
+    )
+    _check(summary["total_steps"] > 0 and served["state_bytes"] > 0, "the slot table served nothing")
+    if platform != "cpu":
+        _check(
+            (summary.get("hbm_peak_bytes") or 0) >= served["state_bytes"],
+            f"device memory peak {summary.get('hbm_peak_bytes')} does not cover the slot table",
+        )
+    result = {
+        "telemetry": stream,
+        "device_kind": start["device_kind"],
+        "sessions_finished": served["sessions_finished"],
+        "sessions_failed": served["sessions_started"] - served["sessions_finished"],
+        "steps": summary["total_steps"],
+        "slot_table_bytes": served["state_bytes"],
+        "compile": summary["compile"],
+        "wall_seconds": round(wall, 1),
+    }
+    print(f"[chip-smoke] serve: {json.dumps(result)}", flush=True)
+    return result
+
+
+def main() -> int:
+    for fresh in (WORK_DIR, REPORT_DIR):  # no earlier run is read
+        shutil.rmtree(fresh, ignore_errors=True)
+        os.makedirs(fresh)
+    device = device_report("tpu")
+    kernel = kernel_phase("tpu")
+    train = train_phase(S_TRAIN_OVERRIDES, platform="tpu", grad_steps=GRAD_STEPS, out_dir=WORK_DIR)
+    _check(
+        train["gru_branch"].startswith("pallas"),
+        f"the S train program on one chip should carry the fused GRU, found {train['gru_branch']}",
+    )
+    served = serve_phase(
+        train["checkpoint"], S_SERVE_OVERRIDES, platform="tpu", sessions=SESSIONS, out_dir=WORK_DIR
+    )
+    verdict = {"ok": True, "device": {k: device[k] for k in ("platform", "kind", "count")}}
+    result = {
+        **verdict,
+        "versions": {k: device[k] for k in ("jax", "jaxlib", "libtpu")},
+        "cache_dir": device["cache_dir"],
+        "kernel": kernel,
+        "train": train,
+        "serve": served,
+        "claim": None,
+    }
+    for name, stream in (("train", train["telemetry"]), ("serve", served["telemetry"])):
+        shutil.copy(stream, os.path.join(REPORT_DIR, f"{name}.telemetry.jsonl"))
+    with open(os.path.join(REPORT_DIR, "result.json"), "w") as fh:
+        json.dump(result, fh, indent=1)
+    print(f"[chip-smoke] result: {json.dumps(result)}", flush=True)
+    # the contract's last line: these two keys and no other
+    print(json.dumps(verdict), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -9,6 +9,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from sheeprl_tpu.utils.utils import host_cpu_device
+
 AGGREGATOR_KEYS = {
     "Rewards/rew_avg",
     "Game/ep_len_avg",
@@ -24,7 +26,7 @@ def prepare_obs(
 ) -> jax.Array:
     """Concatenate the mlp-key observations into one flat float array
     [num_envs, obs_dim] (reference utils.py:prepare_obs)."""
-    with jax.default_device(jax.local_devices(backend="cpu")[0]):
+    with jax.default_device(host_cpu_device()):
         return jnp.concatenate(
             [np.asarray(obs[k], dtype=np.float32).reshape(num_envs, -1) for k in mlp_keys], axis=-1
         )

@@ -1,8 +1,7 @@
 """JAX-aware static analysis + AOT program-contract gate (``sheeprl.py lint``).
 
 Every hazard class this framework has hit shipped first and was caught later by
-a one-off fix: the ``platform_dependent`` TPU branch that lowered on CPU (PR 1),
-``jax.devices()`` handing a non-rank-0 actor another process's device (PR 10),
+a one-off fix: ``jax.devices()`` handing a non-rank-0 actor another process's device (PR 10),
 the Pallas GRU inheriting an unsupported Mosaic dot precision (PR 10), donation
 silently disabled by ``np.asarray`` host views (PR 1), and telemetry events
 emitted outside the schema registry (PR 11). This package turns each of those

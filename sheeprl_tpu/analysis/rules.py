@@ -156,48 +156,6 @@ class JaxDevicesRule(Rule):
                     )
 
 
-class PlatformDependentGateRule(Rule):
-    """``lax.platform_dependent(tpu=...)`` branches must be built only under a
-    ``jax.default_backend()`` gate.
-
-    ``platform_dependent`` lowers EVERY branch for every requested platform —
-    a Pallas TPU kernel in the ``tpu=`` branch refuses to lower for CPU, so an
-    ungated dispatch traces fine on a TPU process and explodes on any CPU
-    process (the PR 1 seed failure: every dreamer-family CPU test red)."""
-
-    name = "platform-dependent-ungated"
-    severity = "critical"
-    doc = "platform_dependent TPU branch without a jax.default_backend() gate"
-
-    def run(self, package) -> Iterator[Finding]:
-        for module in package.modules:
-            if "platform_dependent" not in module.source:
-                continue
-            _set_parents(module.tree)
-            for node in ast.walk(module.tree):
-                if not (isinstance(node, ast.Call) and (dotted_name(node.func) or "").endswith("platform_dependent")):
-                    continue
-                if not any(kw.arg == "tpu" for kw in node.keywords):
-                    continue  # cpu=/default= fast-path gates lower everywhere
-                scopes: Sequence[ast.AST] = _enclosing_functions(node) or [module.tree]
-                gate_scope = scopes[-1]  # outermost function (or the module)
-                gated = any(
-                    isinstance(n, ast.Call)
-                    and (dotted_name(n.func) or "").endswith("default_backend")
-                    for n in ast.walk(gate_scope)
-                )
-                if not gated:
-                    yield self.finding(
-                        module,
-                        node,
-                        "platform_dependent(tpu=...) built without a "
-                        "jax.default_backend() gate — the TPU branch lowers (and "
-                        "fails) on every CPU process",
-                        'guard the dispatch with `jax.default_backend() == "tpu"` '
-                        "(see models.py LayerNormGRUCell for the pattern)",
-                    )
-
-
 class PallasDotPrecisionRule(Rule):
     """Pallas kernel ``dot``s must pin an explicit ``precision=``.
 
@@ -873,7 +831,6 @@ class CfgKeyResolvesRule(Rule):
 def default_rules() -> List[Rule]:
     return [
         JaxDevicesRule(),
-        PlatformDependentGateRule(),
         PallasDotPrecisionRule(),
         AsarrayDonationRule(),
         HostSyncInJitRule(),

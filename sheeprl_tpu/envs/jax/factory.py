@@ -25,6 +25,7 @@ from sheeprl_tpu.envs.jax.base import JaxEnv
 from sheeprl_tpu.envs.jax.classic import CartPole, Pendulum
 from sheeprl_tpu.envs.jax.gridworld import GridWorld
 from sheeprl_tpu.envs.jax.wrappers import AutoReset, VmapEnv
+from sheeprl_tpu.utils.utils import host_cpu_device
 
 # id -> (constructor, default max_episode_steps — gymnasium's registered
 # TimeLimit for the classics, a 4*N*N step budget for gridworlds)
@@ -85,9 +86,8 @@ class JaxToGymEnv(gym.Env):
         # pin the step/reset programs to the host CPU backend by committing the
         # PRNG chain there: committed inputs drive jit placement, and the env
         # state stays committed across steps (jit's deprecated backend= kwarg
-        # is avoided — the ActPlacement device-split reasoning applies: a
-        # per-step dispatch to an accelerator dwarfs a classic-control step)
-        self._cpu = jax.local_devices(backend="cpu")[0]
+        # is avoided). Same placement as the act program it feeds (ActPlacement).
+        self._cpu = host_cpu_device()
         self._reset_fn = jax.jit(self._env.reset)
         self._step_fn = jax.jit(self._env.step)
         self._key = jax.device_put(jax.random.PRNGKey(seed), self._cpu)

@@ -4,8 +4,8 @@ Generalizes the PR 8 restart-policy supervisors: every member runs under its own
 :class:`~sheeprl_tpu.resilience.restart_policy.RestartPolicy` (crash → resume
 from the newest valid checkpoint INSIDE the member's dir — never a sibling's),
 attempts are ``python -m sheeprl_tpu`` children with the member's overrides and
-a pinned ``hydra.run.dir``, and the whole sweep shares ONE persistent XLA
-compile cache: the first member (run alone when ``stagger_first``) compiles,
+a pinned ``hydra.run.dir``, and the whole sweep shares the ONE persistent XLA
+compile cache of the checkout (``utils/compile_cache.py``): the first member (run alone when ``stagger_first``) compiles,
 every later member cold-starts as pure cache hits — measured, not assumed, via
 the telemetry compile gauges (``compile.cold`` in ``leaderboard.json``).
 
@@ -14,7 +14,6 @@ Fleet layout::
     <fleet dir>/
       fleet.json               # the marker discovery/watch/diagnose key on
       telemetry.fleet.jsonl    # the runner's own event stream (spawn/exit/restart)
-      xla_cache/               # the shared persistent compile cache
       members/<name>/          # one pinned hydra.run.dir per member
         telemetry.jsonl        #   one stream across that member's attempts
         attempt<K>.log         #   per-attempt child stdout/stderr
@@ -40,6 +39,7 @@ from typing import Any, Dict, List, Optional
 
 from sheeprl_tpu.fleet import spec as fleet_spec
 from sheeprl_tpu.fleet.rollup import build_leaderboard, format_leaderboard
+from sheeprl_tpu.utils.compile_cache import cache_dir
 
 __all__ = ["run_fleet", "main"]
 
@@ -48,7 +48,7 @@ def _member_dir(fleet_dir: str, name: str) -> str:
     return os.path.join(fleet_dir, "members", name)
 
 
-def _build_member_env(fleet_dir: str, spec: Dict[str, Any]) -> Dict[str, str]:
+def _build_member_env(spec: Dict[str, Any]) -> Dict[str, str]:
     env = dict(os.environ)
     # the package must be importable from any cwd the member inherits
     import sheeprl_tpu
@@ -56,10 +56,12 @@ def _build_member_env(fleet_dir: str, spec: Dict[str, Any]) -> Dict[str, str]:
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(sheeprl_tpu.__file__)))
     env["PYTHONPATH"] = repo_root + os.pathsep + env.get("PYTHONPATH", "")
     if spec.get("compile_cache", True):
-        # the sweep's shared persistent cache — and a 0s persistence threshold,
-        # so even sub-second CPU programs land in it and later members cold-start
-        # as pure cache hits (utils/compile_cache.py honors the env override)
-        env.setdefault("SHEEPRL_JAX_CACHE", os.path.join(fleet_dir, "xla_cache"))
+        # members inherit the ONE persistent cache every entry point uses
+        # (utils/compile_cache.py: JAX_COMPILATION_CACHE_DIR or the in-checkout
+        # default — never a per-fleet directory, whose time-stamped path would
+        # start every sweep cold). A 0s persistence threshold makes even
+        # sub-second CPU programs land in it, so later members cold-start as
+        # pure cache hits.
         env.setdefault("SHEEPRL_JAX_CACHE_MIN_COMPILE_SECS", "0")
     for key, value in (spec.get("env") or {}).items():
         if value is None:
@@ -88,7 +90,7 @@ def run_fleet(
     fleet_dir = os.path.abspath(fleet_dir)
     os.makedirs(fleet_dir, exist_ok=True)
     fleet_spec.write_marker(fleet_dir, spec)
-    member_env = _build_member_env(fleet_dir, spec)
+    member_env = _build_member_env(spec)
     parallel = max(int(max_parallel or spec["max_parallel"]), 1)
 
     sink = JsonlEventSink(os.path.join(fleet_dir, "telemetry.fleet.jsonl"))
@@ -115,7 +117,7 @@ def run_fleet(
 
         endpoint = build_endpoint(http_cfg, labels={"fleet": str(spec["name"])})
     board_lock = threading.Lock()
-    # members_* gauges count TERMINAL member outcomes only — the same taxonomy
+    # members_* gauges count TERMINAL member outcomes only — the same classes
     # leaderboard.json records — while attempts/restarts count per-attempt
     # events (a restarted member is one member, several attempts)
     board = {
@@ -172,7 +174,7 @@ def run_fleet(
         name=spec["name"],
         members=[m["name"] for m in spec["members"]],
         max_parallel=parallel,
-        compile_cache=member_env.get("SHEEPRL_JAX_CACHE") if spec["compile_cache"] else None,
+        compile_cache=cache_dir(member_env) if spec["compile_cache"] else None,
     )
 
     # code-health fingerprint for the whole sweep: one `lint --json` at startup

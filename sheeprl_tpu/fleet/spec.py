@@ -44,7 +44,7 @@ from typing import Any, Dict, List
 FLEET_MARKER = "fleet.json"
 
 _NAME_RE = re.compile(r"^[A-Za-z0-9][A-Za-z0-9._-]*$")
-_RESERVED = {"members", "xla_cache", "gang", "checkpoint"}
+_RESERVED = {"members", "gang", "checkpoint"}
 
 _SEVERITIES = (None, "warning", "critical")
 

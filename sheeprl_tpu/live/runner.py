@@ -636,7 +636,7 @@ def live_main(args: Optional[Sequence[str]] = None) -> int:
 
 
 def _verdict(info: Optional[Dict[str, Any]]) -> int:
-    """Map the final attempt's outcome onto the live exit-code taxonomy."""
+    """Map the final attempt's outcome onto the live exit codes."""
     from sheeprl_tpu.resilience.signals import PREEMPTED_EXIT_CODE
 
     if info is None:

@@ -232,28 +232,17 @@ class LayerNormGRUCell(nn.Module):
                 "ln_bias", nn.initializers.zeros_init(), (3 * self.hidden_size,), jnp.float32
             )
             # the fused Pallas step (matmul + layernorm + gating in one VMEM pass)
-            # applies when lowering for TPU with the weight block VMEM-resident; any
-            # other lowering platform (e.g. the CPU-pinned act path of a TPU run)
-            # takes the XLA path — same math, parity-tested in tests/test_ops.
-            # The platform_dependent branch is built only when the PROCESS backend is
-            # TPU: lax.cond lowers every branch regardless of the selected platform,
-            # so on a CPU-only process the Pallas branch would fail to lower ("Only
-            # interpret mode is supported on CPU backend") even though it can never
-            # be taken. Known limitation (pre-existing, unchanged by this gate): in a
-            # TPU process a jit pinned to backend="cpu" (the ActPlacement act path)
-            # still lowers the Pallas branch for CPU and hits the same error — run
-            # such programs with SHEEPRL_DISABLE_PALLAS=1 until the dispatch keys on
-            # the lowering platform instead of the process backend.
-            import os
-
+            # is the branch a TPU lowering takes when the shape is one the kernel
+            # compiles for; platform_dependent chooses by LOWERING platform, so
+            # the CPU-placed act program of a TPU process (ActPlacement) lowers
+            # the XLA reference and never sees Mosaic — same math, parity-tested
+            # in tests/test_ops.
             from sheeprl_tpu import ops
 
             hx_d = hx.astype(self.dtype)
             if (
                 inp.ndim == 2
                 and ops.pallas_gru_applicable(inp.shape[-1], self.hidden_size)
-                and os.environ.get("SHEEPRL_DISABLE_PALLAS", "0") != "1"
-                and jax.default_backend() == "tpu"
                 # Pallas kernels don't partition: a multi-device mesh (dp or
                 # model-sharded GRU kernel) must take the XLA path
                 and not ops.partitioned_mesh_active()

@@ -8,10 +8,10 @@ struct, and :func:`compile_snapshot` reads it. :class:`RunTelemetry` diffs
 snapshots per log window to drive the ``Compile/count`` / ``Compile/seconds``
 gauges and the unexpected-recompile warning.
 
-On remote TPU backends a compile is minutes, not milliseconds (TPU_PROBE_LOG.md:
->9 min cold for the Dreamer-V3 train program), so an unnoticed steady-state
-recompile loop is the single most expensive silent failure this repo has; this
-counter is what makes it visible.
+A cold compile of a fused train program is tens of seconds to minutes
+(CHANGES.md, PR 21, has the Dreamer-V3 S figure on a v5e), so an unnoticed
+steady-state recompile loop is the single most expensive silent failure this
+repo has; this counter is what makes it visible.
 """
 
 from __future__ import annotations

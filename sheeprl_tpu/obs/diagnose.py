@@ -232,7 +232,7 @@ def detect_recompile_storm(events: Events) -> List[Finding]:
             affected,
             "hunt for shape churn (varying per-round gradient-step counts, env batch "
             "drift); pin shapes, or pre-warm with sheeprl-compile and keep the "
-            "persistent compile cache on (SHEEPRL_JAX_CACHE)",
+            "persistent compile cache at one fixed path (JAX_COMPILATION_CACHE_DIR)",
             recompiles=count,
             compile_seconds=round(seconds, 3),
             windows=len(affected),

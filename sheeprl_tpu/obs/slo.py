@@ -615,7 +615,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     """``python sheeprl.py slo <run_dir>`` entry: print the compliance report,
     write ``slo.json``, exit 0 (or 1 with ``--fail-on`` when a computed OR
     recorded alert fires at that severity; 2 when no stream exists) — the same
-    exit taxonomy ``diagnose`` uses, so CI recipes compose."""
+    exit codes ``diagnose`` uses, so CI recipes compose."""
     import argparse
 
     parser = argparse.ArgumentParser(

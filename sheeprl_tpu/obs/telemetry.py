@@ -488,10 +488,10 @@ class RunTelemetry:
         self._programs[name] = info
         # The memory_analysis() half needs a backend compile. The loop's first
         # real call just compiled the same HLO, so with the persistent compile
-        # cache on (cli._setup_xla_env default) the AOT compile is a cache hit;
-        # without it (SHEEPRL_JAX_CACHE=0) a remote-TPU compile would be a cold
-        # multi-minute stall, so only the CPU backend compiles then — FLOPs
-        # still come from the pre-compile lowering either way.
+        # cache on (cli._setup_xla_env turns it on) the AOT compile is a cache
+        # hit; a caller that never enabled it would pay a second cold compile
+        # of the train program on an accelerator, so only the CPU backend
+        # compiles then — FLOPs still come from the pre-compile lowering.
         import jax
 
         do_compile = bool(jax.config.jax_compilation_cache_dir) or (

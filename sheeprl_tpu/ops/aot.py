@@ -64,12 +64,13 @@ def _aot_gru_pallas_step():
     doc="the exact tpu=Pallas/default=reference dispatch LayerNormGRUCell builds",
 )
 def _aot_gru_platform_dispatch():
-    # the exact dispatch LayerNormGRUCell builds on a TPU process: the tpu
-    # branch is the Pallas kernel, every other platform the XLA reference.
-    # (A CPU lowering of this dispatch is EXPECTED to fail — platform_dependent
-    # lowers every branch, and Mosaic refuses CPU — which is exactly why
-    # models.py only builds it under the jax.default_backend() gate; the
-    # negative is pinned in tests/test_ops/test_tpu_lowering.py.)
+    # the exact dispatch LayerNormGRUCell builds: the tpu branch is the Pallas
+    # kernel, every other platform the XLA reference. A jit lowers for ONE
+    # platform and keeps only that platform's branch (both one-platform
+    # lowerings are pinned in tests/test_ops/test_tpu_lowering.py). Registered
+    # tpu-only because the sweep's ("cpu", "tpu") form is a single
+    # multi-platform module, which lowers every branch for every platform —
+    # and Mosaic has no CPU lowering.
     def dispatch(inp, hx, w, b, scale, bias):
         return jax.lax.platform_dependent(
             tpu=lambda: ops.fused_ln_gru_step(inp, hx, w, b, scale, bias, eps=1e-3),

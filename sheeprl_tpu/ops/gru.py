@@ -12,11 +12,14 @@ XLA compiles this as matmul + a chain of elementwise/reduce ops; the Pallas kern
 runs the whole step in ONE VMEM-resident pass — the [B, 3H] gates tensor never
 round-trips to HBM between the matmul, the layernorm reduction, and the gating —
 which is exactly the fusion the memory-bound sequential scan wants. The kernel tiles
-the batch over a grid and keeps W resident in VMEM, so it applies when
-``K * 3H * 4B`` fits on-chip (all Dreamer sizes up to L; XL falls back to XLA).
+the batch over a grid and keeps the whole ``[K, 3H]`` weight block resident in VMEM,
+so it applies only while that block fits the kernel's scoped VMEM next to the
+activation tiles: the XS and S Dreamer presets (S: K=1024, H=512, 6 MiB in f32).
+From M up (20 MiB) the XLA path runs. See :func:`pallas_gru_applicable`.
 
 ``interpret=True`` runs the same kernel on CPU for tests (numerical-parity suite in
-tests/test_ops/test_gru_kernel.py).
+tests/test_ops/test_gru_kernel.py); ``chip_smoke.py`` runs it compiled against
+:func:`ln_gru_step_reference` at the S shapes.
 """
 
 from __future__ import annotations
@@ -28,9 +31,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# VMEM budget for the weight block (bytes); above this the caller should fall back
-# to the XLA path. ~8 MB leaves room for the activation tiles in 16 MB VMEM.
+# VMEM budget for the weight block (bytes); above this the caller takes the XLA
+# path. Mosaic gives a kernel 16 MiB of scoped VMEM on v5e unless told otherwise:
+# the S block (6 MiB) plus 256-row tiles compiles under it, a 24 MiB block is
+# refused ("exceeded scoped vmem limit", measured on v5e, JAX 0.9.0).
 PALLAS_GRU_VMEM_WEIGHT_BUDGET = 8 * 1024 * 1024
+# TPU vregs are 128 lanes wide. The kernel slices the normalized [B, 3H] gates
+# into three [B, H] pieces, so H is held to whole lanes (and K with it, for the
+# matmul operand): the shapes verified compiled on the chip are lane-aligned, and
+# a toy cell (H=8) has no business in a VMEM-residency kernel.
+_LANES = 128
 
 
 def _ln_gru_kernel(inp_ref, hx_ref, w_ref, b_ref, scale_ref, bias_ref, out_ref, *, eps: float):
@@ -166,7 +176,13 @@ def ln_gru_step_reference(
 
 
 def pallas_gru_applicable(K: int, H: int, itemsize: int = 4) -> bool:
-    """Whether the fused kernel's weight block fits the VMEM budget. (Platform
-    selection is NOT decided here: LayerNormGRUCell dispatches per lowering
-    platform via jax.lax.platform_dependent.)"""
-    return K * 3 * H * itemsize <= PALLAS_GRU_VMEM_WEIGHT_BUDGET
+    """Whether the fused kernel takes a ``[K, 3H]`` cell: lane-aligned ``K`` and
+    ``H`` (lower bound: nothing narrower than one vreg reaches Mosaic) and a
+    weight block inside the VMEM budget (upper bound). Platform selection is NOT
+    decided here: LayerNormGRUCell dispatches per lowering platform via
+    jax.lax.platform_dependent."""
+    return (
+        H % _LANES == 0
+        and K % _LANES == 0
+        and K * 3 * H * itemsize <= PALLAS_GRU_VMEM_WEIGHT_BUDGET
+    )

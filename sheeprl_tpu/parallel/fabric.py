@@ -16,6 +16,7 @@ but is built on:
 
 from __future__ import annotations
 
+import functools
 import os
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
@@ -72,6 +73,14 @@ def normalize_mesh_spec(
     if any(s == 0 or s < -1 for s in shape):
         raise ValueError(f"fabric.mesh_shape dimensions must be >= 1 (or one -1), got {shape}")
     return shape, names
+
+
+@functools.lru_cache(maxsize=None)
+def _announce_auto_resolution(platform: str, device_kind: str) -> None:
+    """``accelerator: auto`` takes whatever backend JAX made the default; say
+    which one that was, once per process (every later fabric resolves the same)."""
+    if distributed.process_index() == 0:
+        print(f"[sheeprl-fabric] accelerator=auto resolved to {platform} ({device_kind})")
 
 
 class Fabric:
@@ -198,8 +207,9 @@ class Fabric:
 
     def _resolve_platform(self) -> str:
         if self.accelerator in ("auto", None):
-            platforms = {d.platform for d in jax.devices()}
-            return "tpu" if "tpu" in platforms else jax.devices()[0].platform
+            device = jax.devices()[0]
+            _announce_auto_resolution(device.platform, device.device_kind)
+            return device.platform
         if self.accelerator in ("tpu", "cpu", "gpu"):
             return self.accelerator
         raise ValueError(f"unknown accelerator {self.accelerator!r}")
@@ -215,8 +225,13 @@ class Fabric:
         platform = self._resolve_platform()
         try:
             all_devices = jax.devices(platform)
-        except RuntimeError:
-            all_devices = jax.devices()
+        except RuntimeError as err:
+            # no fallback: a request for the chip that the CPU would silently
+            # serve is the failure this layer exists to surface
+            raise RuntimeError(
+                f"fabric.accelerator={self.accelerator!r} but JAX has no {platform!r} backend: "
+                f"jax.devices() found {jax.devices()} (JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r})"
+            ) from err
         if self.process_group is not None:
             # A process-group mesh spans every member process; ``devices`` counts
             # devices PER PROCESS (each member contributes the same number, so the

@@ -20,11 +20,7 @@ from typing import Any, Callable, Tuple
 
 import jax
 import jax.numpy as jnp
-
-try:  # jax >= 0.6 top-level API; the experimental path is deprecated
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -100,16 +96,10 @@ def ring_sequence_scan(
     out_specs = (P(), P(axis))
     # relaxed body checking: bodies may contain ops without varying-axis types
     # (e.g. a pallas_call's out_shape); the ring's collectives are explicitly
-    # paired here. The kwarg is `check_vma` on new jax and `check_rep` before it —
-    # probe in that order so both APIs work.
-    try:
-        shmapped = shard_map(
-            _local, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    except TypeError:
-        shmapped = shard_map(
-            _local, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-        )
+    # paired here.
+    shmapped = shard_map(
+        _local, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
     return shmapped(init, xs)
 
 

@@ -384,11 +384,7 @@ def serve_main(args: Optional[Sequence[str]] = None) -> int:
         print(
             f"[sheeprl-serve] primed {stats['programs']} serving program(s) for "
             f"{cfg.algo.name} ({stats['slots']} slots) in {time.perf_counter() - t0:.1f}s"
-            + (
-                f" — persistent cache at {cache_dir}"
-                if cache_dir
-                else " — WARNING: persistent compile cache is DISABLED (SHEEPRL_JAX_CACHE=0?)"
-            )
+            f" — persistent cache at {cache_dir}"
         )
         return 0
 
@@ -499,7 +495,7 @@ def serve_main(args: Optional[Sequence[str]] = None) -> int:
 
 
 def _verdict(info: Optional[Dict[str, Any]]) -> int:
-    """Map one attempt's outcome onto the serve exit-code taxonomy."""
+    """Map one attempt's outcome onto the serve exit codes."""
     from sheeprl_tpu.resilience.signals import PREEMPTED_EXIT_CODE
 
     if info is None:

@@ -1,61 +1,46 @@
 """Persistent XLA compilation cache policy, shared by every entry point (CLI,
-tests, driver hooks). The fused train programs take tens of seconds to compile;
-caching them on disk lets later processes skip the compile entirely. Opt out with
-``SHEEPRL_JAX_CACHE=0`` or point ``SHEEPRL_JAX_CACHE`` at another directory.
+serve, tests, driver hooks). The fused train programs take tens of seconds to
+minutes to compile; caching them on disk lets later processes skip the compile.
 
-The default cache dir is suffixed with a host-CPU-feature fingerprint: XLA:CPU
-AOT-compiles against the build machine's feature set, and loading such an entry
-on a machine with different features can SIGILL (cpu_aot_loader warns about
-exactly this). Fingerprinting the dir means a cache written on one machine is
-simply invisible on a different one instead of a hazard. An explicit
-``SHEEPRL_JAX_CACHE=<dir>`` is used verbatim — the caller owns the key."""
+Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it and this module sets no
+directory in code: the caller places the cache. Where it is not, the cache lives
+at ONE fixed path inside the checkout (``<repo>/.jax_cache``, git-ignored): the
+directory must not move between runs, so it is never derived from ``~``, a temp
+name, a pid or a time, and every process of a fleet or gang inherits the same one.
+
+The cache is not meant to travel between machines: XLA:CPU entries are compiled
+against the build host's CPU features. It stays out of git (``.gitignore``) and
+out of the chip tool's copy (``.chiprunignore``)."""
 
 from __future__ import annotations
 
-import hashlib
 import os
-import platform
+from typing import Mapping
+
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))), ".jax_cache"
+)
 
 
-def _cpu_fingerprint() -> str:
-    """Short stable hash of the host's CPU ISA features (+ arch)."""
-    flags = ""
-    try:
-        with open("/proc/cpuinfo") as f:
-            for line in f:
-                if line.startswith(("flags", "Features")):
-                    # sorted: flag ORDER is not guaranteed stable across kernels
-                    flags = " ".join(sorted(line.split(":", 1)[1].split()))
-                    break
-    except OSError:
-        pass
-    if not flags:
-        # Non-Linux host (no /proc/cpuinfo): fall back to the coarser
-        # OS/release/processor identity for per-machine-class separation. Linux
-        # keeps the pure ISA-flags key so kernel upgrades don't churn the cache.
-        flags = f"{platform.platform()}|{platform.processor()}"
-    raw = f"{platform.machine()}|{flags}"
-    return hashlib.sha256(raw.encode()).hexdigest()[:12]
+def cache_dir(env: Mapping[str, str] = os.environ) -> str:
+    """The directory a process started with ``env`` keeps its cache in (needs no JAX)."""
+    return env.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_CACHE_DIR
 
 
-def enable_compile_cache() -> None:
+def enable_compile_cache() -> str:
+    """Turn the persistent cache on and return the directory in use."""
     import jax
 
-    cache_dir = os.environ.get("SHEEPRL_JAX_CACHE")
-    if cache_dir is None:
-        cache_dir = os.path.expanduser(f"~/.cache/sheeprl_tpu/jax-{_cpu_fingerprint()}")
-    if cache_dir not in ("0", ""):
-        # Persistence threshold: programs compiling faster than this are not
-        # written to the cache (default 1 s — sub-second CPU programs are cheaper
-        # to recompile than to deserialize on a real chip). The fleet runner
-        # (sheeprl_tpu/fleet) sets the env override to 0 so EVERY member program
-        # persists and the sweep's later members cold-start as pure cache hits.
-        try:
-            min_secs = float(os.environ.get("SHEEPRL_JAX_CACHE_MIN_COMPILE_SECS", "1.0"))
-        except ValueError:
-            min_secs = 1.0
-        try:
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", min_secs)
-        except Exception:
-            pass
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+    # Persistence threshold: programs compiling faster than this are not
+    # written to the cache (default 1 s — sub-second CPU programs are cheaper
+    # to recompile than to deserialize on a real chip). The fleet runner
+    # (sheeprl_tpu/fleet) sets the env override to 0 so EVERY member program
+    # persists and the sweep's later members cold-start as pure cache hits.
+    try:
+        min_secs = float(os.environ.get("SHEEPRL_JAX_CACHE_MIN_COMPILE_SECS", "1.0"))
+    except ValueError:
+        min_secs = 1.0
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", min_secs)
+    return str(jax.config.jax_compilation_cache_dir)

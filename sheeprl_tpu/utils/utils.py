@@ -203,10 +203,10 @@ def _pack_leaves(leaves):
 def packed_device_get(tree: Any) -> Any:
     """Fetch a device pytree to host numpy with ONE transfer per dtype group.
 
-    ``jax.device_get`` issues one device→host round-trip per leaf; on a remote
-    accelerator (e.g. a tunneled TPU) each round-trip costs a full RTT, so a
-    ~60-leaf params tree takes ~60 RTTs. Packing all leaves into a single flat
-    device array first makes it one RTT per distinct dtype (usually one).
+    ``jax.device_get`` issues one device→host transfer per leaf, so a ~60-leaf
+    params tree is ~60 transfers. Packing all leaves into a single flat device
+    array first makes it one per distinct dtype (usually one). What a transfer
+    costs on an attached chip is not measured (ROADMAP S3).
     """
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     if not leaves:
@@ -228,21 +228,38 @@ def packed_device_get(tree: Any) -> Any:
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
+def host_cpu_device() -> jax.Device:
+    """This process's host CPU device, for the programs that are placed there
+    (act steps, host-stepped jax envs) while the train program runs on the
+    accelerator. They need the CPU backend NEXT TO the accelerator's: a process
+    started with ``JAX_PLATFORMS=tpu`` has none, and that is an error here, not
+    a reason to move the work."""
+    try:
+        # local_devices: jax.devices() spans ALL processes of a multi-process run,
+        # and a non-rank-0 role (a service actor) must pin ITS host device
+        return jax.local_devices(backend="cpu")[0]
+    except RuntimeError as err:
+        raise RuntimeError(
+            "the act path and the host-stepped envs run on the host CPU backend, which "
+            f"JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r} leaves out: "
+            "list it after the accelerator (JAX_PLATFORMS=tpu,cpu) or leave the variable unset"
+        ) from err
+
+
 class ActPlacement:
     """Act/train device-placement split, shared by every per-step-acting algorithm.
 
-    The one-frame act program runs on the host CPU backend — per-step dispatch
-    latency to an accelerator dwarfs the forward — while the fused train program
-    runs on the accelerator; only the player-visible subtree (``select``) crosses
-    back per train call, as one packed transfer. On a CPU fabric everything is the
+    The one-frame act program runs on the host CPU backend, next to the env it
+    feeds, while the fused train program runs on the accelerator; only the
+    player-visible subtree (``select``) crosses back per train call, as one
+    packed transfer. Whether acting on the host beats acting on an attached
+    chip is not measured (ROADMAP S3). On a CPU fabric everything is the
     identity, so call sites need no branching.
     """
 
     def __init__(self, fabric, select: Optional[Callable[[Any], Any]] = None) -> None:
-        # local_devices: jax.devices() spans ALL processes of a multi-process run,
-        # and a non-rank-0 role (a service actor) must pin ITS host device
-        self.cpu_device = jax.local_devices(backend="cpu")[0]
         self.on_cpu = fabric.device.platform != "cpu"
+        self.cpu_device = host_cpu_device()
         self._select = select or (lambda p: p)
 
     def view(self, params: Any) -> Any:

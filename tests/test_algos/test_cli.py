@@ -42,6 +42,14 @@ def test_decoupled_dp_strategy_passes():
     check_configs(cfg)
 
 
+def test_gang_that_would_need_the_chip_is_refused_before_spawning():
+    # a chip belongs to one process: N children on accelerator=auto/tpu would all claim it
+    gang = ["resilience.distributed.gang.processes=2"]
+    with pytest.raises(ValueError, match="CPU-mesh only"):
+        check_configs(_cfg(gang))
+    check_configs(_cfg(gang + ["fabric.accelerator=cpu"]))
+
+
 def test_negative_learning_starts_fails():
     cfg = compose(["exp=sac", "env=dummy", "env.id=continuous_dummy", "algo.learning_starts=-1"])
     with pytest.raises(ValueError, match="learning_starts"):

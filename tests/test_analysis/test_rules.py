@@ -18,7 +18,6 @@ from sheeprl_tpu.analysis.rules import (
     JaxDevicesRule,
     LoopHooksRule,
     PallasDotPrecisionRule,
-    PlatformDependentGateRule,
     TelemetryEventSchemaRule,
 )
 
@@ -61,53 +60,6 @@ def test_jax_devices_allowed_in_fabric_and_local_devices_everywhere(tmp_path):
         {
             "parallel/fabric.py": "import jax\nall_devices = jax.devices()\n",
             "utils/x.py": "import jax\ndevice = jax.local_devices()[0]\n",
-        },
-    )
-    assert found == []
-
-
-# ---- platform-dependent-ungated ------------------------------------------------
-
-_UNGATED = """
-    import jax
-
-    def dispatch(x):
-        return jax.lax.platform_dependent(
-            tpu=lambda: x * 2,
-            default=lambda: x + 1,
-        )
-"""
-
-_GATED = """
-    import jax
-
-    def dispatch(x):
-        if jax.default_backend() == "tpu":
-            return jax.lax.platform_dependent(
-                tpu=lambda: x * 2,
-                default=lambda: x + 1,
-            )
-        return x + 1
-"""
-
-
-def test_ungated_tpu_branch_fires(tmp_path):
-    found = _findings(tmp_path, PlatformDependentGateRule(), {"models/m.py": _UNGATED})
-    assert len(found) == 1 and found[0]["severity"] == "critical"
-
-
-def test_gated_tpu_branch_and_cpu_gate_are_silent(tmp_path):
-    found = _findings(
-        tmp_path,
-        PlatformDependentGateRule(),
-        {
-            "models/gated.py": _GATED,
-            # cpu=/default= fast-path gates lower on every platform: no tpu kwarg
-            "ops/conv.py": (
-                "import jax\n"
-                "def f(x):\n"
-                "    return jax.lax.platform_dependent(x, cpu=lambda v: v, default=lambda v: v)\n"
-            ),
         },
     )
     assert found == []

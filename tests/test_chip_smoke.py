@@ -1,0 +1,67 @@
+"""``chip_smoke.py`` on the CPU: the same phase functions the chip check runs,
+at tiny widths with the expected platform passed as ``"cpu"``, and ``main()``'s
+refusal to pass without an accelerator."""
+
+from __future__ import annotations
+
+import json
+
+import pytest
+
+import chip_smoke
+from sheeprl_tpu.analysis.programs import DREAMER_TINY_OVERRIDES
+
+# the S experiment at the tiny widths every dreamer-family AOT builder uses
+_TINY = [
+    chip_smoke.S_TRAIN_OVERRIDES[0],
+    *DREAMER_TINY_OVERRIDES,
+    "env.id=discrete_dummy",
+    "env.num_envs=1",
+    "env.sync_env=True",
+    "buffer.size=64",
+    "algo.learning_starts=8",
+    "algo.total_steps=10",
+    "algo.run_test=False",
+]
+
+
+@pytest.mark.timeout(300)
+def test_phases_run_on_cpu_at_tiny_widths(tmp_path):
+    kernel = chip_smoke.kernel_phase("cpu", rows=(16,), K=128, H=128)
+    assert kernel["compiled"] is False and "16" in kernel["rows"]
+    train = chip_smoke.train_phase(_TINY, platform="cpu", grad_steps=3, out_dir=str(tmp_path))
+    assert train["grad_steps"] == 3 and train["gru_branch"] == "xla"  # H=8: never Mosaic
+    served = chip_smoke.serve_phase(
+        train["checkpoint"],
+        ["serve.slots=2", "serve.sessions=2", "serve.max_session_steps=8"],
+        platform="cpu",
+        sessions=2,
+        out_dir=str(tmp_path),
+    )
+    assert served["sessions_finished"] == 2 and served["sessions_failed"] == 0
+
+
+def test_script_refuses_to_pass_without_the_chip(tmp_path, monkeypatch, capsys):
+    # `python chip_smoke.py` is sys.exit(main()): what main() raises is a non-zero exit
+    monkeypatch.setattr(chip_smoke, "WORK_DIR", str(tmp_path / "work"))
+    monkeypatch.setattr(chip_smoke, "REPORT_DIR", str(tmp_path / "report"))
+    with pytest.raises(AssertionError, match="'platform': 'cpu'"):  # names what it found
+        chip_smoke.main()
+    assert '"ok"' not in capsys.readouterr().out  # and prints no result
+
+
+def test_last_line_is_the_verdict_and_nothing_else(tmp_path, monkeypatch, capsys):
+    # the chip check reads the last line of stdout: {"ok", "device"} with
+    # {"platform", "kind", "count"} and no other key; the rest goes to the line before
+    (tmp_path / "t.jsonl").write_text("")
+    phase = {"telemetry": str(tmp_path / "t.jsonl"), "checkpoint": "c", "gru_branch": "pallas (tpu_custom_call)"}
+    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1, "jax": "j", "jaxlib": "l", "libtpu": "t", "cache_dir": "d"}
+    monkeypatch.setattr(chip_smoke, "WORK_DIR", str(tmp_path / "work"))
+    monkeypatch.setattr(chip_smoke, "REPORT_DIR", str(tmp_path / "report"))
+    monkeypatch.setattr(chip_smoke, "device_report", lambda platform: device)
+    for name in ("kernel_phase", "train_phase", "serve_phase"):
+        monkeypatch.setattr(chip_smoke, name, lambda *a, **k: phase)
+    assert chip_smoke.main() == 0
+    *_, full, last = capsys.readouterr().out.splitlines()
+    assert json.loads(last) == {"ok": True, "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
+    assert full.startswith("[chip-smoke] result: ") and json.loads(full.split(": ", 1)[1])["claim"] is None

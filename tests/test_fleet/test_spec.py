@@ -54,7 +54,7 @@ members:
     [
         ("base: [exp=ppo]", "no members"),
         ("members: [{name: a}, {name: a}]", "duplicate"),
-        ("members: [{name: 'xla_cache'}]", "filesystem-safe"),
+        ("members: [{name: 'gang'}]", "filesystem-safe"),
         ("members: [{name: 'a/b'}]", "filesystem-safe"),
         ("sweep: {seed: [1]}\ncompare: {fail_on: bogus}", "fail_on"),
     ],

@@ -77,9 +77,14 @@ def test_kernel_gradient_matches_reference():
         np.testing.assert_allclose(np.asarray(gf), np.asarray(gr), rtol=1e-5, atol=1e-5)
 
 
-def test_vmem_budget_gate():
+def test_applicability_bounds():
+    assert pallas_gru_applicable(512, 256)  # XS
     assert pallas_gru_applicable(1024, 512)  # S-scale (K = mlp+h = 1024) fits
+    assert not pallas_gru_applicable(1664, 1024)  # M (20 MiB block): XLA
     assert not pallas_gru_applicable(12288, 4096)  # XL falls back to XLA
+    # lower bound: the benchmark exp's toy cell (H=8, K=16) never reaches Mosaic
+    assert not pallas_gru_applicable(16, 8)
+    assert not pallas_gru_applicable(1000, 512)  # K off the lane grid
 
 
 @pytest.mark.slow

@@ -12,11 +12,9 @@ what the registry deliberately does not encode:
   dot inherited it and failed to lower for TPU at all (the bug this suite
   caught; the kernel now pins its own precision, and the graftlint
   ``pallas-dot-precision`` rule polices new kernels);
-- the KNOWN-limitation NEGATIVE: ``platform_dependent`` lowers EVERY branch
-  for every requested platform, so a CPU lowering of the Pallas dispatch must
-  FAIL — which is exactly why models.py gates the dispatch on
-  ``jax.default_backend()`` (the graftlint ``platform-dependent-ungated`` rule)
-  and why the ``ops.gru_platform_dispatch`` registry entry is tpu-only;
+- what ``platform_dependent`` does under the installed JAX: a one-platform
+  lowering carries ONLY that platform's branch (so the CPU-placed act program
+  of a TPU process never meets Mosaic, and models.py needs no backend gate);
 - the lower-only contract: the suite (and the sweep) must never backend-compile
   the TPU programs on a real chip's clock.
 """
@@ -63,27 +61,23 @@ def test_pallas_gru_lowers_for_tpu_under_every_precision_config(matmul_precision
     assert "tpu_custom_call" in lowered.as_text(), "the Pallas GRU must lower to a Mosaic custom call"
 
 
-def test_gru_dispatch_cpu_lowering_needs_the_backend_gate():
-    # pins the KNOWN limitation models.py documents: platform_dependent lowers
-    # EVERY branch for every requested platform, and the Pallas TPU kernel
-    # refuses a CPU lowering — which is exactly why LayerNormGRUCell only
-    # builds the dispatch when the process backend is TPU. If this ever starts
-    # passing, that gate (and SHEEPRL_DISABLE_PALLAS) can be retired — and the
-    # ops.gru_platform_dispatch registry entry can widen to ("cpu", "tpu").
+def test_gru_dispatch_lowers_the_branch_of_the_lowering_platform():
+    # the dispatch LayerNormGRUCell builds, lowered for one platform at a time:
+    # the CPU program (tests, and the CPU-placed act program of a TPU process)
+    # is the XLA reference with no Mosaic call; the TPU program has the kernel
     fn, args = FUSED_PROGRAMS["ops.gru_platform_dispatch"].builder()
-    with pytest.raises(Exception, match="interpret mode"):
-        fn.trace(*args).lower(lowering_platforms=("cpu",))
+    traced = fn.trace(*args)
+    assert "tpu_custom_call" not in traced.lower(lowering_platforms=("cpu",)).as_text()
+    assert "tpu_custom_call" in traced.lower(lowering_platforms=("tpu",)).as_text()
 
 
-def test_fast_conv_tpu_lowering_carries_both_branches():
-    # platform_dependent lowers every branch (selection is a platform-index
-    # case, folded by the backend compile): a TPU lowering therefore carries
-    # BOTH the s2d decomposition's conv and the native conv — and the test's
-    # point is that the s2d branch is TPU-lowerable at all (valid StableHLO),
-    # so the gate can never trip a trace error on a real chip
+def test_fast_conv_tpu_lowering_is_the_native_convolution_only():
+    # cpu=s2d decomposition / default=native: a TPU lowering drops the s2d
+    # branch, so the chip runs ONE native convolution (the MXU path) — an
+    # im2col form tuned for XLA:CPU never reaches it
     fn, args = FUSED_PROGRAMS["ops.fast_conv"].builder()
     tpu_hlo = fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
-    assert tpu_hlo.count("stablehlo.convolution") >= 2, "both conv branches must lower"
+    assert tpu_hlo.count("stablehlo.convolution") == 1
 
 
 def test_tpu_lowering_compiles_nothing(monkeypatch):

@@ -29,6 +29,22 @@ def test_single_device_fabric_shares_runtime_settings():
     assert single._callbacks == []
 
 
+def test_accelerator_tpu_without_a_tpu_raises_instead_of_serving_the_cpu():
+    with pytest.raises(RuntimeError, match=r"no 'tpu' backend.*CpuDevice"):
+        Fabric(devices=1, accelerator="tpu")._setup()
+
+
+def test_peak_flops_knows_the_cpu_has_none_and_refuses_an_unknown_tpu():
+    from types import SimpleNamespace
+
+    from sheeprl_tpu.utils.mfu import peak_flops
+
+    assert peak_flops(jax.local_devices()[0]) is None
+    assert peak_flops(SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")) == 197e12
+    with pytest.raises(ValueError, match="TPU v9"):
+        peak_flops(SimpleNamespace(platform="tpu", device_kind="TPU v9"))
+
+
 def test_mesh_and_world_size():
     f = Fabric(devices=4, accelerator="cpu")
     f._setup()

@@ -10,6 +10,33 @@ from sheeprl_tpu.cli import compile_warm, one_train_phase_steps
 from sheeprl_tpu.config import compose
 
 
+def test_compile_cache_is_placed_from_outside_or_at_one_fixed_path(monkeypatch):
+    import os
+
+    import jax
+
+    from sheeprl_tpu.fleet.runner import _build_member_env
+    from sheeprl_tpu.utils import compile_cache
+
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    assert compile_cache.DEFAULT_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        # set from outside: JAX owns the directory, the program sets none
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+        jax.config.update("jax_compilation_cache_dir", "/what/jax/read")
+        assert compile_cache.enable_compile_cache() == "/what/jax/read"
+        # unset: the one fixed in-checkout path
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert compile_cache.enable_compile_cache() == compile_cache.DEFAULT_CACHE_DIR
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    # fleet members inherit that cache: no per-fleet (time-stamped) directory
+    env = _build_member_env({"compile_cache": True})
+    assert "JAX_COMPILATION_CACHE_DIR" not in env and "SHEEPRL_JAX_CACHE" not in env
+    assert compile_cache.cache_dir(env) == compile_cache.DEFAULT_CACHE_DIR
+
+
 def test_one_train_phase_steps_on_policy():
     cfg = compose(["exp=ppo", "env.num_envs=4"])
     # one full rollout across the vectorized envs = one PPO update
