@@ -129,31 +129,35 @@ def make_train_phase(
         actions = jnp.concatenate(
             [jnp.zeros_like(batch["actions"][:1]), batch["actions"][:-1]], axis=0
         )
-        embedded = agent.encoder.apply({"params": wm_params["encoder"]}, batch_obs)
-        hs, zs, post_logits, prior_logits = agent.dynamic_scan(
-            wm_params, embedded, actions, is_first, key
-        )
+        with jax.named_scope("encoder"):
+            embedded = agent.encoder.apply({"params": wm_params["encoder"]}, batch_obs)
+        with jax.named_scope("rssm"):
+            hs, zs, post_logits, prior_logits = agent.dynamic_scan(
+                wm_params, embedded, actions, is_first, key
+            )
         latents = jnp.concatenate([zs, hs], axis=-1)
         extra_loss, extra_metrics = 0.0, {}
         if world_latent_hook is not None:
             latents, extra_loss, extra_metrics = world_latent_hook(wm_params, latents, hook_key)
-        recon = agent.observation_model.apply({"params": wm_params["observation_model"]}, latents)
-        obs_lps = {
-            k: MSEDistribution(recon[k], dims=len(recon[k].shape[2:])).log_prob(batch_obs[k])
-            for k in cnn_dec_keys
-        }
-        obs_lps.update(
-            {
-                k: SymlogDistribution(recon[k], dims=len(recon[k].shape[2:])).log_prob(batch_obs[k])
-                for k in mlp_dec_keys
+        with jax.named_scope("decoder"):
+            recon = agent.observation_model.apply({"params": wm_params["observation_model"]}, latents)
+            obs_lps = {
+                k: MSEDistribution(recon[k], dims=len(recon[k].shape[2:])).log_prob(batch_obs[k])
+                for k in cnn_dec_keys
             }
-        )
-        reward_logits = agent.reward_model.apply({"params": wm_params["reward_model"]}, latents)
-        reward_lp = TwoHotEncodingDistribution(reward_logits, dims=1).log_prob(batch["rewards"])
-        cont_logits = agent.continue_model.apply({"params": wm_params["continue_model"]}, latents)
-        cont_lp = Independent(BernoulliSafeMode(logits=cont_logits), 1).log_prob(
-            1.0 - batch["terminated"]
-        )
+            obs_lps.update(
+                {
+                    k: SymlogDistribution(recon[k], dims=len(recon[k].shape[2:])).log_prob(batch_obs[k])
+                    for k in mlp_dec_keys
+                }
+            )
+        with jax.named_scope("heads"):
+            reward_logits = agent.reward_model.apply({"params": wm_params["reward_model"]}, latents)
+            reward_lp = TwoHotEncodingDistribution(reward_logits, dims=1).log_prob(batch["rewards"])
+            cont_logits = agent.continue_model.apply({"params": wm_params["continue_model"]}, latents)
+            cont_lp = Independent(BernoulliSafeMode(logits=cont_logits), 1).log_prob(
+                1.0 - batch["terminated"]
+            )
         rec_loss, kl, state_loss, reward_loss, observation_loss, continue_loss = reconstruction_loss(
             obs_lps,
             reward_lp,
@@ -191,17 +195,20 @@ def make_train_phase(
         wm = params["world_model"]
         z0 = jax.lax.stop_gradient(zs).reshape(-1, agent.stoch_state_size)
         h0 = jax.lax.stop_gradient(hs).reshape(-1, agent.recurrent_state_size)
-        latents, actions = agent.imagination_scan(wm, actor_params, z0, h0, key, horizon)
-        predicted_values = TwoHotEncodingDistribution(
-            agent.critic.apply({"params": params["critic"]}, latents), dims=1
-        ).mean
-        predicted_rewards = TwoHotEncodingDistribution(
-            agent.reward_model.apply({"params": wm["reward_model"]}, latents), dims=1
-        ).mean
-        continues = Independent(
-            BernoulliSafeMode(logits=agent.continue_model.apply({"params": wm["continue_model"]}, latents)),
-            1,
-        ).mode
+        with jax.named_scope("imagine"):
+            latents, actions = agent.imagination_scan(wm, actor_params, z0, h0, key, horizon)
+        with jax.named_scope("critic"):
+            predicted_values = TwoHotEncodingDistribution(
+                agent.critic.apply({"params": params["critic"]}, latents), dims=1
+            ).mean
+        with jax.named_scope("heads"):
+            predicted_rewards = TwoHotEncodingDistribution(
+                agent.reward_model.apply({"params": wm["reward_model"]}, latents), dims=1
+            ).mean
+            continues = Independent(
+                BernoulliSafeMode(logits=agent.continue_model.apply({"params": wm["continue_model"]}, latents)),
+                1,
+            ).mode
         continues = jnp.concatenate([true_continue[None], continues[1:]], axis=0)
         lambda_values = compute_lambda_values(
             predicted_rewards[1:], predicted_values[1:], continues[1:] * gamma, lmbda
@@ -213,8 +220,9 @@ def make_train_phase(
         normed_lambda = (lambda_values - offset) / invscale
         normed_baseline = (baseline - offset) / invscale
         advantage = normed_lambda - normed_baseline
-        pre = agent.actor.apply({"params": actor_params}, jax.lax.stop_gradient(latents))
-        lp, ent = actor_logprob_entropy(agent, pre, jax.lax.stop_gradient(actions))
+        with jax.named_scope("actor"):
+            pre = agent.actor.apply({"params": actor_params}, jax.lax.stop_gradient(latents))
+            lp, ent = actor_logprob_entropy(agent, pre, jax.lax.stop_gradient(actions))
         if agent.is_continuous:
             objective = advantage
         else:
@@ -231,14 +239,15 @@ def make_train_phase(
         return policy_loss, (latents, lambda_values, discount, new_moments, aux_stats)
 
     def critic_loss_fn(critic_params, target_params, latents, lambda_values, discount):
-        qv_logits = agent.critic.apply({"params": critic_params}, latents[:-1])
-        qv = TwoHotEncodingDistribution(qv_logits, dims=1)
-        target_values = TwoHotEncodingDistribution(
-            agent.critic.apply({"params": target_params}, latents[:-1]), dims=1
-        ).mean
-        value_loss = -qv.log_prob(jax.lax.stop_gradient(lambda_values))
-        value_loss = value_loss - qv.log_prob(jax.lax.stop_gradient(target_values))
-        return jnp.mean(value_loss * discount[:-1].squeeze(-1))
+        with jax.named_scope("critic"):
+            qv_logits = agent.critic.apply({"params": critic_params}, latents[:-1])
+            qv = TwoHotEncodingDistribution(qv_logits, dims=1)
+            target_values = TwoHotEncodingDistribution(
+                agent.critic.apply({"params": target_params}, latents[:-1]), dims=1
+            ).mean
+            value_loss = -qv.log_prob(jax.lax.stop_gradient(lambda_values))
+            value_loss = value_loss - qv.log_prob(jax.lax.stop_gradient(target_values))
+            return jnp.mean(value_loss * discount[:-1].squeeze(-1))
 
     # ONE compiled program per single gradient step, driven by a host loop over the
     # [G, ...] replay block. Two reasons this beats an outer ``lax.scan`` over G:
@@ -262,20 +271,22 @@ def make_train_phase(
         # target-critic EMA before the step (reference dreamer_v3.py:756-761)
         do_ema = (cum % target_freq) == 0
         tau_eff = jnp.where(cum == 0, 1.0, tau)
-        params = {
-            **params,
-            "target_critic": jax.tree_util.tree_map(
-                lambda t, c: jnp.where(do_ema, tau_eff * c + (1 - tau_eff) * t, t),
-                params["target_critic"],
-                params["critic"],
-            ),
-        }
+        with jax.named_scope("optimizer"):
+            params = {
+                **params,
+                "target_critic": jax.tree_util.tree_map(
+                    lambda t, c: jnp.where(do_ema, tau_eff * c + (1 - tau_eff) * t, t),
+                    params["target_critic"],
+                    params["critic"],
+                ),
+            }
 
         (w_loss, (zs, hs, w_metrics)), w_grads = jax.value_and_grad(world_loss_fn, has_aux=True)(
             params["world_model"], batch, k_world
         )
-        w_updates, new_wopt = world_tx.update(w_grads, opt_state["world_model"], params["world_model"])
-        params = {**params, "world_model": optax.apply_updates(params["world_model"], w_updates)}
+        with jax.named_scope("optimizer"):
+            w_updates, new_wopt = world_tx.update(w_grads, opt_state["world_model"], params["world_model"])
+            params = {**params, "world_model": optax.apply_updates(params["world_model"], w_updates)}
         opt_state = {**opt_state, "world_model": new_wopt}
 
         true_continue = (1 - batch["terminated"]).reshape(-1, 1)
@@ -284,8 +295,9 @@ def make_train_phase(
                 params["actor"], params, zs, hs, true_continue, moments_state, k_img
             )
         )
-        a_updates, new_aopt = actor_tx.update(a_grads, opt_state["actor"], params["actor"])
-        params = {**params, "actor": optax.apply_updates(params["actor"], a_updates)}
+        with jax.named_scope("optimizer"):
+            a_updates, new_aopt = actor_tx.update(a_grads, opt_state["actor"], params["actor"])
+            params = {**params, "actor": optax.apply_updates(params["actor"], a_updates)}
         opt_state = {**opt_state, "actor": new_aopt}
         moments_state = new_moments
 
@@ -293,8 +305,9 @@ def make_train_phase(
         c_loss, c_grads = jax.value_and_grad(critic_loss_fn)(
             params["critic"], params["target_critic"], latents_sg, lambda_values, discount
         )
-        c_updates, new_copt = critic_tx.update(c_grads, opt_state["critic"], params["critic"])
-        params = {**params, "critic": optax.apply_updates(params["critic"], c_updates)}
+        with jax.named_scope("optimizer"):
+            c_updates, new_copt = critic_tx.update(c_grads, opt_state["critic"], params["critic"])
+            params = {**params, "critic": optax.apply_updates(params["critic"], c_updates)}
         opt_state = {**opt_state, "critic": new_copt}
 
         metrics = dict(w_metrics)
@@ -441,18 +454,22 @@ class _InlineTrainer:
         # one-shot injected learning pathology (resilience.fault=lr_spike):
         # identity unless the fault armed this iteration
         self.params = apply_armed_learn_fault(self.params)
-        self.params, self.opt_state, self.moments_state, metrics = self.train_phase(
-            self.params,
-            self.opt_state,
-            self.moments_state,
-            data,
-            jnp.asarray(cum_steps),
-            np.asarray(train_key),
-        )
+        with timer("train_dispatch"):  # G async dispatches: returns before the device is done
+            self.params, self.opt_state, self.moments_state, metrics = self.train_phase(
+                self.params,
+                self.opt_state,
+                self.moments_state,
+                data,
+                jnp.asarray(cum_steps),
+                np.asarray(train_key),
+            )
         # fresh output buffers (never donated), held for the telemetry health
         # guard — which only syncs them at window boundaries, off the hot path
         self.last_metrics = metrics
-        host_metrics = packed_device_get(metrics) if want_metrics else None
+        host_metrics = None
+        if want_metrics:
+            with timer("metrics_get"):
+                host_metrics = packed_device_get(metrics)
         return self.act.view(self.params), host_metrics
 
     def checkpoint_state(self):
@@ -737,6 +754,7 @@ def run_dreamer(
     for iter_num in range(start_iter, total_iters + 1):
         bench.maybe_start(policy_step, trainer.sync_tree())
         policy_step += policy_steps_per_iter
+        timer.iteration = iter_num  # the spans of one iteration share it
 
         with timer("Time/env_interaction_time"):
             if iter_num <= learning_starts and state is None:
@@ -750,9 +768,10 @@ def run_dreamer(
                         axis=-1,
                     )
             else:
-                jobs = prepare_obs(fabric, obs, cnn_keys=cnn_keys, mlp_keys=mlp_keys, num_envs=num_envs)
-                actions, key = player.get_actions(act_params, jobs, key)
-                actions = np.asarray(actions)
+                with timer("act"):
+                    jobs = prepare_obs(fabric, obs, cnn_keys=cnn_keys, mlp_keys=mlp_keys, num_envs=num_envs)
+                    actions, key = player.get_actions(act_params, jobs, key)
+                    actions = np.asarray(actions)
                 if is_continuous:
                     real_actions = actions
                 else:
@@ -762,11 +781,13 @@ def run_dreamer(
                     )
 
             step_data["actions"] = actions.reshape((1, num_envs, -1)).astype(np.float32)
-            sampler.add(step_data, validate_args=cfg.buffer.validate_args)
+            with timer("replay_add"):
+                sampler.add(step_data, validate_args=cfg.buffer.validate_args)
 
-            next_obs, rewards, terminated, truncated, infos = envs.step(
-                real_actions.reshape(envs.action_space.shape)
-            )
+            with timer("env_step"):
+                next_obs, rewards, terminated, truncated, infos = envs.step(
+                    real_actions.reshape(envs.action_space.shape)
+                )
             dones = np.logical_or(terminated, truncated).astype(np.uint8)
 
         step_data["is_first"] = np.zeros_like(step_data["terminated"])
@@ -832,13 +853,15 @@ def run_dreamer(
             reset_data["actions"] = np.zeros((1, reset_envs, act_dim), np.float32)
             reset_data["rewards"] = step_data["rewards"][:, dones_idxes]
             reset_data["is_first"] = np.zeros_like(reset_data["terminated"])
-            sampler.add(reset_data, dones_idxes, validate_args=cfg.buffer.validate_args)
+            with timer("replay_add"):
+                sampler.add(reset_data, dones_idxes, validate_args=cfg.buffer.validate_args)
             # the reset rows restart the episode in the *live* step_data
             step_data["rewards"][:, dones_idxes] = 0.0
             step_data["terminated"][:, dones_idxes] = 0.0
             step_data["truncated"][:, dones_idxes] = 0.0
             step_data["is_first"][:, dones_idxes] = 1.0
-            player.init_states(act_params, dones_idxes)
+            with timer("player_reset"):
+                player.init_states(act_params, dones_idxes)
 
         # checkpoint due? (computed BEFORE the train round so a channel trainer can
         # ship the full state with it; a deferring trainer postpones off-round
@@ -860,7 +883,8 @@ def run_dreamer(
             per_rank_gradient_steps = ratio(ratio_steps / world_size)
             if per_rank_gradient_steps > 0:
                 with timer("Time/train_time"):
-                    data = sampler.sample(per_rank_gradient_steps)
+                    with timer("replay_sample"):
+                        data = sampler.sample(per_rank_gradient_steps)
                     key, train_key = jax.random.split(key)
                     act_params, host_metrics = trainer.train(
                         data,

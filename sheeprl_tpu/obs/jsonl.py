@@ -128,6 +128,18 @@ class JsonlEventSink:
             self._fh = None
 
 
+def spans_path(stream_path: str) -> str:
+    """Where a telemetry stream's raw timer spans live: beside it,
+    ``telemetry[.role].jsonl`` -> ``spans[.role].jsonl``, any other name (a configured
+    ``jsonl_path``) ``<root>.spans<ext>``. Never the stream's own path
+    (``RunTelemetry.close`` writes the file, ``obs/trace.py`` draws it)."""
+    head, name = os.path.split(stream_path)
+    if name.startswith("telemetry"):
+        return os.path.join(head, "spans" + name[len("telemetry"):])
+    root, ext = os.path.splitext(name)
+    return os.path.join(head, f"{root}.spans{ext or '.jsonl'}")
+
+
 def parse_stream_line(line: str) -> List[Dict[str, Any]]:
     """Parse one stream line into its event dict(s), tolerating torn writes.
 

@@ -87,6 +87,10 @@ _WINDOW: Dict[str, Tuple[tuple, bool]] = {
     "train_seconds": (_NUM, False),
     "env_seconds": (_NUM, False),
     "phases": (_DICT, False),
+    # the timer's real spans that ended in the window (utils/timer.py):
+    # {name: [count, seconds, self_seconds]}, and its counters {name: [count, total]}
+    "spans": (_DICT, False),
+    "counters": (_DICT, False),
     "mfu": (_NUM, False),
     "hbm": (_DICT, False),
     "rss_bytes": (_INT, False),

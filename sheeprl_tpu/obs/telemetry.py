@@ -50,6 +50,7 @@ the loops behave byte-for-byte as before.
 
 from __future__ import annotations
 
+import json
 import os
 import time
 import warnings
@@ -58,10 +59,10 @@ from typing import Any, Dict, Mapping, Optional, Sequence
 import numpy as np
 
 from sheeprl_tpu.obs.compile_monitor import compile_snapshot, install_compile_monitor
-from sheeprl_tpu.obs.jsonl import JsonlEventSink
+from sheeprl_tpu.obs.jsonl import JsonlEventSink, spans_path
 from sheeprl_tpu.obs.profiler import ProfilerWindow, resolve_profiler_config
 from sheeprl_tpu.utils.mfu import peak_flops, program_analysis
-from sheeprl_tpu.utils.timer import timer
+from sheeprl_tpu.utils.timer import aggregate_spans, timer
 
 # cumulative counter keys of a sampler telemetry snapshot (diffed per window)
 _PREFETCH_COUNTERS = (
@@ -353,6 +354,10 @@ class RunTelemetry:
         self._start_step: Optional[int] = None
         self._start_time = 0.0
         self._timer_last: Dict[str, tuple] = {}  # name -> (total, reset generation)
+        # the timer's span ring and counters, read per window: where the last
+        # window's reading stopped (a perf_counter time; the counters' totals)
+        self._span_cursor = self._born = time.perf_counter()
+        self._counter_last: Dict[str, tuple] = {}
         # "analysis" has no backing timer: register_program accounts its one-shot
         # program-introspection wall time there (it already shifts the open train
         # span past itself, so the window would otherwise leak it into `other`)
@@ -646,6 +651,8 @@ class RunTelemetry:
             analysis = self._window_phases["analysis"]
             self._window_phases = {**{k: 0.0 for k in _PHASE_TIMERS}, "analysis": analysis}
             self._prefetch_delta()
+            self._span_cursor = now
+            self._window_counters()
             return
         # harvest EVERY iteration, not just at window boundaries: the metric log
         # sites reset the timer registry on their own (log_every) cadence, and a
@@ -756,6 +763,10 @@ class RunTelemetry:
                     else None
                 ),
             )
+            try:
+                self._write_spans(spans_path(self._sink.path))
+            except OSError as exc:  # the stream and the endpoint still close
+                warnings.warn(f"telemetry: the raw spans could not be written: {exc!r}")
             self._sink.close()
             self._sink = None
         if self.metrics_endpoint is not None:
@@ -789,6 +800,41 @@ class RunTelemetry:
         zero to that phase)."""
         for phase, name in _PHASE_TIMERS.items():
             self._window_phases[phase] += self._timer_delta(name)
+
+    def _window_spans(self) -> Dict[str, list]:
+        """Aggregate of the spans that ended since the last window."""
+        spans = timer.spans_since(self._span_cursor)
+        if spans:
+            self._span_cursor = spans[-1][2]
+        return {k: [v[0], round(v[1], 6), round(v[2], 6)] for k, v in aggregate_spans(spans).items()}
+
+    def _window_counters(self) -> Dict[str, list]:
+        """What the timer's counters gained since the last window."""
+        out = {}
+        for name, (count, total) in list(timer.counters.items()):
+            last_count, last_total = self._counter_last.get(name, (0, 0.0))
+            self._counter_last[name] = (count, total)
+            if count != last_count:
+                out[name] = [count - last_count, total - last_total]
+        return out
+
+    def _write_spans(self, path: str) -> None:
+        """The raw spans that ended since this object was built (of the ring's last
+        ``timer.RING_CAPACITY``; the ring is the process's, so two loops in one process
+        each write both threads' spans), one JSON object a line, start and end as
+        wall-clock seconds like the events' ``time``: what ``sheeprl.py trace`` draws.
+        Appended and stamped with ``rank`` and ``attempt`` as the stream's events are:
+        a supervised restart into the same log dir keeps the attempt before it."""
+        spans = timer.spans_since(self._born)
+        if not spans or os.path.abspath(path) == os.path.abspath(self._sink.path):
+            return
+        to_wall = time.time() - time.perf_counter()
+        with open(path, "a") as fh:
+            for name, start, end, parent, iteration in spans:
+                fh.write(json.dumps({
+                    "name": name, "start": round(start + to_wall, 6), "end": round(end + to_wall, 6),
+                    "parent": parent, "iter": iteration, "rank": self._rank, "attempt": self._attempt,
+                }) + "\n")
 
     def _append_history(self, event: str, payload: Dict[str, Any]) -> None:
         """Feed the in-loop diagnosis history (bounded; same payloads the sink
@@ -1190,6 +1236,10 @@ class RunTelemetry:
             train_seconds=round(train_seconds, 4),
             env_seconds=round(env_seconds, 4),
             phases=phases,
+            # the timer's real spans that ended in this window, {name: [count,
+            # seconds, self_seconds]}, and its counters, {name: [count, total]}
+            spans=self._window_spans() or None,
+            counters=self._window_counters() or None,
             mfu=mfu,
             hbm=hbm,
             rss_bytes=rss,

@@ -14,10 +14,15 @@ Track layout (Chrome trace ``pid``/``tid`` = process/thread rows):
 - one **thread track per telemetry stream** — the rank-0 player/controller,
   each ``telemetry.actor<r>.jsonl``, the learner role stream — so a service
   gang renders as parallel actor/learner timelines;
-- per window, the **phase attribution** becomes a run of slices laid
-  end-to-end across the window's wall span (env → rollout → replay_wait →
-  train → …). Attribution measures shares, not ordering: inside one window the
-  layout order is fixed, the widths are exact;
+- a run that recorded **real spans** (``utils/timer.py``: windows carry a
+  ``spans`` block and the stream has a ``spans*.jsonl`` beside it, the run's
+  last spans with their start, end, parent and iteration) draws those: each
+  window is one ``window`` slice whose args hold its span totals, and the raw
+  spans sit on the same track, nested and in their true order;
+- a stream without a ``spans`` block (serving, runs from before the spans) gets
+  its windows' **phase attribution** as a run of slices laid end-to-end across
+  the window's wall span (env → rollout → replay_wait → train → …): shares,
+  not ordering; inside one window the layout order is fixed, the widths exact;
 - **serving runs** get the same treatment for their batch-tick phases
   (``serve_step`` / ``serve_wait``) plus counter tracks for the session state
   (active sessions, admission queue depth, batch occupancy);
@@ -193,10 +198,18 @@ def _emit_window(tb: _TraceBuilder, pid: int, tid: int, window: Mapping[str, Any
     }
     if window.get("mfu") is not None:
         args["mfu"] = window.get("mfu")
-    cursor = start
-    for name, seconds in _window_spans(window):
-        tb.slice(pid, tid, name, tb.us(cursor), int(seconds * 1e6), args=args)
-        cursor += seconds
+    if window.get("spans"):
+        # the run recorded real spans: no layout is made up. The window is one
+        # slice carrying its totals; the raw spans are drawn by _emit_real_spans
+        args["spans"] = window["spans"]
+        if window.get("counters"):
+            args["counters"] = window["counters"]
+        tb.slice(pid, tid, "window", tb.us(start), int(wall * 1e6), args=args, cat="window")
+    else:
+        cursor = start
+        for name, seconds in _window_spans(window):
+            tb.slice(pid, tid, name, tb.us(cursor), int(seconds * 1e6), args=args)
+            cursor += seconds
     if window.get("sps") is not None:
         tb.counter(pid, "sps", tb.us(t_end), {"sps": _f(window.get("sps"))})
     serve = window.get("serve")
@@ -212,6 +225,30 @@ def _emit_window(tb: _TraceBuilder, pid: int, tid: int, window: Mapping[str, Any
         )
         if serve.get("occupancy") is not None:
             tb.counter(pid, "occupancy", tb.us(t_end), {"occupancy": _f(serve.get("occupancy"))})
+
+
+def _spans_beside(stream_path: str) -> List[Dict[str, Any]]:
+    """The raw spans ``RunTelemetry.close`` wrote beside a stream
+    (``telemetry.x.jsonl`` -> ``spans.x.jsonl``); none for a stream without."""
+    from sheeprl_tpu.obs.jsonl import spans_path
+
+    path = spans_path(stream_path)
+    if not os.path.isfile(path):
+        return []
+    with open(path) as fh:
+        rows = [json.loads(line) for line in fh if line.strip()]
+    return [r for r in rows if _f(r.get("end")) >= _f(r.get("start")) > 0]
+
+
+def _emit_real_spans(tb: _TraceBuilder, pid: int, tid: int, spans: Sequence[Mapping[str, Any]]) -> None:
+    """Real spans at their own start and length, parents before children so that
+    viewers nest them (a child lies inside its parent on the recording clock)."""
+    for span in sorted(spans, key=lambda r: (_f(r["start"]), -_f(r["end"]))):
+        tb.slice(
+            pid, tid, str(span["name"]), tb.us(_f(span["start"])),
+            int(round((_f(span["end"]) - _f(span["start"])) * 1e6)),
+            args={"iter": span.get("iter"), "parent": span.get("parent")}, cat="span",
+        )
 
 
 def _emit_dataflow_flows(
@@ -406,9 +443,11 @@ def build_trace(run_dir: str) -> Dict[str, Any]:
         raise FileNotFoundError(f"no telemetry*.jsonl stream found under {run_dir!r}")
     base = run_dir if os.path.isdir(run_dir) else os.path.dirname(run_dir)
     events = merge_streams([load_stream(p, base_dir=base) for p in streams])
+    real_spans = {os.path.relpath(p, base): _spans_beside(p) for p in streams}
 
     tb = _TraceBuilder()
     times = [_f(e.get("time")) for e in events if _f(e.get("time")) > 0]
+    times += [_f(r["start"]) for rows in real_spans.values() for r in rows]
     if times:
         # anchor at the earliest WINDOW START (window stamps mark the end)
         starts = [
@@ -439,6 +478,9 @@ def build_trace(run_dir: str) -> Dict[str, Any]:
             window_tracks.append((pid, tid, event))
         else:
             _emit_instants(tb, pid, tid, event)
+    for stream, spans in real_spans.items():
+        if spans:
+            _emit_real_spans(tb, *track_of({"stream": stream}), spans)
     _emit_dataflow_flows(tb, window_tracks)
 
     return {

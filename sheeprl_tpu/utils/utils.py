@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from sheeprl_tpu.config.dotdict import dotdict
+from sheeprl_tpu.utils.timer import timer
 
 
 # ---------------------------------------------------------------------------------
@@ -269,7 +270,18 @@ class ActPlacement:
         path that reads keys outside the act view would break identically on all
         placements, rather than only when an accelerator is attached."""
         view = self._select(params)
-        return packed_device_put(view, self.cpu_device) if self.on_cpu else view
+        if not self.on_cpu:
+            return view
+        # packed_device_put, spelled out so that its two halves are spans of the view
+        # alone (place() moves keys the same way and opens none): the two lines below
+        # must stay equal to packed_device_put's two
+        with timer("act_view"):
+            with timer("act_view.fetch"):  # pack on the device, wait for it, copy to the host
+                host = packed_device_get(view)
+            if not timer.disabled:
+                timer.count("act_view_bytes", sum(x.nbytes for x in jax.tree_util.tree_leaves(host)))
+            with timer("act_view.place"):  # one device_put a leaf onto the host CPU backend
+                return jax.tree_util.tree_map(lambda x: jax.device_put(x, self.cpu_device), host)
 
     def place(self, tree: Any) -> Any:
         """Land an arbitrary pytree (PRNG key, frozen exploration params) host-side

@@ -4,6 +4,7 @@ from __future__ import annotations
 
 import json
 import math
+import os
 
 import jax
 import jax.numpy as jnp
@@ -675,3 +676,148 @@ def test_window_xla_gauges_after_a_profile_analysis(tmp_path):
     assert gauges["Perf/xla_mxu_fraction"] == pytest.approx(0.5)
     assert gauges["Perf/xla_idle_fraction"] == pytest.approx(0.05)
     t.close(20)
+
+
+# ---------------------------------------------------------------------------------
+# the timer's real spans: window.spans / window.counters, and spans.jsonl on close
+# ---------------------------------------------------------------------------------
+def _one_iteration(t, iteration):
+    import time as _time
+
+    t.iteration = iteration
+    with t("Time/env_interaction_time"):
+        with t("act"):
+            _time.sleep(0.004)
+        with t("env_step"):
+            _time.sleep(0.001)
+    with t("Time/train_time"):
+        with t("act_view"):
+            t.count("act_view_bytes", 1024)
+            _time.sleep(0.002)
+
+
+@pytest.mark.parametrize("close", ["clean", "unclean"])
+def test_window_spans_block_and_spans_jsonl(tmp_path, close):
+    """Each window carries the spans that ENDED in it as {name: [count, seconds,
+    self_seconds]} and the counters' gain; close (the loop's, or the crash path's that a
+    StopRun from the harness takes) writes the ring's raw spans beside the stream."""
+    import collections
+
+    from sheeprl_tpu.obs.schema import validate_stream
+    from sheeprl_tpu.obs.telemetry import close_all_live_telemetry
+    from sheeprl_tpu.utils.timer import timer as t
+
+    saved = (t.timers, t.disabled, t.ring, t.counters)
+    t.timers, t.disabled, t.ring, t.counters = {}, False, collections.deque(maxlen=64), {}
+    try:
+        tel = build_telemetry(FakeFabric(), _cfg(telemetry={"enabled": True}, log_every=100), str(tmp_path))
+        _one_iteration(t, 0)  # before the anchor: in no window
+        tel.step(0)
+        _one_iteration(t, 1)
+        _one_iteration(t, 2)
+        tel.step(100)
+        _one_iteration(t, 3)
+        tel.step(200)
+        _one_iteration(t, 4)  # after the last window: only in spans.jsonl
+        if close == "clean":
+            tel.close(200)
+        else:
+            close_all_live_telemetry(clean_exit=False)
+        stream = str(tmp_path / "telemetry.jsonl")
+        assert validate_stream(stream) == []  # `spans` and `counters` are declared
+        first, second = [e for e in read_events(stream) if e["event"] == "window"][:2]
+        assert {k: v[0] for k, v in first["spans"].items()} == {
+            "Time/env_interaction_time": 2, "act": 2, "env_step": 2, "Time/train_time": 2, "act_view": 2,
+        }
+        assert second["spans"]["act"][0] == 1 and second["counters"] == {"act_view_bytes": [1, 1024.0]}
+        assert first["counters"] == {"act_view_bytes": [2, 2048.0]}
+        count, seconds, self_seconds = first["spans"]["Time/env_interaction_time"]
+        children = first["spans"]["act"][1] + first["spans"]["env_step"][1]
+        assert seconds >= children and abs(self_seconds - (seconds - children)) < 1e-5
+        assert first["spans"]["act"][1] == first["spans"]["act"][2] >= 0.008  # a leaf: all of it is its own
+        # the phases are what they were: the span block adds, it does not replace
+        assert abs(first["phases"]["env"] - seconds) < 1e-3
+        rows = [json.loads(line) for line in open(tmp_path / "spans.jsonl")]
+        assert len(rows) == 25 and {r["iter"] for r in rows} == {0, 1, 2, 3, 4}
+        assert all(r["end"] >= r["start"] > 1e9 for r in rows)  # wall-clock seconds, like `time`
+        assert {(r["name"], r["parent"]) for r in rows} == {
+            ("Time/env_interaction_time", None), ("act", "Time/env_interaction_time"),
+            ("env_step", "Time/env_interaction_time"), ("Time/train_time", None), ("act_view", "Time/train_time"),
+        }
+    finally:
+        t.timers, t.disabled, t.ring, t.counters = saved
+
+
+def test_telemetry_off_writes_no_spans_file(tmp_path):
+    from sheeprl_tpu.utils.timer import timer as t
+
+    saved_disabled, t.disabled = t.disabled, False
+    try:
+        tel = build_telemetry(FakeFabric(), _cfg(telemetry={"enabled": False}), str(tmp_path))
+        with t("Time/train_time"):
+            pass
+        tel.step(0)
+        tel.step(500)
+        tel.close(500)
+        assert list(tmp_path.iterdir()) == []
+    finally:
+        t.disabled = saved_disabled
+
+
+@pytest.mark.parametrize("name, beside", [
+    ("telemetry.jsonl", "spans.jsonl"),
+    ("telemetry.learner.jsonl", "spans.learner.jsonl"),
+    ("events.jsonl", "events.spans.jsonl"),  # a configured jsonl_path: any name
+    ("events.learner.jsonl", "events.learner.spans.jsonl"),
+    ("stream", "stream.spans.jsonl"),
+    ("spans.jsonl", "spans.spans.jsonl"),
+])
+def test_spans_path_is_beside_the_stream_and_never_the_stream(name, beside):
+    from sheeprl_tpu.obs.jsonl import spans_path
+
+    assert spans_path(os.path.join("a", name)) == os.path.join("a", beside)
+
+
+@pytest.mark.parametrize("case", ["custom_stream_name", "spans_unwritable", "restart_appends"])
+def test_close_keeps_the_stream_whatever_happens_to_the_spans(tmp_path, case):
+    """`close()` writes the raw spans BESIDE the stream: a stream of any name survives
+    it whole, a spans file that cannot be written costs a warning and neither the
+    summary nor the closing of the sink, and a restart into the same log dir appends
+    its attempt's spans to those before it."""
+    import collections
+
+    from sheeprl_tpu.utils.timer import timer as t
+
+    saved = (t.timers, t.disabled, t.ring, t.counters)
+    t.timers, t.disabled, t.ring, t.counters = {}, False, collections.deque(maxlen=64), {}
+    stream = tmp_path / ("events.jsonl" if case == "custom_stream_name" else "telemetry.jsonl")
+    spans = tmp_path / ("events.spans.jsonl" if case == "custom_stream_name" else "spans.jsonl")
+    try:
+        _one_iteration(t, 0)  # before this object: another run's, in the process's ring
+        for attempt in range(2 if case == "restart_appends" else 1):
+            tel = build_telemetry(
+                FakeFabric(),
+                _cfg(telemetry={"enabled": True, "jsonl_path": str(stream), "attempt": attempt}, log_every=100),
+                str(tmp_path),
+            )
+            tel.step(0)
+            _one_iteration(t, 1)
+            tel.step(100)
+            if case == "spans_unwritable":
+                spans.mkdir()
+                with pytest.warns(UserWarning, match="raw spans could not be written"):
+                    tel.close(100)
+                assert tel._sink is None
+            else:
+                tel.close(100)
+        events = read_events(str(stream))
+        assert [e["event"] for e in events if e["event"] in ("start", "summary")] == (
+            ["start", "summary"] * (2 if case == "restart_appends" else 1)
+        )
+        if case != "spans_unwritable":
+            rows = [json.loads(line) for line in open(spans)]
+            assert {r["iter"] for r in rows} == {1}  # only what ended while this object lived
+            assert [r["attempt"] for r in rows] == [0] * 5 + ([1] * 5 if case == "restart_appends" else [])
+            assert {r["rank"] for r in rows} == {0}
+    finally:
+        t.timers, t.disabled, t.ring, t.counters = saved

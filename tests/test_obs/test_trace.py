@@ -251,3 +251,48 @@ def test_trace_serve_stream_gets_session_counter_tracks(tmp_path):
     assert {"serve_step", "serve_wait"} <= slice_names  # the batch-tick track
     counters = {e["name"] for e in trace["traceEvents"] if e["ph"] == "C"}
     assert {"sessions", "occupancy"} <= counters  # the session tracks
+
+
+@pytest.mark.parametrize("recorded", ["spans", "phases_only"])
+def test_trace_draws_real_spans_where_the_run_recorded_them(tmp_path, recorded):
+    """A window with a `spans` block is one `window` slice and the stream's
+    spans.jsonl is drawn as it happened: true starts, children inside parents, in
+    order. Without the block (serving, runs from before the spans) the phase layout
+    is still laid end to end."""
+    base = str(tmp_path / "run")
+    t0 = 1_700_000_200.0
+    window = {
+        "event": "window", "time": t0 + 1.0, "step": 8, "window": 0, "final": False, "wall_seconds": 1.0,
+        "sps": 8.0, "phases": {"env": 0.3, "train": 0.6, "other": 0.1},
+    }
+    if recorded == "spans":
+        window["spans"] = {"Time/env_interaction_time": [1, 0.3, 0.05], "act": [1, 0.25, 0.25],
+                           "Time/train_time": [1, 0.6, 0.2], "act_view": [1, 0.4, 0.4]}
+        window["counters"] = {"act_view_bytes": [1, 4096.0]}
+    _write_stream(os.path.join(base, "telemetry.jsonl"), [{"event": "start", "time": t0, "every": 8}, window])
+    rows = [  # written in the order the spans ENDED, as the ring holds them
+        {"name": "act", "start": t0 + 0.05, "end": t0 + 0.30, "parent": "Time/env_interaction_time", "iter": 3},
+        {"name": "Time/env_interaction_time", "start": t0 + 0.02, "end": t0 + 0.32, "parent": None, "iter": 3},
+        {"name": "act_view", "start": t0 + 0.55, "end": t0 + 0.95, "parent": "Time/train_time", "iter": 3},
+        {"name": "Time/train_time", "start": t0 + 0.35, "end": t0 + 0.95, "parent": None, "iter": 3},
+    ]
+    if recorded == "spans":
+        with open(os.path.join(base, "spans.jsonl"), "w") as fh:
+            fh.writelines(json.dumps(r) + "\n" for r in rows)
+    trace = build_trace(base)
+    _assert_perfetto_loadable(trace)
+    slices = [e for e in trace["traceEvents"] if e["ph"] == "X"]
+    names = [e["name"] for e in slices]
+    if recorded == "phases_only":
+        assert names == ["env", "train", "other"]  # the made-up layout, for streams without spans
+        return
+    assert not {"env", "train", "other"} & set(names)  # no slice order is made up
+    drawn = {e["name"]: e for e in slices}
+    assert drawn["window"]["dur"] == 1_000_000 and drawn["window"]["args"]["spans"]["act"] == [1, 0.25, 0.25]
+    assert drawn["window"]["args"]["counters"] == {"act_view_bytes": [1, 4096.0]}
+    spans = [e for e in slices if e["cat"] == "span"]
+    assert [e["name"] for e in spans] == ["Time/env_interaction_time", "act", "Time/train_time", "act_view"]
+    assert [(e["ts"], e["dur"]) for e in spans] == [(20_000, 300_000), (50_000, 250_000), (350_000, 600_000), (550_000, 400_000)]
+    for child, parent in (("act", "Time/env_interaction_time"), ("act_view", "Time/train_time")):
+        c, p = drawn[child], drawn[parent]
+        assert p["ts"] <= c["ts"] and c["ts"] + c["dur"] <= p["ts"] + p["dur"] and c["args"]["parent"] == parent
