@@ -76,8 +76,12 @@ def compute_lambda_values(
 def prepare_obs(
     fabric, obs: Dict[str, np.ndarray], *, cnn_keys: Sequence[str] = (), mlp_keys: Sequence[str] = (), num_envs: int = 1
 ) -> Dict[str, np.ndarray]:
-    # host arrays: the act program's placement follows the player params (see the
-    # dreamer_v3 prepare_obs note on avoiding a per-frame accelerator round-trip)
+    # host arrays: the act program runs where the player's params live
+    # (utils.ActPlacement), and jit moves the frame there. On the host CPU backend
+    # (every loop but the coupled Dreamer-V3 one) that is no transfer; in the
+    # coupled Dreamer-V3 loop on one attached chip it is one host-to-device copy of
+    # the frame per env step, inside the `act` span (dreamer_v3.settle_act_placement;
+    # `act_steady_ms`, ledger, PR 28)
     out: Dict[str, np.ndarray] = {}
     for k in cnn_keys:
         v = np.asarray(obs[k], dtype=np.float32)

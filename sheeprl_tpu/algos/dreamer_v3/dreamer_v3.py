@@ -432,6 +432,12 @@ class _InlineTrainer:
     # at train rounds; the loop then postpones an off-round checkpoint to the next
     # train round (or to close())
     defers_checkpoints = False
+    # the donated train program and the player run on one thread, one after the
+    # other, and the loop rebinds its act view from every train() call: the view
+    # may be this trainer's own device buffers (run_dreamer decides, with the
+    # fabric's device count). A channel-backed trainer receives its view as host
+    # bytes from a learner that donates concurrently, and does not say this
+    acts_on_own_buffers = True
 
     def __init__(self, *, fabric, cfg, act, train_phase, params, opt_state, moments_state):
         self.fabric = fabric
@@ -485,6 +491,21 @@ class _InlineTrainer:
         state here (paired with the shutdown sentinel) for a deferred last
         checkpoint; inline training has nothing deferred."""
         return None
+
+
+def settle_act_placement(act: ActPlacement, trainer, fabric) -> None:
+    """Where the player runs, decided here and nowhere else, from the trainer's class
+    and the fabric's device count, before the first view is taken and the key placed
+    (the placements, their costs and who may alias: ``ActPlacement``'s docstring).
+
+    An inline trainer on one accelerator device: on that device, on the trainer's
+    own buffers. The three player programs follow their arguments, ``prepare_obs``
+    hands them host frames, and ``np.asarray(actions)`` is the loop's only wait for
+    the device. Anything else keeps the host placement: a channel-backed trainer's
+    view arrives as host bytes, and what a player over replicated or model-sharded
+    parameters costs an env step on a mesh is not measured (PERF.md section 7)."""
+    if getattr(trainer, "acts_on_own_buffers", False) and fabric.num_devices == 1:
+        act.alias_device()
 
 
 def run_dreamer(
@@ -654,16 +675,7 @@ def run_dreamer(
         state_shardings=build_state_shardings(fabric, params, opt_state, moments_state),
     )
 
-    # Act/train device split (shared ActPlacement design, utils/utils.py): with the
-    # fabric on an accelerator the per-step player program runs on the host CPU
-    # backend — per-dispatch latency to a TPU dwarfs the one-frame forward; the
-    # reference pays per-step .cpu() syncs instead (dreamer_v3.py:630-664) — while
-    # the fused multi-gradient-step train program runs on the accelerator. Only the
-    # player-visible params cross back per train call, as one packed transfer.
     act = ActPlacement(fabric, lambda p: {"world_model": p["world_model"], "actor": p["actor"]})
-    act_params = act.view(params)
-    key = act.place(key)
-
     trainer = (trainer_factory or _InlineTrainer)(
         fabric=fabric,
         cfg=cfg,
@@ -673,6 +685,9 @@ def run_dreamer(
         opt_state=opt_state,
         moments_state=moments_state,
     )
+    settle_act_placement(act, trainer, fabric)
+    act_params = act.view(params)
+    key = act.place(key)
 
     # counters (reference dreamer_v3.py:571-597)
     start_iter = (state["iter_num"] // world_size) + 1 if state is not None else 1

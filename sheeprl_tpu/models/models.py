@@ -234,9 +234,11 @@ class LayerNormGRUCell(nn.Module):
             # the fused Pallas step (matmul + layernorm + gating in one VMEM pass)
             # is the branch a TPU lowering takes when the shape is one the kernel
             # compiles for; platform_dependent chooses by LOWERING platform, so
-            # the CPU-placed act program of a TPU process (ActPlacement) lowers
-            # the XLA reference and never sees Mosaic — same math, parity-tested
-            # in tests/test_ops.
+            # an act program placed on the host CPU of a TPU process (ActPlacement)
+            # lowers the XLA reference and never sees Mosaic, while the coupled
+            # Dreamer-V3 player, which runs on the chip, takes the kernel at
+            # num_envs rows (chip_smoke.py checks one row) — same math,
+            # parity-tested in tests/test_ops.
             from sheeprl_tpu import ops
 
             hx_d = hx.astype(self.dtype)

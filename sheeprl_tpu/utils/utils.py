@@ -206,8 +206,12 @@ def packed_device_get(tree: Any) -> Any:
 
     ``jax.device_get`` issues one device→host transfer per leaf, so a ~60-leaf
     params tree is ~60 transfers. Packing all leaves into a single flat device
-    array first makes it one per distinct dtype (usually one). What a transfer
-    costs on an attached chip is not measured (ROADMAP S3).
+    array first makes it one per distinct dtype (usually one). On an attached
+    v5e chip the pack, the wait and the copy of Dreamer-V3 XL's 785 MB act view
+    took 255 ms and L's 509 MB 154 ms (``act_view_sync_ms``, ledger, PR 27):
+    right for a few values (metrics, a key), too dear for a big tree every
+    train call, which is why the coupled Dreamer-V3 loop no longer fetches its
+    view at all (:class:`ActPlacement`).
     """
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     if not leaves:
@@ -248,28 +252,57 @@ def host_cpu_device() -> jax.Device:
 
 
 class ActPlacement:
-    """Act/train device-placement split, shared by every per-step-acting algorithm.
+    """Where the per-step act program runs, and how the player's parameters get
+    there. Shared by every per-step-acting algorithm; three placements:
 
-    The one-frame act program runs on the host CPU backend, next to the env it
-    feeds, while the fused train program runs on the accelerator; only the
-    player-visible subtree (``select``) crosses back per train call, as one
-    packed transfer. Whether acting on the host beats acting on an attached
-    chip is not measured (ROADMAP S3). On a CPU fabric everything is the
-    identity, so call sites need no branching.
+    - **CPU fabric**: everything is the identity, so call sites need no branching.
+    - **host** (an accelerator fabric, the default): the one-frame act program runs
+      on the host CPU backend, next to the env it feeds, while the fused train
+      program runs on the accelerator; the player-visible subtree (``select``) is
+      packed on the device, fetched and placed on the CPU device once per train
+      call. Every loop but the coupled Dreamer-V3 one acts this way, and so do the
+      channel-backed Dreamer-V3 trainers, whose view arrives as host bytes. For a
+      small player next to its env nothing measures the alternative (PERF.md
+      section 7).
+    - **aliased** (:meth:`alias_device`): the view is ``select(params)`` itself,
+      the trainer's own device buffers, and keys stay where JAX made them, beside
+      those buffers on the one-device fabric's device, so the player's programs
+      compile for the accelerator and follow their arguments. Nothing is packed,
+      fetched, placed or committed: one committed argument among uncommitted ones
+      commits a jit's outputs, and the player's carry then compiles each of its
+      programs a second time, the reset in mid-run (seen on the chip, PERF.md
+      section 6, PR 28). Only a caller whose train program and player run one
+      after the other on one thread, and which rebinds its view from every train
+      call, may ask for it (a donated tree must never be read again):
+      ``run_dreamer`` with an inline trainer on a one-device fabric. There the
+      host placement cost Dreamer-V3 XL about 590 ms of a 990 ms cycle (ledger,
+      PR 27); what the aliased one costs is in the ledger's PR 28 lines.
     """
 
     def __init__(self, fabric, select: Optional[Callable[[Any], Any]] = None) -> None:
         self.on_cpu = fabric.device.platform != "cpu"
+        self.aliased = False
         self.cpu_device = host_cpu_device()
         self._select = select or (lambda p: p)
 
+    def alias_device(self) -> None:
+        """Act on the fabric's device, on the caller's own parameter buffers (the
+        class docstring says who may). On a CPU fabric this changes nothing."""
+        if self.on_cpu:
+            self.on_cpu, self.aliased = False, True
+
     def view(self, params: Any) -> Any:
-        """The player-visible act params: ``select(params)``, landed host-side.
+        """The player-visible act params: ``select(params)``, landed host-side
+        unless aliased. The counter ``act_view_bytes`` counts the bytes COPIED.
 
         Note ``select`` narrows the tree on EVERY fabric, CPU included — a test()
         path that reads keys outside the act view would break identically on all
         placements, rather than only when an accelerator is attached."""
         view = self._select(params)
+        if self.aliased:
+            with timer("act_view"):  # kept: `act_first_use_ms` and the trace reader look for it
+                timer.count("act_view_bytes", 0)
+                return view
         if not self.on_cpu:
             return view
         # packed_device_put, spelled out so that its two halves are spans of the view
@@ -285,7 +318,8 @@ class ActPlacement:
 
     def place(self, tree: Any) -> Any:
         """Land an arbitrary pytree (PRNG key, frozen exploration params) host-side
-        so the act program's dispatch and key chain never touch the accelerator."""
+        so the act program's dispatch and key chain never touch the accelerator.
+        Aliased, the tree stays as it is: on the fabric's device, uncommitted."""
         return packed_device_put(tree, self.cpu_device) if self.on_cpu else tree
 
 
