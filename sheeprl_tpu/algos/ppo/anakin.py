@@ -22,6 +22,13 @@ reference semantics):
 - ``a2c`` — one full-rollout gradient step per iteration, no ratio clipping
   (``algos/a2c/loss.py``).
 
+A third shape of the same step is the SEQUENCE policy (``algo.policy=sequence``,
+``algos/ppo/sequence_policy.py``): a sequence model that reads one token a step and
+carries its own state. It enters through one seam, :func:`_make_sequence_program`: a
+carry through the rollout's scan, minibatches of whole sequences, a teacher-forced
+forward in the loss and a mask for the steps whose action the env ignores. The MLP
+flavours' program is untouched by it.
+
 Phase attribution: a fused program has no host-visible env/train boundary, so
 the loop splits each call's wall time between the ``rollout`` phase (fused
 env+act, new in the schema) and ``train`` by a one-shot MEASURED wall time of
@@ -53,6 +60,7 @@ from sheeprl_tpu.algos.a2c.loss import policy_loss as a2c_policy_loss
 from sheeprl_tpu.algos.a2c.loss import value_loss as a2c_value_loss
 from sheeprl_tpu.algos.ppo.agent import build_agent, make_dists, policy_output
 from sheeprl_tpu.algos.ppo.loss import entropy_loss, policy_loss, value_loss
+from sheeprl_tpu.algos.ppo.sequence_policy import SequencePolicy, build_sequence_policy
 from sheeprl_tpu.algos.ppo.utils import test
 from sheeprl_tpu.analysis.programs import register_fused_program
 from sheeprl_tpu.config import instantiate
@@ -103,6 +111,17 @@ def sparse_truncation_bootstrap(values_fn, traj, gamma, num_steps, num_envs, max
     return rewards + flat_bonus.reshape(num_steps, num_envs, 1)
 
 
+def _episode_stats(info, done_f):
+    """[return sum, length sum, count] of the episodes that ended this step."""
+    return jnp.stack(
+        [
+            jnp.sum(info["episode_return"] * done_f),
+            jnp.sum(info["episode_length"].astype(jnp.float32) * done_f),
+            jnp.sum(done_f),
+        ]
+    )
+
+
 def _flavor(cfg) -> str:
     name = str(cfg.algo.name)
     if name.startswith("a2c"):
@@ -139,6 +158,8 @@ def make_anakin_program(
     Module-level (like ``ppo.make_train_phase``) so the AOT lowering tests
     exercise exactly the program main() ships.
     """
+    if isinstance(agent, SequencePolicy):
+        return _make_sequence_program(agent, env, cfg, fabric, tx, total_num_envs)
     flavor = _flavor(cfg)
     world_size = fabric.world_size
     T = int(cfg.algo.rollout_steps)
@@ -228,13 +249,7 @@ def make_anakin_program(
                 # critic over every step for a ~0.2%-nonzero mask
                 transition["terminal_observation"] = info["terminal_observation"]
                 transition["truncated"] = info["truncated"]
-            step_stats = jnp.stack(
-                [
-                    jnp.sum(info["episode_return"] * done_f),
-                    jnp.sum(info["episode_length"].astype(jnp.float32) * done_f),
-                    jnp.sum(done_f),
-                ]
-            )
+            step_stats = _episode_stats(info, done_f)
             return (env_state, next_obs, key), (transition, step_stats)
 
         (env_state, obs, key), (traj, step_stats) = jax.lax.scan(
@@ -408,6 +423,176 @@ def make_anakin_program(
     return fused, rollout_only, updates_per_iter
 
 
+def _masked(x, mask, reduction: str):
+    """``algos/ppo/loss.py``'s reductions over the steps that count (``mask`` 1)."""
+    total = jnp.sum(x * mask)
+    if reduction == "mean":
+        return total / jnp.maximum(mask.sum(), 1.0)
+    if reduction == "sum":
+        return total
+    raise ValueError(f"the sequence flavour reduces its loss by mean or sum, not {reduction!r}")
+
+
+def _mean_counters(stacked):
+    """Scan-stacked counters -> scalars: the mean a decode step, or a gradient step."""
+    return {k: v.mean() for k, v in stacked.items()} if stacked else {}
+
+
+def _make_sequence_program(policy: SequencePolicy, env, cfg, fabric, tx, total_num_envs):
+    """The fused program for a sequence policy: (anakin_step, rollout_only, updates_per_iter)
+    as :func:`make_anakin_program` gives them, with one more output at the end of
+    ``anakin_step``'s: ``{"counters", "record"}``, the expert layers' counters as scalars
+    and what the iteration's own steps produced (the trajectory, the experts chosen in the
+    rollout and in each gradient step, each gradient step's loss parts and sequences).
+
+    One rollout is one episode: the env ends every episode after exactly ``rollout_steps``
+    steps, so the policy's carry starts empty in every call and no cache outlives one.
+    A minibatch is ``per_rank_batch_size`` whole sequences; the loss and the advantage
+    statistics leave out the steps whose action the env ignored (``info["action_mask"]``).
+    The parts carry ``jax.named_scope`` names (``rollout``, ``update``, ``gae``, ``ppo_loss``,
+    ``optimizer``, and the trunk's own): metadata, which changes no program."""
+    if _flavor(cfg) != "ppo":
+        raise ValueError("a sequence policy is trained by the ppo flavour")
+    if fabric.world_size > 1:
+        raise ValueError("the sequence policy runs on one device: there is no expert or data axis for it yet")
+    T, E = int(cfg.algo.rollout_steps), total_num_envs
+    if env.spec.episode_steps != T:
+        raise ValueError(
+            f"a sequence policy needs episodes of exactly algo.rollout_steps={T} steps "
+            f"(the env's are {env.spec.episode_steps}): every rollout begins at a reset"
+        )
+    gamma, gae_lambda = float(cfg.algo.gamma), float(cfg.algo.gae_lambda)
+    loss_reduction = cfg.algo.loss_reduction
+    vf_coef = float(cfg.algo.get("vf_coef", 1.0))
+    clip_vloss = bool(cfg.algo.get("clip_vloss", False))
+    normalize_advantages = bool(cfg.algo.get("normalize_advantages", False))
+    max_grad_norm = float(cfg.algo.get("max_grad_norm", 0.0) or 0) or None
+    learn_on = learn_stats.enabled(cfg)
+    batch_sequences = min(int(cfg.algo.per_rank_batch_size), E)
+    if E % batch_sequences:
+        raise ValueError(f"algo.per_rank_batch_size={batch_sequences} sequences must divide env.num_envs={E}")
+    num_minibatches = E // batch_sequences
+    update_epochs = int(cfg.algo.get("update_epochs", 1))
+    updates_per_iter = update_epochs * num_minibatches
+
+    def rollout_phase(params, env_state, obs, key):
+        """T decode steps of batch E through the policy's caches, from an empty carry."""
+
+        def body(carry, _):
+            env_state, obs, key, state = carry
+            key, step_key = jax.random.split(key)
+            logits, values, state, aux = policy.step(params, state, obs)
+            actions = jax.random.categorical(step_key, logits).astype(jnp.int32)
+            logprob = jnp.take_along_axis(jax.nn.log_softmax(logits), actions[:, None], axis=-1)[:, 0]
+            env_state, next_obs, reward, done, info = env.step(env_state, actions)
+            done_f = done.astype(jnp.float32)
+            transition = {
+                "tokens": obs,
+                "actions": actions,
+                "logprobs": logprob,
+                "values": values,
+                "rewards": reward.astype(jnp.float32),
+                "dones": done_f,
+                "mask": info["action_mask"].astype(jnp.float32),
+            }
+            if aux:
+                transition["route_ids"] = aux["route_ids"]
+            step_stats = _episode_stats(info, done_f)
+            return (env_state, next_obs, key, state), (transition, step_stats, aux.get("counters", {}))
+
+        with jax.named_scope("rollout"):
+            (env_state, obs, key, _), (traj, step_stats, counters) = jax.lax.scan(
+                body, (env_state, obs, key, policy.initial_carry(E)), None, length=T
+            )
+        return env_state, obs, key, traj, step_stats.sum(axis=0), _mean_counters(counters)
+
+    def loss_fn(params, batch, clip_coef, ent_coef):
+        logits, new_values, aux = policy.forward(params, batch["tokens"])
+        with jax.named_scope("ppo_loss"):
+            mask = batch["mask"]
+            logp_all = jax.nn.log_softmax(logits, axis=-1)
+            logprob = jnp.take_along_axis(logp_all, batch["actions"][..., None], axis=-1)[..., 0]
+            entropy = -jnp.sum(jnp.exp(logp_all) * logp_all, axis=-1)
+            advantages = batch["advantages"]
+            if normalize_advantages:
+                mean = _masked(advantages, mask, "mean")
+                std = jnp.sqrt(_masked(jnp.square(advantages - mean), mask, "mean"))
+                advantages = (advantages - mean) / (std + 1e-8)
+            pg_loss = _masked(policy_loss(logprob, batch["logprobs"], advantages, clip_coef, "none"), mask, loss_reduction)
+            v_loss = _masked(
+                value_loss(new_values, batch["values"], batch["returns"], clip_coef, clip_vloss, "none"),
+                mask, loss_reduction,
+            )
+            ent_loss = _masked(entropy_loss(entropy, "none"), mask, loss_reduction)
+            loss = pg_loss + vf_coef * v_loss + ent_coef * ent_loss
+        return loss, (jnp.stack([pg_loss, v_loss, ent_loss]), aux)
+
+    def train_phase(params, opt_state, traj, train_key, clip_coef, ent_coef):
+        with jax.named_scope("gae"):
+            # every episode ends with the rollout: the value after the last step is masked out
+            returns, advantages = gae(
+                traj["rewards"], traj["values"], traj["dones"], jnp.zeros((E,), jnp.float32), T, gamma, gae_lambda
+            )
+        keys = ("tokens", "actions", "logprobs", "values", "mask")
+        sequences = {k: jnp.swapaxes(traj[k], 0, 1) for k in keys}  # [E, T]: a row is a sequence
+        sequences["returns"] = jnp.swapaxes(returns, 0, 1)
+        sequences["advantages"] = jnp.swapaxes(advantages, 0, 1)
+
+        def grad_step(carry, idx):
+            params, opt_state = carry
+            batch = {k: jnp.take(v, idx, axis=0) for k, v in sequences.items()}
+            grads, (parts, aux) = jax.grad(loss_fn, has_aux=True)(params, batch, clip_coef, ent_coef)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = tx.update(grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
+            learn = learn_stats.maybe(learn_on, lambda: {
+                **learn_stats.group_stats(
+                    "policy", grads=grads, updates=updates, params=params, opt_state=opt_state, clip=max_grad_norm
+                ),
+                "Learn/loss/policy": parts[0],
+                "Learn/loss/value": parts[1],
+                "Learn/loss/entropy": parts[2],
+            })
+            out = {"losses": parts, "sequences": idx, "learn": learn}
+            if aux:
+                out["route_ids"], out["counters"] = aux["route_ids"], aux["counters"]
+            return (params, opt_state), out
+
+        def epoch_body(carry, epoch_key):
+            order = jax.random.permutation(epoch_key, E).reshape(num_minibatches, batch_sequences)
+            return jax.lax.scan(grad_step, carry, order)
+
+        (params, opt_state), out = jax.lax.scan(
+            epoch_body, (params, opt_state), jax.random.split(train_key, update_epochs)
+        )
+        # [epochs, minibatches, ...] -> [gradient steps, ...]
+        out = jax.tree_util.tree_map(lambda x: x.reshape(updates_per_iter, *x.shape[2:]), out)
+        return params, opt_state, out
+
+    def anakin_step(params, opt_state, env_state, obs, key, stats, clip_coef, ent_coef):
+        key, train_key = jax.random.split(key)
+        env_state, obs, key, traj, ep_stats, rollout_counters = rollout_phase(params, env_state, obs, key)
+        with jax.named_scope("update"):
+            params, opt_state, out = train_phase(params, opt_state, traj, train_key, clip_coef, ent_coef)
+        new_stats = {
+            "ep_return_sum": stats["ep_return_sum"] + ep_stats[0],
+            "ep_length_sum": stats["ep_length_sum"] + ep_stats[1],
+            "ep_count": stats["ep_count"] + ep_stats[2],
+            "losses": out["losses"].mean(axis=0),
+        }
+        counters = {f"rollout_{k}": v for k, v in rollout_counters.items()}
+        counters.update({f"update_{k}": v for k, v in _mean_counters(out.get("counters")).items()})
+        record = {"traj": traj, "losses": out["losses"], "sequences": out["sequences"]}
+        if "route_ids" in out:
+            record["update_route_ids"] = out["route_ids"]
+        learn = learn_stats.reduce_stacked(out["learn"])
+        return params, opt_state, env_state, obs, key, new_stats, learn, {"counters": counters, "record": record}
+
+    fused = jax.jit(anakin_step, donate_argnums=(0, 1, 2, 3, 4))
+    rollout_only = jax.jit(rollout_phase)
+    return fused, rollout_only, updates_per_iter
+
+
 @register_fused_program(
     "ppo.anakin_step",
     min_donated=10,
@@ -559,7 +744,11 @@ def run_anakin(fabric, cfg: Dict[str, Any]):
 
     key = fabric.seed_everything(cfg.seed + rank)
     key, agent_key, env_key = jax.random.split(key, 3)
-    agent, params = build_agent(fabric, actions_dim, is_continuous, cfg, observation_space, agent_key)
+    sequence = str(cfg.algo.get("policy", "mlp")) == "sequence"
+    if sequence:
+        agent, params = build_sequence_policy(cfg, spec.action.num_actions, agent_key)
+    else:
+        agent, params = build_agent(fabric, actions_dim, is_continuous, cfg, observation_space, agent_key)
     if state is not None:
         params = jax.tree_util.tree_map(jnp.asarray, state["agent"])
 
@@ -635,21 +824,28 @@ def run_anakin(fabric, cfg: Dict[str, Any]):
         # one-shot injected learning pathology (resilience.fault=lr_spike):
         # identity unless the fault armed this iteration
         params = apply_armed_learn_fault(params)
-        params, opt_state, env_state, obs, key, stats, learn = anakin_step(
-            params,
-            opt_state,
-            env_state,
-            obs,
-            key,
-            stats,
-            np.float32(clip_coef),
-            np.float32(ent_coef),
-        )
-        # one scalar sync per ITERATION (T * num_envs env steps), not per env
-        # step: keeps the host from racing ahead of the device queue and makes
-        # the wall-time split below honest. No data is transferred.
-        jax.block_until_ready(stats["losses"])
+        # the call and its wait are one span (`anakin_step`, on a capture's clock too);
+        # the two phase timers below get their shares of the same seconds
+        with timer("anakin_step"):
+            params, opt_state, env_state, obs, key, stats, learn, *extras = anakin_step(
+                params,
+                opt_state,
+                env_state,
+                obs,
+                key,
+                stats,
+                np.float32(clip_coef),
+                np.float32(ent_coef),
+            )
+            # one scalar sync per ITERATION (T * num_envs env steps), not per env
+            # step: keeps the host from racing ahead of the device queue and makes
+            # the wall-time split below honest. No data is transferred.
+            jax.block_until_ready(stats["losses"])
         elapsed = time.perf_counter() - t0
+        if extras and not timer.disabled:
+            # the sequence flavour's counters (scalars, behind the wait above)
+            for name, value in jax.device_get(extras[0]["counters"]).items():
+                timer.count(f"moe/{name}", float(value))
 
         # split the fused call's wall time between the rollout (fused env+act)
         # and train phases by the measured rollout-only time; compile-dominated
@@ -742,19 +938,22 @@ def run_anakin(fabric, cfg: Dict[str, Any]):
             or preempted
         ):
             last_checkpoint = policy_step
-            # snapshot to host numpy first: params/opt_state are donated into the
-            # NEXT anakin_step call, and an async checkpoint backend must never
-            # hold references into donated device buffers
-            ckpt_state = {
-                "agent": packed_device_get(params),
-                "optimizer": packed_device_get(opt_state),
-                "iter_num": iter_num * world_size,
-                "batch_size": int(cfg.algo.per_rank_batch_size * world_size),
-                "last_log": last_log,
-                "last_checkpoint": last_checkpoint,
-            }
             ckpt_path = os.path.join(log_dir, "checkpoint", f"ckpt_{policy_step}_{rank}.ckpt")
+            # the snapshot is part of the checkpoint's time: the pack programs of
+            # `packed_device_get` compile on first use, and left outside the span that
+            # was most of a short run's wall time, attributed to nothing
             with timer("Time/checkpoint_time"):
+                # snapshot to host numpy first: params/opt_state are donated into the
+                # NEXT anakin_step call, and an async checkpoint backend must never
+                # hold references into donated device buffers
+                ckpt_state = {
+                    "agent": packed_device_get(params),
+                    "optimizer": packed_device_get(opt_state),
+                    "iter_num": iter_num * world_size,
+                    "batch_size": int(cfg.algo.per_rank_batch_size * world_size),
+                    "last_log": last_log,
+                    "last_checkpoint": last_checkpoint,
+                }
                 fabric.call("on_checkpoint_coupled", ckpt_path=ckpt_path, state=ckpt_state)
             resilience.observe_checkpoint(ckpt_path, policy_step, preempted=preempted)
         if preempted:
@@ -764,7 +963,12 @@ def run_anakin(fabric, cfg: Dict[str, Any]):
     wait_for_checkpoint()
     if not resilience.finalize(policy_step) and fabric.is_global_zero and cfg.algo.run_test:
         with timer("Time/test_time"):
-            test(agent.apply, params, fabric, cfg, log_dir)
+            if sequence:
+                # one more sampled rollout of every env, on the device
+                ep = np.asarray(rollout_only(params, env_state, obs, key)[4])
+                fabric.print(f"Test - Reward: {ep[0] / max(ep[2], 1.0)}")
+            else:
+                test(agent.apply, params, fabric, cfg, log_dir)
     telemetry.close(policy_step)
     if logger is not None:
         logger.finalize()

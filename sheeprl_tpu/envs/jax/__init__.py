@@ -19,6 +19,7 @@ from sheeprl_tpu.envs.jax.base import ActionSpec, EnvSpec, JaxEnv
 from sheeprl_tpu.envs.jax.classic import CartPole, Pendulum
 from sheeprl_tpu.envs.jax.factory import JAX_ENV_IDS, JaxToGymEnv, make_jax_env, resolve_jax_env
 from sheeprl_tpu.envs.jax.gridworld import GridWorld
+from sheeprl_tpu.envs.jax.tokens import TokenCopy
 from sheeprl_tpu.envs.jax.wrappers import AutoReset, AutoResetState, VmapEnv
 
 __all__ = [
@@ -32,6 +33,7 @@ __all__ = [
     "JaxEnv",
     "JaxToGymEnv",
     "Pendulum",
+    "TokenCopy",
     "VmapEnv",
     "make_jax_env",
     "resolve_jax_env",

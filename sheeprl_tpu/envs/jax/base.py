@@ -75,6 +75,9 @@ class EnvSpec:
     # Anakin rollout uses it to decide statically whether to pay the
     # truncation-bootstrap value pass
     max_episode_steps: Optional[int] = None
+    # set by envs whose every episode lasts exactly this many steps (the token env):
+    # the sequence flavour of the Anakin loop makes one rollout one episode
+    episode_steps: Optional[int] = None
 
     def to_gym_obs_space(self):
         import gymnasium as gym

@@ -24,17 +24,24 @@ import numpy as np
 from sheeprl_tpu.envs.jax.base import JaxEnv
 from sheeprl_tpu.envs.jax.classic import CartPole, Pendulum
 from sheeprl_tpu.envs.jax.gridworld import GridWorld
+from sheeprl_tpu.envs.jax.tokens import TokenCopy
 from sheeprl_tpu.envs.jax.wrappers import AutoReset, VmapEnv
 from sheeprl_tpu.utils.utils import host_cpu_device
 
 # id -> (constructor, default max_episode_steps — gymnasium's registered
 # TimeLimit for the classics, a 4*N*N step budget for gridworlds)
-JAX_ENV_IDS = ("CartPole-v1", "Pendulum-v1", "gridworld_empty", "gridworld_four_rooms")
+JAX_ENV_IDS = ("CartPole-v1", "Pendulum-v1", "gridworld_empty", "gridworld_four_rooms", "token_copy")
 
 
-def resolve_jax_env(env_id: str) -> Tuple[JaxEnv, Optional[int]]:
+def resolve_jax_env(env_id: str, tokens: Optional[Any] = None) -> Tuple[JaxEnv, Optional[int]]:
     """Build the bare single-instance env for ``env_id`` and return it with the
-    id's default episode step budget."""
+    id's default episode step budget. ``tokens`` is the ``env.tokens`` group the
+    token env takes its sizes from (it ends its own episodes: no step budget)."""
+    if env_id == "token_copy":
+        if tokens is None:
+            raise ValueError("token_copy needs env.tokens (vocab_size, episode_steps, prompt_min, prompt_max)")
+        return TokenCopy(int(tokens.vocab_size), int(tokens.episode_steps), int(tokens.prompt_min),
+                         int(tokens.prompt_max)), None
     if env_id == "CartPole-v1":
         return CartPole(), 500
     if env_id == "Pendulum-v1":
@@ -54,7 +61,7 @@ def make_jax_env(cfg: Any, num_envs: int) -> VmapEnv:
     """The pure plane entry point: ``cfg.env.id`` resolved, AutoReset applied
     (``cfg.env.max_episode_steps`` overrides the id default; <= 0 disables
     truncation entirely), batched over ``num_envs``."""
-    env, default_limit = resolve_jax_env(str(cfg.env.id))
+    env, default_limit = resolve_jax_env(str(cfg.env.id), cfg.env.get("tokens", None))
     limit = cfg.env.get("max_episode_steps", None)
     limit = default_limit if limit is None else (int(limit) if int(limit) > 0 else None)
     return VmapEnv(AutoReset(env, max_episode_steps=limit), num_envs)

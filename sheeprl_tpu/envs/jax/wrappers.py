@@ -47,6 +47,7 @@ class AutoReset(JaxEnv):
             obs_low=env.spec.obs_low,
             obs_high=env.spec.obs_high,
             max_episode_steps=self.max_episode_steps,
+            episode_steps=env.spec.episode_steps,
         )
 
     def reset(self, key: jax.Array) -> Tuple[AutoResetState, jax.Array]:
@@ -63,7 +64,7 @@ class AutoReset(JaxEnv):
     def step(
         self, state: AutoResetState, action: jax.Array
     ) -> Tuple[AutoResetState, jax.Array, jax.Array, jax.Array, Dict[str, jax.Array]]:
-        inner, obs, reward, terminated, _ = self.env.step(state.inner, action)
+        inner, obs, reward, terminated, inner_info = self.env.step(state.inner, action)
         episode_return = state.episode_return + reward
         episode_length = state.episode_length + 1
         if self.max_episode_steps is not None:
@@ -85,6 +86,7 @@ class AutoReset(JaxEnv):
             episode_length=jnp.where(done, 0, episode_length).astype(jnp.int32),
         )
         info = {
+            **inner_info,  # the env's own fixed-shape arrays (e.g. the token env's action mask)
             # the pre-reset observation of THIS step (the host plane's
             # infos["final_obs"]); valid only where done
             "terminal_observation": obs,
