@@ -5,8 +5,9 @@ One process, three phases, through the entry points a user calls:
 
 1. ``kernel``: the fused Pallas LayerNorm-GRU step, compiled, at the row counts
    the S run sends it (1 from the player's step, which runs on the chip since
-   PR 28; 16 in the train program's dynamic scan, 1024 in its imagination),
-   value and gradient against ``ln_gru_step_reference``.
+   PR 28; 1024 in the train program's imagination; 16 as well, the posterior
+   scan's rows, though since PR 32 that scan steps the XLA reference, whose
+   bias carries the tap), value and gradient against ``ln_gru_step_reference``.
 2. ``train``: ``sheeprl_tpu.cli.run`` on Dreamer-V3 at the S preset the repo
    ships (``exp=dreamer_v3_100k_ms_pacman``: dense 512, recurrent 512, 32x32
    latents, CNN multiplier 32, batch 16 x sequence 64, horizon 15, 64x64x3
@@ -280,6 +281,19 @@ def train_phase(overrides: Sequence[str], *, platform: str, grad_steps: int, out
             f"act views copied {sum(total for _, total in views)} bytes in {len(views)} windows: "
             "the coupled loop should alias the trainer's buffers",
         )
+
+    # the train program's posterior scan forms every RSSM kernel's gradient outside its
+    # backward loop (counted when the program is traced, so in one window)
+    counted = [e["counters"] for e in events if e.get("counters")]
+    grads = {
+        name: sum(c[f"rssm/weight_grad_bytes_{name}"][1] for c in counted if f"rssm/weight_grad_bytes_{name}" in c)
+        for name in ("hoisted", "in_scan")
+    }
+    _check(
+        grads["hoisted"] > 0 and grads["in_scan"] == 0,
+        f"the posterior scan sums {grads['in_scan']} bytes of kernel gradients inside its loop and forms "
+        f"{grads['hoisted']} outside: expected all of them outside",
+    )
 
     ir_files = glob.glob(os.path.join(ir_dir, "*jit_train_step*compile*"))
     _check(len(ir_files) >= 1, f"no lowered train_step program under {ir_dir}")

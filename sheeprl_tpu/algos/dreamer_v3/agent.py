@@ -23,13 +23,15 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import flax.linen as nn
+from flax.traverse_util import flatten_dict, unflatten_dict
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from sheeprl_tpu.models.models import LayerNormGRUCell, resolve_activation
+from sheeprl_tpu.models.models import LayerNormGRUCell, resolve_activation, tapped
 from sheeprl_tpu.ops.conv import FastConv2x
 from sheeprl_tpu.ops.deconv import FusedConvTranspose4x4S2
+from sheeprl_tpu.utils.timer import timer
 from sheeprl_tpu.utils.utils import symlog
 
 # Hafner init: trunc-normal with variance 1/fan_avg and the 0.8796... correction —
@@ -58,8 +60,9 @@ class DenseStack(nn.Module):
     def __call__(self, x: jax.Array) -> jax.Array:
         act = resolve_activation(self.activation)
         x = x.astype(self.dtype)
-        for _ in range(self.n_layers):
-            x = nn.Dense(self.units, use_bias=False, kernel_init=hafner_init, dtype=self.dtype)(x)
+        for i in range(self.n_layers):
+            y = nn.Dense(self.units, use_bias=False, kernel_init=hafner_init, dtype=self.dtype)(x)
+            x = tapped(self, f"tap_{i}", x, y)
             x = nn.LayerNorm(epsilon=self.eps, dtype=self.dtype)(x)
             x = act(x)
         return x
@@ -80,7 +83,7 @@ class MLPHead(nn.Module):
     def __call__(self, x: jax.Array) -> jax.Array:
         x = DenseStack(self.units, self.n_layers, self.activation, self.eps, self.dtype)(x)
         init = hafner_init if self.head_init_scale is None else uniform_init(self.head_init_scale)
-        return nn.Dense(self.output_dim, kernel_init=init, dtype=self.dtype)(x)
+        return tapped(self, "tap_head", x, nn.Dense(self.output_dim, kernel_init=init, dtype=self.dtype)(x))
 
 
 class CNNEncoder(nn.Module):
@@ -461,6 +464,66 @@ def actor_logprob_entropy(
 # ---------------------------------------------------------------------------------
 # agent container + scan programs
 # ---------------------------------------------------------------------------------
+# the kernels the posterior step multiplies by: name -> (the kernel's path under
+# ``wm_params``, the path of the tap its module takes on that product (models.tapped)
+# under the model's ``taps`` and ``tap_inputs`` collections)
+_STEP_KERNELS = {
+    "recurrent_in": (
+        ("recurrent_model", "DenseStack_0", "Dense_0", "kernel"),
+        ("recurrent_model", "DenseStack_0", "tap_0"),
+    ),
+    "gru": (
+        ("recurrent_model", "LayerNormGRUCell_0", "kernel"),
+        ("recurrent_model", "LayerNormGRUCell_0", "gates"),
+    ),
+    "representation_in": (
+        ("representation_model", "DenseStack_0", "Dense_0", "kernel"),
+        ("representation_model", "DenseStack_0", "tap_0"),
+    ),
+    "representation_head": (
+        ("representation_model", "Dense_0", "kernel"),
+        ("representation_model", "tap_head"),
+    ),
+}
+
+
+def _scan_hoisting_kernel_grads(run: Callable, kernels: Dict[str, jax.Array], rest: Dict, keys):
+    """``run(kernels, rest, keys)[0]``, differentiable in ``kernels`` and ``rest``.
+
+    ``run`` is a ``lax.scan`` that multiplies by ``kernels`` at every step; it returns
+    ``(outs, tap_inputs)`` and takes in ``rest["xs"]["taps"]`` one tap a kernel and
+    step, added to that step's product (models.tapped). The backward pass differentiates
+    the scan with the kernels held constant, so its loop carries no kernel-sized sum,
+    and forms each kernel's gradient once: the stacked inputs against the taps'
+    stacked cotangents. Whatever else the step depends on is in ``rest``: a
+    differentiable value it closed over instead would lose its gradient in silence.
+    """
+
+    dtypes = {name: kernel.dtype for name, kernel in kernels.items()}
+
+    @jax.custom_vjp
+    def scan(kernels, rest, keys):
+        return run(kernels, rest, keys)[0]
+
+    def forward(kernels, rest, keys):
+        outs, vjp, tap_inputs = jax.vjp(lambda rest: run(kernels, rest, keys), rest, has_aux=True)
+        return outs, (vjp, tap_inputs)
+
+    def backward(residuals, cotangents):
+        vjp, tap_inputs = residuals
+        (d_rest,) = vjp(cotangents)
+        d_kernels = {
+            name: jnp.einsum(
+                "tbk,tbn->kn", tap_inputs[name], d_rest["xs"]["taps"][name], preferred_element_type=jnp.float32
+            ).astype(dtype)
+            for name, dtype in dtypes.items()
+        }
+        return d_kernels, d_rest, None
+
+    scan.defvjp(forward, backward)
+    return scan(kernels, rest, keys)
+
+
 @dataclass
 class DV3Agent:
     """All Flax modules plus the pure-scan RSSM programs. ``params`` pytrees are
@@ -511,9 +574,7 @@ class DV3Agent:
         if not self.learnable_initial_recurrent_state:
             w = jax.lax.stop_gradient(w)
         h0 = jnp.broadcast_to(jnp.tanh(w), (*batch_shape, self.recurrent_state_size))
-        prior_logits = self.transition_model.apply({"params": wm_params["transition_model"]}, h0)
-        prior_logits = unimix_logits(prior_logits, self.discrete_size, self.unimix)
-        z0 = stochastic_state(prior_logits, self.discrete_size, sample=False)
+        z0 = stochastic_state(self._prior_logits(wm_params, h0), self.discrete_size, sample=False)
         return h0, z0
 
     def _representation(self, wm_params: Dict, h: jax.Array, embedded: jax.Array, key: jax.Array):
@@ -529,9 +590,12 @@ class DV3Agent:
         logits = unimix_logits(logits, self.discrete_size, self.unimix)
         return logits, stochastic_state(logits, self.discrete_size, key)
 
-    def _transition(self, wm_params: Dict, h: jax.Array, key: jax.Array):
+    def _prior_logits(self, wm_params: Dict, h: jax.Array) -> jax.Array:
         logits = self.transition_model.apply({"params": wm_params["transition_model"]}, h)
-        logits = unimix_logits(logits, self.discrete_size, self.unimix)
+        return unimix_logits(logits, self.discrete_size, self.unimix)
+
+    def _transition(self, wm_params: Dict, h: jax.Array, key: jax.Array):
+        logits = self._prior_logits(wm_params, h)
         return logits, stochastic_state(logits, self.discrete_size, key)
 
     def _recurrent(self, wm_params: Dict, z: jax.Array, a: jax.Array, h: jax.Array) -> jax.Array:
@@ -550,12 +614,30 @@ class DV3Agent:
         """Posterior/prior unroll over the sequence — ONE lax.scan replacing the
         reference's per-timestep Python loop (dreamer_v3.py:86-97).
 
+        The loop does only what its carry ``(h, z)`` needs. The prior comes from the
+        stacked recurrent states after it, ``embedded``'s share of the posterior's
+        first product before it, and the gradients of the kernels the step does
+        multiply by are formed after the backward loop, one product a kernel
+        (``_scan_hoisting_kernel_grads``): left to ``lax.scan``'s transpose, each
+        would be a kernel-sized sum read and written at every time step to add a
+        product of B rows (howto/performance.md).
+
         Returns (recurrent_states, posteriors, posterior_logits, prior_logits), all
         time-major with flattened stochastic states.
         """
-        step, init, xs = self._dynamic_scan_pieces(wm_params, embedded, actions, is_first, key)
-        _, (hs, zs, post_logits, prior_logits) = jax.lax.scan(step, init, xs)
-        return hs, zs, post_logits, prior_logits
+        kernels, rest, keys = self._posterior_pieces(wm_params, embedded, actions, is_first, key)
+        T, B = embedded.shape[:2]
+        for name, kernel in kernels.items():
+            # the GRU's gates are float32 whatever the compute dtype (ops/gru.py)
+            dtype = jnp.float32 if name == "gru" else embedded.dtype
+            rest["xs"]["taps"].setdefault(name, jnp.zeros((T, B, kernel.shape[-1]), dtype))
+        self._count_weight_grad_bytes(wm_params, in_scan={})
+
+        def run(kernels, rest, keys):
+            return self._posterior_scan(kernels, rest, keys, jax.lax.scan, keep_inputs=True)
+
+        hs, zs, post_logits = _scan_hoisting_kernel_grads(run, kernels, rest, keys)
+        return hs, zs, post_logits, self._prior_logits(wm_params, hs)
 
     def dynamic_scan_sp(
         self,
@@ -572,64 +654,112 @@ class DV3Agent:
         carry hops along a ppermute ring, so each device holds only T/S steps of
         inputs and activations (SURVEY §5.7's extension hook; no reference
         counterpart). Numerically identical to ``dynamic_scan`` (parity-tested);
-        both run the SAME step body from ``_dynamic_scan_pieces``."""
+        both run the SAME step body from ``_posterior_scan``. The step's kernels
+        keep plain autodiff here, which sums their gradients inside the loop."""
         from sheeprl_tpu.parallel.sequence import ring_sequence_scan
 
-        step, init, xs = self._dynamic_scan_pieces(wm_params, embedded, actions, is_first, key)
-        _, (hs, zs, post_logits, prior_logits) = ring_sequence_scan(step, init, xs, mesh, axis)
-        return hs, zs, post_logits, prior_logits
+        kernels, rest, keys = self._posterior_pieces(wm_params, embedded, actions, is_first, key)
+        self._count_weight_grad_bytes(wm_params, in_scan=kernels)
 
-    def _dynamic_scan_pieces(self, wm_params, embedded, actions, is_first, key):
-        """The shared RSSM step body + init + per-step inputs consumed by both the
-        plain and the sequence-parallel unrolls."""
-        T, B = embedded.shape[:2]
-        h0, z0 = self.initial_state(wm_params, (B,))
+        def ring(step, init, xs):
+            return ring_sequence_scan(step, init, xs, mesh, axis)
+
+        (hs, zs, post_logits), _ = self._posterior_scan(kernels, rest, keys, ring, keep_inputs=False)
+        return hs, zs, post_logits, self._prior_logits(wm_params, hs)
+
+    def _count_weight_grad_bytes(self, wm_params: Dict, in_scan: Dict[str, jax.Array]) -> None:
+        """Counted once a trace: the bytes of the RSSM's kernels whose gradient is
+        formed outside the posterior loop, and of those (``in_scan``, the step's own)
+        still summed inside it."""
+        total = sum(
+            leaf.nbytes
+            for model in ("recurrent_model", "representation_model", "transition_model")
+            for path, leaf in jax.tree_util.tree_leaves_with_path(wm_params[model])
+            if path[-1].key == "kernel"
+        )
+        inside = sum(kernel.nbytes for kernel in in_scan.values())
+        timer.count("rssm/weight_grad_bytes_hoisted", total - inside)
+        timer.count("rssm/weight_grad_bytes_in_scan", inside)
+
+    def _posterior_pieces(self, wm_params, embedded, actions, is_first, key):
+        """What the posterior loop consumes, computed outside it: ``kernels``, the
+        kernels its step multiplies by (``_STEP_KERNELS``); ``rest``, everything else
+        that can carry a gradient (the two models' other leaves by path, the initial
+        state, the per-step inputs ``xs``); and the per-step sampling keys."""
+        T = embedded.shape[0]
+        H = self.recurrent_state_size
+        dtype = embedded.dtype
+        h0, z0 = self.initial_state(wm_params, (embedded.shape[1],))
         keys = jax.random.split(key, T)
         # the carry must keep the compute dtype through the whole scan: fp32
         # actions/is_first would promote the bf16 body output back to fp32 and break
         # the carry-type invariant under precision=bf16-*
-        actions = actions.astype(embedded.dtype)
-        is_first = is_first.astype(embedded.dtype)
-        h0, z0 = h0.astype(embedded.dtype), z0.astype(embedded.dtype)
-        init = (
-            jnp.zeros((B, self.recurrent_state_size), embedded.dtype),
-            jnp.zeros((B, self.stoch_state_size), embedded.dtype),
-        )
-
-        def _recurrent_prior(h, z_prev, a, first):
-            """Shared step prefix: reset masking, recurrent update, unimixed prior."""
-            a = (1 - first) * a
-            h = (1 - first) * h + first * h0
-            z_prev = (1 - first) * z_prev + first * z0
-            h = self._recurrent(wm_params, z_prev, a, h)
-            prior_logits = self.transition_model.apply({"params": wm_params["transition_model"]}, h)
-            return h, unimix_logits(prior_logits, self.discrete_size, self.unimix)
-
+        xs = {"a": actions.astype(dtype), "first": is_first.astype(dtype), "taps": {}}
+        names = ["recurrent_in", "gru"]
         if self.decoupled_rssm:
             # the posterior is non-recurrent, so the WHOLE sequence's posteriors come
             # from one batched feedforward pass (reference DecoupledRSSM samples the
-            # posterior outside the time loop); only the recurrent/prior chain stays
+            # posterior outside the time loop); only the recurrent chain stays
             # sequential
-            post_logits_all, zs_all = jax.vmap(
+            xs["post_logits"], xs["z"] = jax.vmap(
                 lambda e, k: self._representation(wm_params, h0, e, k)
             )(embedded, keys)
+            keys = None
+        else:
+            names += ["representation_in", "representation_head"]
+        # the two models' leaves by path, the step's kernels taken out
+        models = flatten_dict({model: wm_params[model] for model in {_STEP_KERNELS[name][0][0] for name in names}})
+        kernels = {name: models.pop(_STEP_KERNELS[name][0]) for name in names}
+        if not self.decoupled_rssm:
+            # concat([h, embedded]) @ W = h @ W[:H] + embedded @ W[H:] (the Dense has no
+            # bias): the step multiplies by W[:H] alone, and embedded's share, all
+            # T x B rows in one product, reaches the same pre-activation as a tap
+            w = kernels["representation_in"]
+            kernels["representation_in"] = w[:H]
+            xs["taps"]["representation_in"] = nn.Dense(
+                w.shape[-1], use_bias=False, dtype=self.representation_model.dtype
+            ).apply({"params": {"kernel": w[H:]}}, embedded)
+        rest = {"models": models, "h0": h0.astype(dtype), "z0": z0.astype(dtype), "xs": xs}
+        return kernels, rest, keys
 
-            def step(carry, inp):
-                h, z_prev = carry
-                a, z_t, post_logits_t, first = inp
-                h, prior_logits = _recurrent_prior(h, z_prev, a, first)
-                return (h, z_t), (h, z_t, post_logits_t, prior_logits)
+    def _posterior_scan(self, kernels, rest, keys, scan, keep_inputs: bool):
+        """``scan`` over the RSSM step shared by the plain and the sequence-parallel
+        unrolls. Returns ((hs, zs, post_logits), tap_inputs): with ``keep_inputs`` the
+        input of each of ``kernels`` at every step, stacked over time, else {}."""
+        h0, z0 = rest["h0"], rest["z0"]
+        models = unflatten_dict({**rest["models"], **{_STEP_KERNELS[name][0]: kernel for name, kernel in kernels.items()}})
 
-            return step, init, (actions, zs_all, post_logits_all, is_first)
+        def apply(module, model, taps, *args):
+            """``module`` on ``args`` with the step's taps, and the inputs it kept."""
+            paths = {name: path[1:] for name, (_, path) in _STEP_KERNELS.items() if path[0] == model and name in taps}
+            variables = {"params": models[model], "taps": unflatten_dict({path: taps[name] for name, path in paths.items()})}
+            if not keep_inputs:
+                return module.apply(variables, *args), {}
+            out, kept = module.apply(variables, *args, mutable=["tap_inputs"])
+            kept = flatten_dict(kept["tap_inputs"])
+            return out, {name: kept[path] for name, path in paths.items()}
 
         def step(carry, inp):
-            h, z, = carry
-            a, e, first, k = inp
-            h, prior_logits = _recurrent_prior(h, z, a, first)
-            post_logits, z = self._representation(wm_params, h, e, k)
-            return (h, z), (h, z, post_logits, prior_logits)
+            h, z = carry
+            a, first = inp["a"], inp["first"]
+            # reset masking, then the recurrent update
+            a = (1 - first) * a
+            h = (1 - first) * h + first * h0
+            z = (1 - first) * z + first * z0
+            h, inputs = apply(self.recurrent_model, "recurrent_model", inp["taps"], jnp.concatenate([z, a], axis=-1), h)
+            if self.decoupled_rssm:
+                z, post_logits = inp["z"], inp["post_logits"]
+            else:
+                logits, rep_inputs = apply(self.representation_model, "representation_model", inp["taps"], h)
+                inputs.update(rep_inputs)
+                post_logits = unimix_logits(logits, self.discrete_size, self.unimix)
+                z = stochastic_state(post_logits, self.discrete_size, inp["key"])
+            return (h, z), ((h, z, post_logits), inputs)
 
-        return step, init, (actions, embedded, is_first, keys)
+        xs = rest["xs"] if keys is None else {**rest["xs"], "key": keys}
+        init = (jnp.zeros_like(h0), jnp.zeros_like(z0))
+        _, outs = scan(step, init, xs)
+        return outs
 
     def imagination_scan(
         self,

@@ -194,6 +194,18 @@ class NatureCNN(nn.Module):
         return jax.nn.relu(x)
 
 
+def tapped(module: nn.Module, name: str, x: jax.Array, y: jax.Array) -> jax.Array:
+    """``y``, what ``module`` adds to or takes from a product of ``x`` with one of its
+    kernels, made reachable from outside ``apply``: plus the variable ``taps/<name>``
+    where the caller passed one, and with ``x`` kept as ``tap_inputs/<name>`` where the
+    caller made that collection mutable. A zero tap's cotangent is the product's, so
+    a caller that steps the module in a ``lax.scan`` can form the kernel's gradient
+    once, after the loop (``DV3Agent.dynamic_scan``). With neither, ``y`` itself."""
+    module.sow("tap_inputs", name, x, reduce_fn=lambda _, new: new, init_fn=lambda: None)
+    tap = module.get_variable("taps", name, None)
+    return y if tap is None else y + tap
+
+
 class LayerNormGRUCell(nn.Module):
     """GRU cell with layer-norm applied to the stacked input/recurrent projection
     (reference models.py:331-411: norm after the input projection, before gating).
@@ -223,7 +235,8 @@ class LayerNormGRUCell(nn.Module):
             else jnp.zeros((3 * self.hidden_size,), jnp.float32)
         )
         w = w.astype(self.dtype)
-        b = b.astype(self.dtype)
+        # the bias is added to the product before the norm, so a [B, 3H] tap rides on it
+        b = tapped(self, "gates", inp, b.astype(self.dtype))
         if self.layer_norm:
             scale = self.param(
                 "ln_scale", nn.initializers.ones_init(), (3 * self.hidden_size,), jnp.float32
@@ -244,6 +257,7 @@ class LayerNormGRUCell(nn.Module):
             hx_d = hx.astype(self.dtype)
             if (
                 inp.ndim == 2
+                and b.ndim == 1  # the kernel takes a per-feature bias: a tapped step is XLA's
                 and ops.pallas_gru_applicable(inp.shape[-1], self.hidden_size)
                 # Pallas kernels don't partition: a multi-device mesh (dp or
                 # model-sharded GRU kernel) must take the XLA path
