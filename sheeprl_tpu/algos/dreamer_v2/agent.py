@@ -18,8 +18,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from sheeprl_tpu.models.models import LayerNormGRUCell, resolve_activation
-from sheeprl_tpu.ops.conv import FastConv2x
-from sheeprl_tpu.ops.deconv import FusedConvTransposeS2Valid
 from sheeprl_tpu.utils.distribution import TruncatedNormal
 
 
@@ -77,11 +75,11 @@ class CNNEncoder(nn.Module):
         x = x.reshape(-1, *x.shape[-3:])
         x = jnp.moveaxis(x, -3, -1).astype(self.dtype)
         for i, mult in enumerate((1, 2, 4, 8)):
-            # CPU fast-gradient stride-2 conv (ops/conv.py; TPU keeps the native
-            # lowering); explicit name keeps nn.Conv's parameter tree
-            x = FastConv2x(
-                features=mult * self.channels_multiplier,
-                kernel_size=4,
+            x = nn.Conv(
+                mult * self.channels_multiplier,
+                (4, 4),
+                strides=(2, 2),
+                padding="VALID",
                 use_bias=not self.layer_norm,
                 dtype=self.dtype,
                 name=f"Conv_{i}",
@@ -142,13 +140,12 @@ class CNNDecoder(nn.Module):
             (2 * self.channels_multiplier, 5),
             (1 * self.channels_multiplier, 6),
         ]
-        # FusedConvTransposeS2Valid == nn.ConvTranspose(k, s=2, VALID) exactly
-        # (ops/deconv.py; parity-tested), ~3x faster under XLA:CPU's lowering;
-        # explicit names keep the nn.ConvTranspose param tree (checkpoints intact).
         for i, (ch, k) in enumerate(specs):
-            x = FusedConvTransposeS2Valid(
+            x = nn.ConvTranspose(
                 ch,
-                kernel_size=k,
+                (k, k),
+                strides=(2, 2),
+                padding="VALID",
                 use_bias=not self.layer_norm,
                 dtype=self.dtype,
                 name=f"ConvTranspose_{i}",
@@ -156,9 +153,11 @@ class CNNDecoder(nn.Module):
             if self.layer_norm:
                 x = nn.LayerNorm(epsilon=1e-3, dtype=self.dtype)(x)
             x = act(x)
-        x = FusedConvTransposeS2Valid(
+        x = nn.ConvTranspose(
             sum(self.output_channels),
-            kernel_size=6,
+            (6, 6),
+            strides=(2, 2),
+            padding="VALID",
             dtype=self.dtype,
             name=f"ConvTranspose_{len(specs)}",
         )(x)
@@ -203,6 +202,7 @@ class RecurrentModel(nn.Module):
     dense_units: int
     activation: Any = "elu"
     layer_norm: bool = True
+    fused_step: bool = False  # LayerNormGRUCell's: on where the programs run on one device
     dtype: Any = jnp.float32
 
     @nn.compact
@@ -212,6 +212,7 @@ class RecurrentModel(nn.Module):
             hidden_size=self.recurrent_state_size,
             bias=True,
             layer_norm=self.layer_norm,
+            fused_step=self.fused_step,
             dtype=self.dtype,
         )(h, feat)
 
@@ -447,6 +448,7 @@ def build_agent(
         dense_units=wm_cfg.recurrent_model.dense_units,
         activation=cfg.algo.dense_act,
         layer_norm=wm_cfg.recurrent_model.get("layer_norm", True),
+        fused_step=fabric.num_devices == 1,
         dtype=dtype,
     )
     representation_model = MLPHead(

@@ -29,8 +29,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from sheeprl_tpu.models.models import LayerNormGRUCell, resolve_activation, tapped
-from sheeprl_tpu.ops.conv import FastConv2x
-from sheeprl_tpu.ops.deconv import FusedConvTranspose4x4S2
 from sheeprl_tpu.utils.timer import timer
 from sheeprl_tpu.utils.utils import symlog
 
@@ -105,13 +103,14 @@ class CNNEncoder(nn.Module):
         x = x.reshape(-1, *x.shape[-3:])
         x = jnp.moveaxis(x, -3, -1).astype(self.dtype)  # NCHW -> NHWC
         for i in range(self.stages):
-            # CPU fast-gradient stride-2 conv (ops/conv.py; pad-1 folds into the
-            # pre-pad); explicit name keeps nn.Conv's parameter tree. TPU keeps
-            # the native MXU conv.
-            x = FastConv2x(
-                features=(2**i) * self.channels_multiplier,
-                kernel_size=4,
-                padding=1,
+            # pad 1, then VALID: nn.Conv's own padding would lower a padded
+            # convolution, another program than the one the chip's numbers are of
+            x = jnp.pad(x, ((0, 0), (1, 1), (1, 1), (0, 0)))
+            x = nn.Conv(
+                (2**i) * self.channels_multiplier,
+                (4, 4),
+                strides=(2, 2),
+                padding="VALID",
                 use_bias=False,
                 kernel_init=hafner_init,
                 dtype=self.dtype,
@@ -156,6 +155,27 @@ class Encoder(nn.Module):
         return jnp.concatenate(outs, axis=-1)
 
 
+class ConvTransposeHead(nn.Module):
+    """``nn.ConvTranspose(features, (4, 4), strides=(2, 2), padding="SAME")`` with its
+    parameter tree, the bias added as the ``[features]`` vector it is kept as:
+    ``nn.ConvTranspose`` reshapes it to ``[1, 1, 1, features]`` first, the same values
+    from another program than the one the chip's numbers are of."""
+
+    features: int
+    kernel_init: Callable
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        kernel = self.param("kernel", self.kernel_init, (4, 4, x.shape[-1], self.features), jnp.float32)
+        bias = self.param("bias", nn.initializers.zeros_init(), (self.features,), jnp.float32)
+        y = jax.lax.conv_transpose(
+            x.astype(self.dtype), kernel.astype(self.dtype), (2, 2), "SAME",
+            dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        )
+        return y + bias.astype(self.dtype)
+
+
 class CNNDecoder(nn.Module):
     """Inverse of CNNEncoder: latent → 4x4 → stride-2 deconv stages → channel-first
     images per key (reference agent.py:154-228)."""
@@ -180,13 +200,12 @@ class CNNDecoder(nn.Module):
         )(latent)
         lead = x.shape[:-1]
         x = x.reshape(-1, spatial, spatial, top_channels)
-        # FusedConvTranspose4x4S2 == nn.ConvTranspose(k=4, s=2, SAME) exactly
-        # (ops/deconv.py; parity-tested), in the phase-decomposed form XLA:CPU runs
-        # ~3x faster; explicit names keep the nn.ConvTranspose param tree, so
-        # checkpoints are unaffected.
         for i in range(self.stages - 1):
-            x = FusedConvTranspose4x4S2(
+            x = nn.ConvTranspose(
                 (2 ** (self.stages - 2 - i)) * self.channels_multiplier,
+                (4, 4),
+                strides=(2, 2),
+                padding="SAME",
                 use_bias=False,
                 kernel_init=hafner_init,
                 dtype=self.dtype,
@@ -194,7 +213,7 @@ class CNNDecoder(nn.Module):
             )(x)
             x = nn.LayerNorm(epsilon=self.eps, dtype=self.dtype)(x)
             x = act(x)
-        x = FusedConvTranspose4x4S2(
+        x = ConvTransposeHead(
             sum(self.output_channels),
             kernel_init=uniform_init(1.0) if self.hafner_heads else hafner_init,
             dtype=self.dtype,
@@ -249,6 +268,7 @@ class RecurrentModel(nn.Module):
     dense_units: int
     activation: Any = "silu"
     eps: float = 1e-3
+    fused_step: bool = False  # LayerNormGRUCell's: on where the programs run on one device
     dtype: Any = jnp.float32
 
     @nn.compact
@@ -260,6 +280,7 @@ class RecurrentModel(nn.Module):
             layer_norm=True,
             layer_norm_eps=self.eps,
             kernel_init=hafner_init,
+            fused_step=self.fused_step,
             dtype=self.dtype,
         )(h, feat)
 
@@ -856,6 +877,7 @@ def build_agent(
         dense_units=wm_cfg.recurrent_model.dense_units,
         activation=cfg.algo.dense_act,
         eps=eps,
+        fused_step=fabric.num_devices == 1,
         dtype=dtype,
     )
     representation_model = MLPHead(

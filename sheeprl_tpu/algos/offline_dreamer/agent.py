@@ -219,6 +219,7 @@ def build_agent(
         dense_units=wm_cfg.recurrent_model.dense_units,
         activation=cfg.algo.dense_act,
         eps=eps,
+        fused_step=fabric.num_devices == 1,
         dtype=dtype,
     )
     representation_model = MLPHead(

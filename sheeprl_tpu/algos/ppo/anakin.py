@@ -11,8 +11,7 @@ program in a loop, carries only opaque device references (params, opt state,
 env state, obs, PRNG key, stats accumulators), and pulls a handful of SCALARS
 (episode stats, losses) at the telemetry/logging cadence. Compare
 ``algos/ppo/ppo.py``, which pays a host<->device round trip per vector env step
-— the structural bound PERF_ANALYSIS.md identifies once train programs are
-fast.
+— the structural bound once train programs are fast.
 
 Two flavors share the driver (the host loops ``ppo.py``/``a2c.py`` define the
 reference semantics):

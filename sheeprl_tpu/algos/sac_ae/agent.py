@@ -27,7 +27,6 @@ import numpy as np
 
 from sheeprl_tpu.algos.sac.agent import CriticEnsemble
 from sheeprl_tpu.models.models import MLP
-from sheeprl_tpu.ops.deconv import FusedConvTransposeS2Valid
 
 LOG_STD_MAX = 2.0
 LOG_STD_MIN = -10.0
@@ -103,10 +102,8 @@ class CNNDecoderAE(nn.Module):
         for _ in range(3):
             x = nn.ConvTranspose(32 * self.channels_multiplier, (3, 3), strides=(1, 1), padding="VALID", dtype=self.dtype)(x)
             x = jax.nn.relu(x)
-        # phase-decomposed drop-in for the stride-2 upsample (ops/deconv.py); the
-        # explicit name keeps nn.ConvTranspose's auto-name slot (checkpoints intact)
-        x = FusedConvTransposeS2Valid(
-            sum(self.output_channels), kernel_size=4, dtype=self.dtype, name="ConvTranspose_3"
+        x = nn.ConvTranspose(
+            sum(self.output_channels), (4, 4), strides=(2, 2), padding="VALID", dtype=self.dtype, name="ConvTranspose_3"
         )(x)
         x = jnp.moveaxis(x, -1, -3)  # NHWC -> NCHW
         x = x.reshape(*lead, *x.shape[-3:])

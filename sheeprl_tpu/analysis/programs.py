@@ -176,23 +176,7 @@ def _finding(spec: ProgramSpec, summary: str, suggestion: str, severity: str = "
 
 def check_program_contract(spec: ProgramSpec) -> List[Finding]:
     """Build, lower and (optionally) compile one registered program; return the
-    contract violations as findings (empty list = contract holds).
-
-    The process-wide partitioned-mesh gate is restored to its PRIOR value after
-    each program: a mesh-building spec (anakin's 8-device fabric) flips it
-    sticky, and a later single-device spec lowered under it would take the
-    native paths instead of the fast paths production single-device runs lower
-    — masking exactly the regressions the sweep exists to catch."""
-    from sheeprl_tpu import ops
-
-    prior_partitioned = ops.partitioned_mesh_active()
-    try:
-        return _check_program_contract(spec)
-    finally:
-        ops.set_partitioned_mesh(prior_partitioned)
-
-
-def _check_program_contract(spec: ProgramSpec) -> List[Finding]:
+    contract violations as findings (empty list = contract holds)."""
     import jax
 
     from sheeprl_tpu.utils.mfu import abstractify

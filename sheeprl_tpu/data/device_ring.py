@@ -2,7 +2,7 @@
 
 Every host-replay off-policy loop pays one host→device round trip per
 environment step (write) plus one per train round (sample + ``device_put``) —
-the structural bound PERF_ANALYSIS.md identifies once train programs are fast,
+the structural bound once train programs are fast,
 and the boundary the Podracer architectures (arxiv 2104.06272) and MindSpeed RL
 (arxiv 2507.19017) both erase by keeping the RL stages device-resident. This
 module puts the replay buffer itself ON the mesh:

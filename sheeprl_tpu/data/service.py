@@ -3,7 +3,7 @@
 Every decoupled topology before this module coupled acting and learning
 one-to-one: the player samples its OWN replay buffer and blocks on the learner's
 round (``BroadcastChannel`` lockstep alternation), so actor cores idle while the
-learner's fused train program runs — PERF_ANALYSIS.md's structural bound once
+learner's fused train program runs — the structural bound once
 train programs are fast. MindSpeed RL (arxiv 2507.19017) argues the unit of
 production RL is a fleet with a shared distributed dataflow, and the Podracer
 architectures (arxiv 2104.06272) fill accelerators by decoupling actor and

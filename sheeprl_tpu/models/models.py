@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from sheeprl_tpu.ops.conv import FastConv2x
+from sheeprl_tpu import ops
 
 ModuleType = Optional[str]
 ArgType = Union[Tuple[Any, ...], Dict[str, Any], None]
@@ -107,27 +107,12 @@ class CNN(nn.Module):
             x = jnp.moveaxis(x, -3, -1)  # NCHW -> NHWC
         x = x.astype(self.dtype)
         for i, (ch, k, s) in enumerate(zip(self.channels, self.kernel_sizes, self.strides)):
-            # sym_pad: the symmetric per-side padding when expressible as one int
-            # (every non-string config here is), else None (e.g. "SAME")
             if isinstance(self.paddings, str):
                 padding = self.paddings
-                sym_pad = 0 if padding == "VALID" else None
             else:
                 p = self.paddings[i] if not isinstance(self.paddings, int) else self.paddings
                 padding = [(p, p), (p, p)]
-                sym_pad = p
-            # stride-2 even-k convs with VALID or symmetric-int padding (the
-            # Dreamer encoder stages) take the CPU fast-gradient decomposition
-            # (ops/conv.py; TPU keeps the native conv). Explicit names keep the
-            # nn.Conv parameter tree.
-            if sym_pad is not None and s == 2 and k % 2 == 0:
-                x = FastConv2x(
-                    features=ch, kernel_size=k, padding=sym_pad, dtype=self.dtype, name=f"Conv_{i}"
-                )(x)
-            else:
-                x = nn.Conv(
-                    ch, (k, k), strides=(s, s), padding=padding, dtype=self.dtype, name=f"Conv_{i}"
-                )(x)
+            x = nn.Conv(ch, (k, k), strides=(s, s), padding=padding, dtype=self.dtype, name=f"Conv_{i}")(x)
             if self.layer_norm:
                 x = nn.LayerNorm(dtype=self.dtype, epsilon=1e-3)(x)  # NHWC: normalize channels
             x = act(x)
@@ -212,6 +197,11 @@ class LayerNormGRUCell(nn.Module):
 
     One fused matmul computes all three gates — the shape the MXU wants. Usable as a
     ``lax.scan`` body for full-sequence unrolls.
+
+    ``fused_step``: whether a TPU lowering of a step the Pallas kernel compiles for
+    takes the kernel. A Pallas kernel does not partition, so whoever builds the module
+    says whether its programs run on one device (the agents' ``build_agent``, from the
+    fabric they are handed); the XLA step is the default.
     """
 
     hidden_size: int
@@ -220,6 +210,7 @@ class LayerNormGRUCell(nn.Module):
     layer_norm: bool = True
     layer_norm_eps: float = 1e-3
     kernel_init: Optional[Callable] = None
+    fused_step: bool = False
     dtype: Any = jnp.float32
 
     @nn.compact
@@ -252,16 +243,12 @@ class LayerNormGRUCell(nn.Module):
             # Dreamer-V3 player, which runs on the chip, takes the kernel at
             # num_envs rows (chip_smoke.py checks one row) — same math,
             # parity-tested in tests/test_ops.
-            from sheeprl_tpu import ops
-
             hx_d = hx.astype(self.dtype)
             if (
-                inp.ndim == 2
+                self.fused_step
+                and inp.ndim == 2
                 and b.ndim == 1  # the kernel takes a per-feature bias: a tapped step is XLA's
                 and ops.pallas_gru_applicable(inp.shape[-1], self.hidden_size)
-                # Pallas kernels don't partition: a multi-device mesh (dp or
-                # model-sharded GRU kernel) must take the XLA path
-                and not ops.partitioned_mesh_active()
             ):
                 return jax.lax.platform_dependent(
                     tpu=lambda: ops.fused_ln_gru_step(

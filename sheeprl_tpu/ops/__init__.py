@@ -1,7 +1,7 @@
-"""Pallas TPU kernels for the hot ops (SURVEY §2.4: the reference's native-speed
-layer is external libtorch/cuDNN kernels; here the custom-kernel layer is Pallas)."""
+"""The one Pallas TPU kernel of the tree and its XLA reference: the fused LayerNorm-GRU
+step (``ops/gru.py``; XS and S cells only). The convolutions are ``flax.linen.Conv`` /
+``lax.conv_transpose`` at their call sites."""
 
-from sheeprl_tpu.ops.deconv import FusedConvTranspose4x4S2, FusedConvTransposeS2Valid
 from sheeprl_tpu.ops.gru import (
     fused_ln_gru_step,
     ln_gru_step_reference,
@@ -9,36 +9,7 @@ from sheeprl_tpu.ops.gru import (
 )
 
 __all__ = [
-    "FusedConvTranspose4x4S2",
-    "FusedConvTransposeS2Valid",
     "fused_ln_gru_step",
     "ln_gru_step_reference",
     "pallas_gru_applicable",
-    "partitioned_mesh_active",
-    "set_partitioned_mesh",
 ]
-
-# Whether this process traces programs for a PARTITIONED (>1 device) mesh.
-# The custom-gradient kernels (fast conv, fused deconv, Pallas GRU step) are
-# single-device decompositions: their packing reshapes mix the batch axis with
-# spatial/channel dims, and the SPMD partitioner mis-scales the resulting fused
-# reductions once the batch is sharded over >2 devices (measured on the DV3
-# world loss: x2.1 at 4 CPU devices, x7.7 at 8; updated params survived only
-# because clip+adam absorb a uniform gradient scale). The gate fires at >1
-# device even though 2-way was measured exact: 2-way exactness is a partitioner
-# CHOICE, not a contract, and the cost is confined to CPU-simulated meshes —
-# on TPU the conv/deconv fast paths are CPU-only `platform_dependent` branches
-# (native MXU convs run either way) and a Pallas kernel under ANY partitioning
-# is a correctness hazard, not a win. ``Fabric._setup`` sets the flag sticky
-# upward; single-device runs keep the fast paths.
-_PARTITIONED_MESH = {"active": False}
-
-
-def set_partitioned_mesh(active: bool) -> None:
-    """Record whether programs are being built for a multi-device mesh (called
-    by ``Fabric._setup``); disables the custom-kernel fast paths when True."""
-    _PARTITIONED_MESH["active"] = bool(active)
-
-
-def partitioned_mesh_active() -> bool:
-    return _PARTITIONED_MESH["active"]

@@ -7,14 +7,11 @@ TPU lowering path — verified off-chip by the fused-program contract sweep
 CPU mesh (the Pallas GRU lowers through Mosaic to a ``tpu_custom_call``). A
 branch that only ever lowered on CPU could hide a TPU-side trace error until
 the first paid chip window. These registrations generalize
-``tests/test_ops/test_tpu_lowering.py``'s hand-written programs:
-
-- the fused Pallas LayerNorm-GRU step and the ``platform_dependent`` dispatch
-  the models build (tpu=Pallas / default=XLA reference) lower for TPU with the
-  Mosaic custom call present — and gradients THROUGH the dispatch lower too
-  (the train programs differentiate these ops);
-- the s2d fast-conv gate (``ops/conv.py``) and the im2col/phase deconv gate
-  (``ops/deconv.py``) lower for cpu AND tpu in one multi-platform lowering.
+``tests/test_ops/test_tpu_lowering.py``'s hand-written programs: the fused Pallas
+LayerNorm-GRU step and the ``platform_dependent`` dispatch the models build
+(tpu=Pallas / default=XLA reference) lower for TPU with the Mosaic custom call
+present — and gradients THROUGH the dispatch lower too (the train programs
+differentiate these ops).
 
 None of these programs donate (they are op-level, not train-state programs),
 so their contracts assert lowering validity + custom-call hygiene only.
@@ -97,52 +94,3 @@ def _aot_gru_step_grad():
     # the custom-VJP backward recomputes in reference math — the property that
     # matters is that the WHOLE gradient program lowers cleanly for TPU
     return jax.jit(jax.grad(loss)), (args[2],)
-
-
-@register_fused_program(
-    "ops.fast_conv",
-    donated=False,
-    platforms=("cpu", "tpu"),
-    doc="s2d fast-conv gate (cpu=s2d decomposition / default=native) lowers for both platforms",
-)
-def _aot_fast_conv():
-    from sheeprl_tpu.ops.conv import FastConv2x
-
-    module = FastConv2x(features=8, kernel_size=4, max_fast_cin=8)
-    x = jnp.ones((2, 16, 16, 3), jnp.float32)
-    params = module.init(jax.random.PRNGKey(0), x)
-    return jax.jit(lambda p, x: module.apply(p, x)), (params, x)
-
-
-@register_fused_program(
-    "ops.fast_conv_grad",
-    donated=False,
-    platforms=("cpu", "tpu"),
-    doc="gradient through the conv gate lowers for both platforms",
-)
-def _aot_fast_conv_grad():
-    from sheeprl_tpu.ops.conv import FastConv2x
-
-    module = FastConv2x(features=8, kernel_size=4, max_fast_cin=8)
-    x = jnp.ones((2, 16, 16, 3), jnp.float32)
-    params = module.init(jax.random.PRNGKey(0), x)
-
-    def loss(p):
-        return module.apply(p, x).sum()
-
-    return jax.jit(jax.grad(loss)), (params,)
-
-
-@register_fused_program(
-    "ops.fast_deconv",
-    donated=False,
-    platforms=("cpu", "tpu"),
-    doc="im2col/phase deconv gate (cpu=phase form / default=native) lowers for both platforms",
-)
-def _aot_fast_deconv():
-    from sheeprl_tpu.ops.deconv import FusedConvTranspose4x4S2
-
-    module = FusedConvTranspose4x4S2(features=6)
-    x = jnp.ones((2, 8, 8, 4), jnp.float32)
-    params = module.init(jax.random.PRNGKey(0), x)
-    return jax.jit(lambda p, x: module.apply(p, x)), (params, x)

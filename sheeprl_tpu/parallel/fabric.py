@@ -303,17 +303,6 @@ class Fabric:
                 )
             mesh_devices = np.asarray(all_devices[:total]).reshape(shape)
         self._mesh = Mesh(mesh_devices, axis_names=self.axis_names)
-        # the custom-kernel fast paths (fast conv / fused deconv / Pallas GRU)
-        # are single-device decompositions the SPMD partitioner mis-compiles on
-        # a partitioned mesh. The gate is STICKY upward: once any multi-device
-        # mesh exists in this process every later trace takes the native
-        # lowerings — a 1-device fabric built mid-run (eval views, reference
-        # builds) must not silently re-arm the fast paths for a partitioned
-        # program whose first call (= trace) happens after it.
-        if int(self._mesh.devices.size) > 1:
-            from sheeprl_tpu import ops
-
-            ops.set_partitioned_mesh(True)
         # make uncommitted computations follow the selected accelerator (otherwise a
         # `fabric.accelerator=cpu` run would still trace onto a default TPU device);
         # the default must be a LOCAL device — a process_group mesh interleaves

@@ -65,18 +65,6 @@ def chdir_tmp(tmp_path, monkeypatch):
     yield
 
 
-@pytest.fixture(autouse=True)
-def _reset_partitioned_mesh_flag():
-    """``Fabric._setup`` flips the process-wide partitioned-mesh gate (which
-    disables the custom-kernel fast paths); reset it so a test that built a
-    multi-device fabric never changes which conv/GRU lowering a LATER test
-    exercises."""
-    from sheeprl_tpu import ops
-
-    yield
-    ops.set_partitioned_mesh(False)
-
-
 @pytest.fixture()
 def standard_args():
     return [

@@ -43,9 +43,6 @@ EXPECTED_PROGRAMS = {
     "ops.gru_pallas_step",
     "ops.gru_platform_dispatch",
     "ops.gru_step_grad",
-    "ops.fast_conv",
-    "ops.fast_conv_grad",
-    "ops.fast_deconv",
 }
 
 

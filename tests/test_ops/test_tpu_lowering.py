@@ -1,10 +1,10 @@
 """TPU-readiness AOT lowering tests (ROADMAP item 5 off-chip prep).
 
 The per-platform lowering assertions this file used to hand-write (Pallas GRU
-step / dispatch / gradients, conv + deconv gates, for cpu and tpu alike) now
-run as the fused-program registry sweep — ``sheeprl_tpu/ops/aot.py`` registers
-the programs, ``tests/test_analysis/test_aot_contracts.py`` (and ``python
-sheeprl.py lint --aot``) lowers and asserts each contract. What stays HERE is
+step / dispatch / gradients) now run as the fused-program registry sweep —
+``sheeprl_tpu/ops/aot.py`` registers the programs,
+``tests/test_analysis/test_aot_contracts.py`` (and ``python sheeprl.py lint
+--aot``) lowers and asserts each contract. What stays HERE is
 what the registry deliberately does not encode:
 
 - the matmul-precision parametrization: Mosaic only lowers DEFAULT/HIGHEST
@@ -43,8 +43,6 @@ def test_ops_lowering_contracts_are_registered():
         spec = FUSED_PROGRAMS[name]
         assert spec.contract.platforms == ("tpu",)
         assert "tpu_custom_call" in spec.contract.allow_custom_calls
-    for name in ("ops.fast_conv", "ops.fast_conv_grad", "ops.fast_deconv"):
-        assert set(FUSED_PROGRAMS[name].contract.platforms) == {"cpu", "tpu"}
 
 
 @pytest.mark.parametrize("matmul_precision", ["default", "high", "highest"])
@@ -71,13 +69,37 @@ def test_gru_dispatch_lowers_the_branch_of_the_lowering_platform():
     assert "tpu_custom_call" in traced.lower(lowering_platforms=("tpu",)).as_text()
 
 
-def test_fast_conv_tpu_lowering_is_the_native_convolution_only():
-    # cpu=s2d decomposition / default=native: a TPU lowering drops the s2d
-    # branch, so the chip runs ONE native convolution (the MXU path) — an
-    # im2col form tuned for XLA:CPU never reaches it
-    fn, args = FUSED_PROGRAMS["ops.fast_conv"].builder()
-    tpu_hlo = fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
-    assert tpu_hlo.count("stablehlo.convolution") == 1
+@pytest.mark.parametrize("fused_step", [False, True])
+def test_gru_cell_takes_the_kernel_only_where_its_builder_says_one_device(fused_step):
+    # the module field is the whole gate: off by default, and nothing in the process
+    # (a Fabric set up earlier, an environment variable) changes what a cell lowers
+    from sheeprl_tpu.models.models import LayerNormGRUCell
+
+    cell = LayerNormGRUCell(hidden_size=128, fused_step=fused_step)
+    hx, x = jnp.ones((16, 128)), jnp.ones((16, 128))
+    params = jax.eval_shape(cell.init, jax.random.PRNGKey(0), hx, x)
+    hlo = _lower(lambda p, hx, x: cell.apply(p, hx, x), params, hx, x).as_text()
+    assert ("tpu_custom_call" in hlo) is fused_step
+
+
+def test_dreamer_v3_encoder_and_decoder_lower_for_tpu_to_convolutions_and_compile_nothing():
+    # one convolution a stage and no fork on the platform: the chip runs what every
+    # other backend runs, and lowering the real modules compiles nothing either
+    from sheeprl_tpu.algos.dreamer_v3.agent import CNNDecoder, CNNEncoder
+    from sheeprl_tpu.obs.compile_monitor import compile_snapshot, install_compile_monitor
+
+    enc = CNNEncoder(keys=("rgb",), channels_multiplier=4, stages=3)
+    dec = CNNDecoder(keys=("rgb",), output_channels=(3,), channels_multiplier=4, image_size=(32, 32), stages=3)
+    obs, latent = {"rgb": jnp.ones((2, 3, 32, 32))}, jnp.ones((2, 16))
+    programs = [(enc, obs, jax.eval_shape(enc.init, jax.random.PRNGKey(0), obs)),
+                (dec, latent, jax.eval_shape(dec.init, jax.random.PRNGKey(0), latent))]
+    install_compile_monitor()
+    before = compile_snapshot()["count"]
+    for module, x, params in programs:
+        hlo = _lower(lambda p, x: module.apply(p, x), params, x).as_text()
+        assert hlo.count("stablehlo.convolution") == 3
+        assert "stablehlo.case" not in hlo and "custom_call" not in hlo
+    assert compile_snapshot()["count"] == before
 
 
 def test_tpu_lowering_compiles_nothing(monkeypatch):
