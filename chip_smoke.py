@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """The quickest proof that the system still starts on the chip.
 
-One process, three phases, through the entry points a user calls:
+One process, four phases, through the entry points a user calls:
 
 1. ``kernel``: the fused Pallas LayerNorm-GRU step, compiled, at the row counts
    the S run sends it (1 from the player's step, which runs on the chip since
@@ -17,6 +17,12 @@ One process, three phases, through the entry points a user calls:
    so the run prefills, compiles and takes ``GRAD_STEPS`` gradient steps.
 3. ``serve``: ``sheeprl_tpu.cli.serve`` answers ``SESSIONS`` sessions from the
    checkpoint that run wrote, through the donated slot-step program.
+4. ``experts``: the grouped expert products of the sequence-model policy
+   (``ops/grouped_matmul.py``), compiled, at the LFM2 cell's shapes against
+   ``lax.ragged_dot`` at ``highest``, value and both gradients; then
+   ``sheeprl_tpu.cli.run`` on ``exp=ppo_anakin_lfm2`` at widths the kernels tile,
+   whose update must hold the kernels under its ``experts`` scope, count three
+   bf16 passes at the CLI's ``high`` and drop no pair.
 
 Every check reads what the run itself recorded (its telemetry stream, its
 checkpoint) or what JAX reports; a failed check raises, so any failed phase is a
@@ -70,6 +76,31 @@ S_TRAIN_OVERRIDES = [
     "buffer.size=4096",
     "metric.log_level=0",
 ]
+# the fused PPO loop's sequence flavour at widths the grouped kernels tile (multiples of 128)
+# with more than `lfm2.DENSE_TOKENS` tokens a gradient step, so that the update sorts its pairs
+LM_OVERRIDES = [
+    "exp=ppo_anakin_lfm2",
+    "fabric.accelerator=tpu",
+    "fabric.devices=1",
+    "env.num_envs=16",
+    "algo.rollout_steps=128",
+    "algo.per_rank_batch_size=8",
+    "algo.total_steps=6144",  # three iterations: telemetry anchors after the first
+    "algo.lm.vocab_size=1024",
+    "algo.lm.hidden_size=512",
+    "algo.lm.intermediate_size=1024",
+    "algo.lm.moe_intermediate_size=256",
+    "algo.lm.num_attention_heads=8",
+    "algo.lm.num_key_value_heads=2",
+    "algo.lm.experts_held=[0,4]",
+    "checkpoint.every=0",
+    "checkpoint.save_last=False",
+    "metric.log_level=0",
+]
+# [M, K, N] of the LFM2 cell's w1/w3 product and its 8 held experts; the bound of
+# tests/test_models/test_lfm2_grouped.py on three bf16 passes against float64
+CELL_PRODUCT = (32768, 2048, 1792, 8)
+THREE_PASS_BOUND = 2e-5
 S_SERVE_OVERRIDES = [
     "serve.slots=4",
     f"serve.sessions={SESSIONS}",
@@ -370,6 +401,132 @@ def serve_phase(checkpoint: str, overrides: Sequence[str], *, platform: str, ses
     return result
 
 
+def experts_phase(
+    overrides: Sequence[str], *, platform: str, out_dir: str, product: Sequence[int] = CELL_PRODUCT
+) -> Dict[str, Any]:
+    """The grouped expert products, alone and inside the program that trains with them.
+    Alone: ``lfm2._gmm_tpu`` (Mosaic on a TPU, Pallas' interpreter elsewhere) at three
+    passes over ``product``'s ``[M, K, N]`` and groups, uneven and one of them empty,
+    value, input gradient and weight gradient against ``lax.ragged_dot`` at ``highest``.
+    Inside: ``cli.run`` of the sequence-policy PPO loop with telemetry on, then the run's
+    own counters and, on a TPU, the compiled ``anakin_step``'s custom calls by name stack."""
+    import jax
+    import jax.numpy as jnp
+
+    import sheeprl_tpu.algos.ppo.anakin as anakin
+    from sheeprl_tpu.cli import run
+    from sheeprl_tpu.models import lfm2
+    from sheeprl_tpu.obs.jsonl import read_events
+
+    m, k, n, groups = product
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    shares = jax.random.dirichlet(keys[0], jnp.full((groups - 1,), 4.0))
+    sizes = jnp.concatenate([jnp.floor(shares * (m // 4)), jnp.zeros((1,))]).astype(jnp.int32)  # the last group empty
+    valid = (jnp.arange(m) < sizes.sum())[:, None]
+    rows = jnp.where(valid, jax.random.normal(keys[1], (m, k)), 0.0)
+    weights = jax.random.normal(keys[2], (groups, k, n)) / jnp.sqrt(k)
+    cotangent = jnp.where(valid, jax.random.normal(keys[3], (m, n)), 0.0)
+
+    def through(product_fn):
+        def masked(rows, weights):  # as `lfm2.grouped_matmul` masks: rows of no group read 0 both ways
+            return jnp.where(valid, product_fn(jnp.where(valid, rows, 0.0), weights), 0.0)
+
+        def loss(rows, weights):
+            return jnp.sum(masked(rows, weights) * cotangent)
+
+        return jax.jit(lambda r, w: (masked(r, w), *jax.grad(loss, argnums=(0, 1))(r, w)))
+
+    kernels = through(lambda r, w: lfm2._gmm_tpu(r, w, sizes, 3))(rows, weights)
+    with jax.default_matmul_precision("highest"):
+        expected = through(lambda r, w: jax.lax.ragged_dot(r, w, sizes))(rows, weights)
+    gaps = {
+        name: float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+        for name, got, want in zip(("value", "input_gradient", "weight_gradient"), kernels, expected)
+    }
+    _check(
+        all(gap < THREE_PASS_BOUND for gap in gaps.values()),
+        f"the grouped products at {list(product)} are off `ragged_dot` at `highest` by {gaps}, over {THREE_PASS_BOUND}",
+    )
+    _check(not bool(jnp.any(kernels[2][-1])), "an expert that no pair landed on has a weight gradient")
+
+    programs = []
+
+    class SeenProgram:  # the loop's own fused program, compiled once more to read its op names
+        def __init__(self, fused):
+            self.fused, self.hlo = fused, None
+
+        def __call__(self, *args):
+            if self.hlo is None and platform == "tpu":
+                self.hlo = self.fused.lower(*args).compile().as_text()
+            return self.fused(*args)
+
+        def __getattr__(self, name):  # `lower`, for the telemetry's program analysis
+            return getattr(self.fused, name)
+
+    def seen_program(*args, **kwargs):
+        fused, *rest = original(*args, **kwargs)
+        programs.append(SeenProgram(fused))
+        return (programs[-1], *rest)
+
+    run_dir = os.path.join(out_dir, "experts")
+    t0 = time.perf_counter()
+    original, anakin.make_anakin_program = anakin.make_anakin_program, seen_program
+    try:
+        run(
+            list(overrides)
+            + [f"hydra.run.dir={run_dir}", "metric.telemetry.enabled=true", "metric.telemetry.every=1"]
+        )
+    finally:
+        anakin.make_anakin_program = original
+    wall = time.perf_counter() - t0
+
+    (stream,) = glob.glob(os.path.join(run_dir, "version_*", "telemetry.jsonl"))
+    events = read_events(stream)
+    start, summary = _one(events, "start"), _one(events, "summary")
+    _check(start["platform"] == platform, f"the policy was built on {start['platform']!r}, not {platform!r}")
+    _check(summary["clean_exit"] is True, "the sequence-policy run did not exit cleanly")
+    counted: Dict[str, list] = {}
+    for event in events:
+        for name, (count, total) in (event.get("counters") or {}).items():
+            seen = counted.setdefault(name, [0, 0.0])
+            seen[0], seen[1] = seen[0] + count, seen[1] + total
+    mean = {name: total / count for name, (count, total) in counted.items() if count}
+    _check(
+        mean.get("moe/update_pairs_held", 0) > 0 and mean.get("moe/update_pairs_dropped") == 0
+        and mean.get("moe/rollout_pairs_dropped") == 0,
+        f"the run's expert counters: {mean}",
+    )
+    _check(
+        0 < mean.get("moe/update_tile_fill", 0) <= 1,
+        f"`moe/update_tile_fill` reads {mean.get('moe/update_tile_fill')}: the update did not sort its pairs",
+    )
+    calls = []
+    if platform == "tpu":
+        # the CLI runs at `float32_matmul_precision=high`: three bf16 passes in the kernels
+        _check(
+            mean.get("moe/update_grouped_product_passes") == 3,
+            f"`moe/update_grouped_product_passes` reads {mean.get('moe/update_grouped_product_passes')} under `high`, not 3",
+        )
+        (program,) = programs
+        calls = [line for line in (program.hlo or "").splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+        scoped = [line for line in calls if "/update/" in line and "experts" in line and "grouped_matmul" in line]
+        _check(
+            len(scoped) > 0 and len(scoped) == len(calls),
+            f"{len(scoped)} of the program's {len(calls)} kernel calls sit under `update/.../experts`",
+        )
+    result = {
+        "telemetry": stream,
+        "product": list(product),
+        "gaps_to_ragged_dot_at_highest": gaps,
+        "counters": {name: mean[name] for name in sorted(mean) if name.startswith("moe/")},
+        "kernel_calls_under_update_experts": len(calls),
+        "compile": summary["compile"],
+        "wall_seconds": round(wall, 1),
+    }
+    print(f"[chip-smoke] experts: {json.dumps(result)}", flush=True)
+    return result
+
+
 def main() -> int:
     for fresh in (WORK_DIR, REPORT_DIR):  # no earlier run is read
         shutil.rmtree(fresh, ignore_errors=True)
@@ -384,6 +541,7 @@ def main() -> int:
     served = serve_phase(
         train["checkpoint"], S_SERVE_OVERRIDES, platform="tpu", sessions=SESSIONS, out_dir=WORK_DIR
     )
+    experts = experts_phase(LM_OVERRIDES, platform="tpu", out_dir=WORK_DIR)
     verdict = {"ok": True, "device": {k: device[k] for k in ("platform", "kind", "count")}}
     result = {
         **verdict,
@@ -392,9 +550,10 @@ def main() -> int:
         "kernel": kernel,
         "train": train,
         "serve": served,
+        "experts": experts,
         "claim": None,
     }
-    for name, stream in (("train", train["telemetry"]), ("serve", served["telemetry"])):
+    for name, stream in (("train", train["telemetry"]), ("serve", served["telemetry"]), ("experts", experts["telemetry"])):
         shutil.copy(stream, os.path.join(REPORT_DIR, f"{name}.telemetry.jsonl"))
     with open(os.path.join(REPORT_DIR, "result.json"), "w") as fh:
         json.dump(result, fh, indent=1)

@@ -31,6 +31,8 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from sheeprl_tpu.ops.grouped_matmul import gmm, gmm_tiling, row_tiles_visited, tgmm, tgmm_tiling
+
 INIT_STD = 0.02
 EXPERT_BIAS_STD = 0.05
 WEIGHT_SUM_EPS = 1e-20
@@ -255,59 +257,81 @@ def _permute_bwd(res, g):
 _permute.defvjp(_permute_fwd, _permute_bwd)
 
 
-def _tiling(m: int, k: int, n: int):
-    def tile(size, want):
-        return next(t for t in (want, 256, 128) if size % t == 0)
-
-    return tile(m, 512), tile(k, 512), tile(n, 256)
+# bf16 passes of a float32 product at each ambient matmul precision (`jax.default_matmul_precision`,
+# which `cli.py` sets from `float32_matmul_precision`): what XLA:TPU gives every `@` of this model
+_PASSES = {None: 1, "default": 1, "high": 3, "highest": 6}
 
 
-@jax.custom_vjp
-def _gmm_tpu(rows, weights, group_sizes):
-    """Pallas' grouped matmul (megablox) in float32. Its kernels take their matmul
-    precision from the ambient default, and Mosaic knows only `default` (one bf16 pass)
-    and `highest`: a program at `high` asks for `highest` here, forward and backward."""
-    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
-
-    with jax.default_matmul_precision("highest"):
-        return gmm(rows, weights, group_sizes, jnp.float32, _tiling(rows.shape[0], rows.shape[1], weights.shape[2]))
+def matmul_passes() -> int:
+    """How many bf16 passes the grouped products take: as many as the precision in force
+    when the program is traced gives every other product (`high` three, `highest` six)."""
+    precision = jax.config.jax_default_matmul_precision
+    if precision not in _PASSES:
+        raise ValueError(f"lfm2: no count of bf16 passes is known for jax_default_matmul_precision={precision!r}")
+    return _PASSES[precision]
 
 
-def _gmm_tpu_fwd(rows, weights, group_sizes):
-    return _gmm_tpu(rows, weights, group_sizes), (rows, weights, group_sizes)
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gmm_tpu(rows, weights, group_sizes, passes):
+    """The repo's grouped matmul kernels (`ops/grouped_matmul.py`) in float32 at `passes`
+    bf16 passes, forward and backward: a tile is read from HBM once and split in VMEM.
+    Off the chip (tests) the same kernels run in Pallas' interpreter."""
+    (m, k), n = rows.shape, weights.shape[2]
+    return gmm(rows, weights, group_sizes, gmm_tiling(m, k, n), passes, interpret=_interpret())
 
 
-def _gmm_tpu_bwd(res, g):
-    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+def _gmm_tpu_fwd(rows, weights, group_sizes, passes):
+    return _gmm_tpu(rows, weights, group_sizes, passes), (rows, weights, group_sizes)
 
+
+def _gmm_tpu_bwd(passes, res, g):
     rows, weights, group_sizes = res
-    tiling = _tiling(rows.shape[0], rows.shape[1], weights.shape[2])
-    with jax.default_matmul_precision("highest"):
-        d_rows = gmm(g, weights, group_sizes, jnp.float32, tiling, transpose_rhs=True)
-        d_weights = tgmm(rows.swapaxes(0, 1), g, group_sizes, jnp.float32, tiling, num_actual_groups=weights.shape[0])
+    (m, k), n = rows.shape, weights.shape[2]
+    d_rows = gmm(g, weights, group_sizes, gmm_tiling(m, n, k), passes, transpose_rhs=True, interpret=_interpret())
+    d_weights = tgmm(rows, g, group_sizes, tgmm_tiling(m, k, n), passes, interpret=_interpret())
     return d_rows, d_weights, None
 
 
 _gmm_tpu.defvjp(_gmm_tpu_fwd, _gmm_tpu_bwd)
 
 
+def _interpret() -> bool:
+    return jax.default_backend() != "tpu"
+
+
+def tile_fill(group_sizes, tm: int):
+    """Pairs landed over the rows of the ``tm``-row tiles the grouped products visit for
+    them: under 1 by the tiles that straddle a group's end, each computed in full."""
+    rows = row_tiles_visited(group_sizes, tm) * tm
+    return group_sizes.sum().astype(jnp.float32) / jnp.maximum(rows, 1).astype(jnp.float32)
+
+
+def kernel_passes(m: int, k: int, n: int) -> int:
+    """The bf16 passes the grouped kernels take for ``[m, k] x [groups, k, n]`` in the program
+    being traced, or 0 where they are not taken: off the TPU, or at a size they cannot tile."""
+    if jax.default_backend() != "tpu" or any(size % 128 for size in (m, k, n)):
+        return 0
+    return matmul_passes()
+
+
 def grouped_matmul(rows, weights, group_sizes, valid):
     """``rows`` ``[M, K]`` sorted by group, ``weights`` ``[G, K, N]``: row ``i`` of group
     ``g`` times ``weights[g]``. Rows past ``sum(group_sizes)`` (``valid`` False) belong to
     no group here: they are not computed and read as 0, both ways. Where the TPU is the
-    default backend the products are Pallas' grouped matmul (megablox ``gmm``, which visits
-    only the tiles in use); elsewhere ``lax.ragged_dot``. XLA:TPU expands a ``ragged_dot``
-    to one dense product per group (8 times the FLOPs at 8 groups), so a TPU run whose
-    widths the kernel cannot tile says so, once, rather than be measured on that path
-    with nothing said."""
+    default backend the products are the repo's grouped matmul kernels, which visit only
+    the tiles in use and take the bf16 passes of the ambient matmul precision
+    (`matmul_passes`); elsewhere ``lax.ragged_dot``. XLA:TPU expands a ``ragged_dot`` to
+    one dense product per group (8 times the FLOPs at 8 groups), so a TPU run whose widths
+    the kernel cannot tile says so, once, rather than be measured on that path with
+    nothing said."""
     rows = jnp.where(valid[:, None], rows, 0.0)
     sizes = rows.shape[0], rows.shape[1], weights.shape[2]
-    if jax.default_backend() != "tpu":
-        out = jax.lax.ragged_dot(rows, weights, group_sizes)
-    elif all(size % 128 == 0 for size in sizes):
-        out = _gmm_tpu(rows, weights, group_sizes)
+    passes = kernel_passes(*sizes)
+    if passes:
+        out = _gmm_tpu(rows, weights, group_sizes, passes)
     else:
-        _warn_dense_groups(sizes, weights.shape[0])
+        if jax.default_backend() == "tpu":
+            _warn_dense_groups(sizes, weights.shape[0])
         out = jax.lax.ragged_dot(rows, weights, group_sizes)
     return jnp.where(valid[:, None], out, 0.0)
 
@@ -338,7 +362,7 @@ def _experts_dense(p, u, ids, w, spec: LFM2Spec):
     with jax.named_scope("experts"):
         hidden = jax.nn.silu(jnp.einsum("nh,ehf->enf", u, p["w1"])) * jnp.einsum("nh,ehf->enf", u, p["w3"])
         y = jnp.einsum("enf,efh,ne->nh", hidden, p["w2"], weight)
-    return y, group_sizes, group_sizes.sum()
+    return y, group_sizes, group_sizes.sum(), {}
 
 
 def _experts_grouped(p, u, ids, w, spec: LFM2Spec):
@@ -366,23 +390,29 @@ def _experts_grouped(p, u, ids, w, spec: LFM2Spec):
     with jax.named_scope("router"):
         out = _permute(out[:n_tokens * k], inverse, order).reshape(n_tokens, k, -1)
         y = jnp.sum(out * w[..., None], axis=1)
-    return y, group_sizes, valid.sum()
+        sizes = rows.shape[0], rows.shape[1], p["w1"].shape[2]
+        of_the_form = {"tile_fill": tile_fill(group_sizes, gmm_tiling(*sizes)[0]),
+                       "grouped_product_passes": jnp.float32(kernel_passes(*sizes))}
+    return y, group_sizes, valid.sum(), of_the_form
 
 
 def expert_layer(p, u, spec: LFM2Spec):
     """``u`` ``[N, H]`` -> the held experts' part of the layer ``[N, H]``, the chosen ids
-    ``[N, k]`` and three counters (pairs on held experts, the fullest held expert's load
-    over the mean, pairs dropped: those on held experts that no product computed)."""
+    ``[N, k]`` and the counters (pairs on held experts, the fullest held expert's load
+    over the mean, pairs dropped: those on held experts that no product computed; where
+    the products are grouped, the share of their row tiles that pairs fill and the bf16
+    passes a product takes in the kernels, 0 where `lax.ragged_dot` takes it)."""
     e0, held = spec.experts_held
     with jax.named_scope("router"):
         ids, w = route(p, u, spec)
     experts = _experts_dense if u.shape[0] <= DENSE_TOKENS else _experts_grouped
-    y, group_sizes, computed = experts(p, u, ids, w, spec)
+    y, group_sizes, computed, of_the_form = experts(p, u, ids, w, spec)
     landed = jnp.sum((ids >= e0) & (ids < e0 + held))
     counters = {
         "pairs_held": landed.astype(jnp.float32),
         "max_load": group_sizes.max().astype(jnp.float32) * held / jnp.maximum(landed, 1).astype(jnp.float32),
         "pairs_dropped": (landed - computed).astype(jnp.float32),
+        **of_the_form,
     }
     return y, ids, counters
 
@@ -400,12 +430,14 @@ def _ffn(p, u, ffn: str, spec: LFM2Spec):
 
 def _stack_routes(routes):
     """Per-layer (ids ``[N, k]``, counters) -> ids ``[N, layers, k]`` and counters summed
-    (``max_load``: the mean over the layers)."""
+    (``max_load``, ``tile_fill`` and ``grouped_product_passes``: the mean over the layers)."""
     if not routes:
         return None, None
     ids = jnp.stack([r[0] for r in routes], axis=1)
     counters = {name: sum(r[1][name] for r in routes) for name in routes[0][1]}
-    counters["max_load"] = counters["max_load"] / len(routes)
+    for name in ("max_load", "tile_fill", "grouped_product_passes"):  # in this order: a set's changes from run to run
+        if name in counters:
+            counters[name] = counters[name] / len(routes)
     return ids, counters
 
 

@@ -159,8 +159,36 @@ def test_a_traced_run_after_an_untraced_one_writes_no_second_cache_entry(tmp_pat
     assert len(read["keyed_by_names"]) > len(read["untraced"])
 
 
-@pytest.mark.parametrize("telemetry", ["true", "false"])
-def test_composing_with_telemetry_leaves_the_cache_key_alone(telemetry):
+def _lowered_expert_layer(counting: bool) -> str:
+    """The grouped expert layer's value and gradient, lowered for the TPU (its kernels
+    and all) with the timer, and so the counters, on or off."""
+    import jax.numpy as jnp
+
+    from sheeprl_tpu.models import lfm2
+    from sheeprl_tpu.utils.timer import timer
+
+    spec = lfm2.LFM2Spec(
+        vocab_size=32, hidden_size=128, intermediate_size=128, moe_intermediate_size=128, num_attention_heads=2,
+        num_key_value_heads=1, layer_types=("conv",), num_dense_layers=0, num_experts=4, num_experts_per_tok=2,
+        experts_held=(0, 2), max_seq_len=8)
+    p = jax.eval_shape(lambda: lfm2.init_params(spec, jax.random.PRNGKey(0))["layer_0"]["ffn"])
+    u = jax.ShapeDtypeStruct((192, spec.hidden_size), jnp.float32)
+
+    def layer(p, u):
+        y, _, counters = lfm2.expert_layer(p, u, spec)
+        return jnp.sum(y), counters
+
+    was, timer.disabled = timer.disabled, not counting
+    try:
+        lowered = jax.jit(jax.grad(layer, has_aux=True)).trace(p, u).lower(lowering_platforms=("tpu",))
+    finally:
+        timer.disabled = was
+    return lowered.as_text()
+
+
+@pytest.mark.parametrize("exp, telemetry", [("dreamer_v3", "true"), ("dreamer_v3", "false"),
+                                            ("ppo_anakin_lfm2", "true"), ("ppo_anakin_lfm2", "false")])
+def test_composing_with_telemetry_leaves_the_cache_key_alone(exp, telemetry, monkeypatch):
     from sheeprl_tpu import cli
     from sheeprl_tpu.config import compose
 
@@ -169,11 +197,18 @@ def test_composing_with_telemetry_leaves_the_cache_key_alone(telemetry):
     before = {knob: getattr(jax.config, knob) for knob in knobs}
     assert before["jax_compilation_cache_include_metadata_in_key"] is False  # JAX's default
     try:
-        cfg = compose(["exp=dreamer_v3", f"metric.telemetry.enabled={telemetry}",
+        cfg = compose([f"exp={exp}", f"metric.telemetry.enabled={telemetry}",
                        "metric.profiler.mode=" + ("window" if telemetry == "true" else "off")])
         cli._setup_xla_env(cfg)
         assert jax.config.jax_compilation_cache_include_metadata_in_key is False
         assert jax.config.jax_compilation_cache_dir == before["jax_compilation_cache_dir"]
+        if exp == "ppo_anakin_lfm2":
+            # the sequence program's two new counters, `moe/update_tile_fill` and
+            # `moe/update_grouped_product_passes`, are scalars the program returns either way
+            # (the loop hands them to the timer only while it is on): one program, one cache key
+            monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+            text = _lowered_expert_layer(counting=telemetry == "true")
+            assert "tpu_custom_call" in text and text == _lowered_expert_layer(counting=telemetry != "true")
     finally:
         for knob, value in before.items():
             jax.config.update(knob, value)

@@ -41,6 +41,19 @@ def test_phases_run_on_cpu_at_tiny_widths(tmp_path):
     assert served["sessions_finished"] == 2 and served["sessions_failed"] == 0
 
 
+@pytest.mark.timeout(300)
+def test_the_experts_phase_runs_on_cpu_at_small_widths(tmp_path):
+    """The kernels in Pallas' interpreter against `ragged_dot`, then the sequence-policy loop
+    at widths they would tile: on the CPU the update sorts its pairs and takes `ragged_dot`."""
+    small = [o for o in chip_smoke.LM_OVERRIDES if not o.startswith(("fabric.accelerator", "algo.lm.", "env.num_envs", "algo.total_steps"))]
+    small += ["fabric.accelerator=cpu", "env.num_envs=4", "algo.total_steps=1536", "algo.per_rank_batch_size=2",
+              "algo.lm.hidden_size=128", "algo.lm.moe_intermediate_size=128", "algo.lm.vocab_size=64", "algo.lm.experts_held=[0,4]"]
+    experts = chip_smoke.experts_phase(small, platform="cpu", out_dir=str(tmp_path), product=(512, 256, 128, 4))
+    assert max(experts["gaps_to_ragged_dot_at_highest"].values()) < chip_smoke.THREE_PASS_BOUND
+    assert experts["counters"]["moe/update_pairs_dropped"] == 0 and 0 < experts["counters"]["moe/update_tile_fill"] <= 1
+    assert experts["counters"]["moe/update_grouped_product_passes"] == 0 and experts["kernel_calls_under_update_experts"] == 0
+
+
 def test_script_refuses_to_pass_without_the_chip(tmp_path, monkeypatch, capsys):
     # `python chip_smoke.py` is sys.exit(main()): what main() raises is a non-zero exit
     monkeypatch.setattr(chip_smoke, "WORK_DIR", str(tmp_path / "work"))
@@ -59,7 +72,7 @@ def test_last_line_is_the_verdict_and_nothing_else(tmp_path, monkeypatch, capsys
     monkeypatch.setattr(chip_smoke, "WORK_DIR", str(tmp_path / "work"))
     monkeypatch.setattr(chip_smoke, "REPORT_DIR", str(tmp_path / "report"))
     monkeypatch.setattr(chip_smoke, "device_report", lambda platform: device)
-    for name in ("kernel_phase", "train_phase", "serve_phase"):
+    for name in ("kernel_phase", "train_phase", "serve_phase", "experts_phase"):
         monkeypatch.setattr(chip_smoke, name, lambda *a, **k: phase)
     assert chip_smoke.main() == 0
     *_, full, last = capsys.readouterr().out.splitlines()
