@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """The quickest proof that the system still starts on the chip.
 
-One process, four phases, through the entry points a user calls:
+One process, five phases, through the entry points a user calls:
 
 1. ``kernel``: the fused Pallas LayerNorm-GRU step, compiled, at the row counts
    the S run sends it (1 from the player's step, which runs on the chip since
@@ -23,6 +23,13 @@ One process, four phases, through the entry points a user calls:
    ``sheeprl_tpu.cli.run`` on ``exp=ppo_anakin_lfm2`` at widths the kernels tile,
    whose update must hold the kernels under its ``experts`` scope, count three
    bf16 passes at the CLI's ``high`` and drop no pair.
+5. ``qwen3_next``: the sequence policy's second trunk (``models/qwen3_next.py``)
+   at Qwen3-Next-80B-A3B's published widths, one period of its layers: tokens
+   decoded one a step through the three kinds of state against the chunked
+   whole-sequence forward; then ``sheeprl_tpu.cli.run`` on
+   ``exp=ppo_anakin_qwen3_next`` at widths the kernels tile, whose update's
+   bounded dispatch must drop no pair, fill its buffers by a share in (0, 1]
+   and count three bf16 passes.
 
 Every check reads what the run itself recorded (its telemetry stream, its
 checkpoint) or what JAX reports; a failed check raises, so any failed phase is a
@@ -97,6 +104,42 @@ LM_OVERRIDES = [
     "checkpoint.save_last=False",
     "metric.log_level=0",
 ]
+# the same loop on the `qwen3_next` trunk, at widths the grouped kernels tile, with a share
+# (8 of 64 experts, 4 a token) whose dispatch bound is under tokens x k
+Q3N_OVERRIDES = [
+    "exp=ppo_anakin_qwen3_next",
+    "fabric.accelerator=tpu",
+    "fabric.devices=1",
+    "env.num_envs=16",
+    "algo.rollout_steps=128",
+    "algo.per_rank_batch_size=8",
+    "algo.total_steps=6144",
+    "algo.lm.vocab_size=1024",
+    "algo.lm.hidden_size=512",
+    "algo.lm.moe_intermediate_size=256",
+    "algo.lm.shared_expert_intermediate_size=256",
+    "algo.lm.num_attention_heads=4",
+    "algo.lm.num_key_value_heads=2",
+    "algo.lm.head_dim=128",
+    "algo.lm.linear_num_key_heads=2",
+    "algo.lm.linear_num_value_heads=4",
+    "algo.lm.linear_key_head_dim=128",
+    "algo.lm.linear_value_head_dim=128",
+    "algo.lm.num_experts=64",
+    "algo.lm.experts_held=[0,8]",
+    "checkpoint.every=0",
+    "checkpoint.save_last=False",
+    "metric.log_level=0",
+]
+# Qwen3-Next-80B-A3B's published widths (perfbench/configs/qwen3_next_80b_a3b_ep16.json), one
+# period of its layers with 8 of the 512 experts held: what the decode-against-forward check runs
+Q3N_PUBLISHED = dict(
+    vocab_size=4096, hidden_size=2048, moe_intermediate_size=512, shared_expert_intermediate_size=512,
+    num_attention_heads=16, num_key_value_heads=2, head_dim=256, linear_num_key_heads=16, linear_num_value_heads=32,
+    linear_key_head_dim=128, linear_value_head_dim=128, num_experts=512, num_experts_per_tok=10, experts_held=(0, 8),
+    layer_types=("linear_attention", "linear_attention", "linear_attention", "full_attention"),
+)
+DECODE_GAP_BOUND = 2e-3  # of the logits' spread, at `high`: PERF.md section 2 has the cell's readings
 # [M, K, N] of the LFM2 cell's w1/w3 product and its 8 held experts; the bound of
 # tests/test_models/test_lfm2_grouped.py on three bf16 passes against float64
 CELL_PRODUCT = (32768, 2048, 1792, 8)
@@ -401,6 +444,30 @@ def serve_phase(checkpoint: str, overrides: Sequence[str], *, platform: str, ses
     return result
 
 
+def _sequence_run_counters(run_dir: str, platform: str):
+    """(the stream, its summary, the mean of each counter) of a sequence-policy run that has
+    ended: it ran on ``platform``, exited cleanly, held pairs in the update and dropped none."""
+    from sheeprl_tpu.obs.jsonl import read_events
+
+    (stream,) = glob.glob(os.path.join(run_dir, "version_*", "telemetry.jsonl"))
+    events = read_events(stream)
+    start, summary = _one(events, "start"), _one(events, "summary")
+    _check(start["platform"] == platform, f"the policy was built on {start['platform']!r}, not {platform!r}")
+    _check(summary["clean_exit"] is True, f"the sequence-policy run in {run_dir} did not exit cleanly")
+    counted: Dict[str, list] = {}
+    for event in events:
+        for name, (count, total) in (event.get("counters") or {}).items():
+            seen = counted.setdefault(name, [0, 0.0])
+            seen[0], seen[1] = seen[0] + count, seen[1] + total
+    mean = {name: total / count for name, (count, total) in counted.items() if count}
+    _check(
+        mean.get("moe/update_pairs_held", 0) > 0 and mean.get("moe/update_pairs_dropped") == 0
+        and mean.get("moe/rollout_pairs_dropped") == 0,
+        f"the run's expert counters: {mean}",
+    )
+    return stream, summary, mean
+
+
 def experts_phase(
     overrides: Sequence[str], *, platform: str, out_dir: str, product: Sequence[int] = CELL_PRODUCT
 ) -> Dict[str, Any]:
@@ -416,7 +483,6 @@ def experts_phase(
     import sheeprl_tpu.algos.ppo.anakin as anakin
     from sheeprl_tpu.cli import run
     from sheeprl_tpu.models import lfm2
-    from sheeprl_tpu.obs.jsonl import read_events
 
     m, k, n, groups = product
     keys = jax.random.split(jax.random.PRNGKey(0), 4)
@@ -480,22 +546,7 @@ def experts_phase(
         anakin.make_anakin_program = original
     wall = time.perf_counter() - t0
 
-    (stream,) = glob.glob(os.path.join(run_dir, "version_*", "telemetry.jsonl"))
-    events = read_events(stream)
-    start, summary = _one(events, "start"), _one(events, "summary")
-    _check(start["platform"] == platform, f"the policy was built on {start['platform']!r}, not {platform!r}")
-    _check(summary["clean_exit"] is True, "the sequence-policy run did not exit cleanly")
-    counted: Dict[str, list] = {}
-    for event in events:
-        for name, (count, total) in (event.get("counters") or {}).items():
-            seen = counted.setdefault(name, [0, 0.0])
-            seen[0], seen[1] = seen[0] + count, seen[1] + total
-    mean = {name: total / count for name, (count, total) in counted.items() if count}
-    _check(
-        mean.get("moe/update_pairs_held", 0) > 0 and mean.get("moe/update_pairs_dropped") == 0
-        and mean.get("moe/rollout_pairs_dropped") == 0,
-        f"the run's expert counters: {mean}",
-    )
+    stream, summary, mean = _sequence_run_counters(run_dir, platform)
     _check(
         0 < mean.get("moe/update_tile_fill", 0) <= 1,
         f"`moe/update_tile_fill` reads {mean.get('moe/update_tile_fill')}: the update did not sort its pairs",
@@ -527,6 +578,73 @@ def experts_phase(
     return result
 
 
+def qwen3_next_phase(
+    overrides: Sequence[str], *, platform: str, out_dir: str, widths: Dict[str, Any] = Q3N_PUBLISHED,
+    batch: int = 4, steps: int = 96
+) -> Dict[str, Any]:
+    """The `qwen3_next` trunk on this platform. Alone, at ``widths``: ``steps`` tokens decoded
+    one a step through the three kinds of state (KV cache, convolution columns, matrix
+    state) against the chunked whole-sequence forward over the same tokens, logits and
+    values. Inside: ``cli.run`` of the sequence-policy PPO loop with telemetry on, then the
+    run's own counters: no pair dropped, the bounded dispatch's fill in (0, 1] and, on a
+    TPU, three bf16 passes in the grouped kernels under `high`."""
+    import jax
+    import jax.numpy as jnp
+
+    from sheeprl_tpu.cli import run
+    from sheeprl_tpu.models import qwen3_next
+
+    spec = qwen3_next.Qwen3NextSpec(**widths, max_seq_len=steps)
+    with jax.default_matmul_precision("high"):  # what the CLI sets for a run
+        params = jax.jit(lambda key: qwen3_next.init_params(spec, key))(jax.random.PRNGKey(0))
+        tokens = jax.random.randint(jax.random.PRNGKey(1), (batch, steps), 0, spec.vocab_size)
+
+        @jax.jit
+        def decode(params, tokens):
+            def one(carry, column):
+                logits, value, carry, _, counters = qwen3_next.step(params, spec, carry, column)
+                return carry, (logits, value, counters["pairs_dropped"])
+
+            _, (logits, values, dropped) = jax.lax.scan(one, qwen3_next.init_carry(spec, batch), tokens.T)
+            return jnp.swapaxes(logits, 0, 1), values.T, dropped.sum()
+
+        logits, values, dropped = decode(params, tokens)
+        full_logits, full_values, _, counters = jax.jit(lambda p, t: qwen3_next.forward(p, spec, t))(params, tokens)
+    spread = float(jnp.std(full_logits))
+    gaps = {"logits": float(jnp.max(jnp.abs(logits - full_logits))) / spread,
+            "values": float(jnp.max(jnp.abs(values - full_values))) / max(float(jnp.std(full_values)), 1e-30)}
+    _check(
+        all(gap < DECODE_GAP_BOUND for gap in gaps.values()),
+        f"decoding through the state is off the full forward by {gaps} of the spread, over {DECODE_GAP_BOUND}",
+    )
+    _check(float(dropped) == 0 and float(counters["pairs_dropped"]) == 0, "a pair on a held expert was dropped")
+    del params
+
+    run_dir = os.path.join(out_dir, "qwen3_next")
+    t0 = time.perf_counter()
+    run(list(overrides) + [f"hydra.run.dir={run_dir}", "metric.telemetry.enabled=true", "metric.telemetry.every=1"])
+    wall = time.perf_counter() - t0
+    stream, summary, mean = _sequence_run_counters(run_dir, platform)
+    _check(
+        0 < mean.get("moe/update_dispatch_fill", 0) <= 1,
+        f"`moe/update_dispatch_fill` reads {mean.get('moe/update_dispatch_fill')}: not in (0, 1]",
+    )
+    if platform == "tpu":
+        _check(
+            mean.get("moe/update_grouped_product_passes") == 3,
+            f"`moe/update_grouped_product_passes` reads {mean.get('moe/update_grouped_product_passes')} under `high`, not 3",
+        )
+    result = {
+        "telemetry": stream,
+        "decode_gaps_to_the_full_forward": gaps,
+        "counters": {name: mean[name] for name in sorted(mean) if name.startswith("moe/")},
+        "compile": summary["compile"],
+        "wall_seconds": round(wall, 1),
+    }
+    print(f"[chip-smoke] qwen3_next: {json.dumps(result)}", flush=True)
+    return result
+
+
 def main() -> int:
     for fresh in (WORK_DIR, REPORT_DIR):  # no earlier run is read
         shutil.rmtree(fresh, ignore_errors=True)
@@ -542,6 +660,7 @@ def main() -> int:
         train["checkpoint"], S_SERVE_OVERRIDES, platform="tpu", sessions=SESSIONS, out_dir=WORK_DIR
     )
     experts = experts_phase(LM_OVERRIDES, platform="tpu", out_dir=WORK_DIR)
+    trunk = qwen3_next_phase(Q3N_OVERRIDES, platform="tpu", out_dir=WORK_DIR)
     verdict = {"ok": True, "device": {k: device[k] for k in ("platform", "kind", "count")}}
     result = {
         **verdict,
@@ -551,9 +670,11 @@ def main() -> int:
         "train": train,
         "serve": served,
         "experts": experts,
+        "qwen3_next": trunk,
         "claim": None,
     }
-    for name, stream in (("train", train["telemetry"]), ("serve", served["telemetry"]), ("experts", experts["telemetry"])):
+    streams = (("train", train), ("serve", served), ("experts", experts), ("qwen3_next", trunk))
+    for name, stream in ((name, phase["telemetry"]) for name, phase in streams):
         shutil.copy(stream, os.path.join(REPORT_DIR, f"{name}.telemetry.jsonl"))
     with open(os.path.join(REPORT_DIR, "result.json"), "w") as fh:
         json.dump(result, fh, indent=1)

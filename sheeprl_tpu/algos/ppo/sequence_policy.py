@@ -10,8 +10,16 @@ a step and keeps its own state over the episode. The fused loop asks three thing
   teacher-forced, inside the loss.
 
 ``aux`` is ``{"route_ids", "counters"}`` where the trunk has expert layers (the experts
-each token chose, and the layers' counters), else empty. The one trunk there is so far
-is ``models/lfm2.py`` (LFM2-8B-A1B's block, ``lfm2_moe``): ``algo.lm`` holds its sizes.
+each token chose, and the layers' counters), else empty.
+
+There are two trunks, and ``algo.lm.model_type`` names one (`TRUNKS`): ``lfm2_moe`` (the
+default; ``models/lfm2.py``, LFM2-8B-A1B's block: gated short convolutions and grouped-query
+attention, a sigmoid router with a bias) and ``qwen3_next`` (``models/qwen3_next.py``,
+Qwen3-Next-80B-A3B's block: gated delta-rule linear attention 3:1 with gated attention, a
+softmax router and a shared expert). A trunk is a module with ``init_params``, ``init_carry``,
+``step`` and ``forward`` and a spec class with ``from_cfg``; ``algo.lm`` holds its sizes (the
+published ones are in ``perfbench/configs/lfm2_8b_a1b_ep4.json`` and
+``perfbench/configs/qwen3_next_80b_a3b_ep16.json``). What the two share is ``models/lm_layers.py``.
 """
 
 from __future__ import annotations
@@ -21,25 +29,29 @@ from typing import Any, Dict, Tuple
 
 import jax
 
-from sheeprl_tpu.models import lfm2
+from sheeprl_tpu.models import lfm2, qwen3_next
+
+# `algo.lm.model_type` -> (the trunk's module, its spec)
+TRUNKS = {"lfm2_moe": (lfm2, lfm2.LFM2Spec), "qwen3_next": (qwen3_next, qwen3_next.Qwen3NextSpec)}
 
 
 @dataclass(frozen=True)
 class SequencePolicy:
-    spec: lfm2.LFM2Spec
+    spec: Any
+    trunk: Any = lfm2
 
     def init(self, key: jax.Array) -> Dict[str, Any]:
-        return lfm2.init_params(self.spec, key)
+        return self.trunk.init_params(self.spec, key)
 
     def initial_carry(self, batch: int) -> Dict[str, Any]:
-        return lfm2.init_carry(self.spec, batch)
+        return self.trunk.init_carry(self.spec, batch)
 
     def step(self, params, carry, tokens):
-        logits, value, carry, ids, counters = lfm2.step(params, self.spec, carry, tokens)
+        logits, value, carry, ids, counters = self.trunk.step(params, self.spec, carry, tokens)
         return logits, value, carry, _aux(ids, counters)
 
     def forward(self, params, tokens):
-        logits, values, ids, counters = lfm2.forward(params, self.spec, tokens)
+        logits, values, ids, counters = self.trunk.forward(params, self.spec, tokens)
         return logits, values, _aux(ids, counters)
 
 
@@ -53,5 +65,9 @@ def build_sequence_policy(cfg: Any, vocab_size: int, key: jax.Array) -> Tuple[Se
     lm = cfg.algo.lm
     if int(lm.vocab_size) != int(vocab_size):
         raise ValueError(f"algo.lm.vocab_size ({lm.vocab_size}) is not the env's ({vocab_size})")
-    policy = SequencePolicy(lfm2.LFM2Spec.from_cfg(lm, vocab_size, int(cfg.algo.rollout_steps)))
+    model_type = str(lm.get("model_type", "lfm2_moe"))
+    if model_type not in TRUNKS:
+        raise ValueError(f"algo.lm.model_type={model_type!r} names no trunk; there are {sorted(TRUNKS)}")
+    trunk, spec = TRUNKS[model_type]
+    policy = SequencePolicy(spec.from_cfg(lm, vocab_size, int(cfg.algo.rollout_steps)), trunk)
     return policy, policy.init(key)

@@ -13,8 +13,12 @@ over their sum), and computes the part of the result its own experts give, by on
 of the (token, expert) pairs and grouped matrix products over the experts held. What
 absent experts would add is left out (that is another chip's part; on one chip the layer
 runs without its exchange). No token is dropped and there is no capacity: the pairs'
-buffer is the static worst case, and the grouped products visit only the rows in use;
+buffers are bounded by the share held and further rounds compute what lands beyond them
+(`lm_layers._experts_bounded`), and the grouped products visit only the rows in use;
 a decode step's few tokens skip the sort and go through every held expert (`DENSE_TOKENS`).
+The layer itself, with the norm's core, RoPE, SwiGLU, attention and the heads, is
+``models/lm_layers.py``'s, which the ``qwen3_next`` trunk shares; this module keeps the
+names it is known by (`route`, `expert_layer`, `grouped_matmul`, ...).
 
 The parts carry ``jax.named_scope`` names (``embed``, ``short_conv``, ``attention``,
 ``router``, ``experts``, ``dense_ffn``, ``lm_head``, ``value_head``), which a profiler
@@ -23,19 +27,20 @@ capture shows on each op and which change no program (names are metadata).
 
 from __future__ import annotations
 
-import warnings
 from dataclasses import dataclass
-from functools import lru_cache, partial
+from functools import partial
 from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from sheeprl_tpu.ops.grouped_matmul import gmm, gmm_tiling, row_tiles_visited, tgmm, tgmm_tiling
+from sheeprl_tpu.models import lm_layers
+from sheeprl_tpu.models.lm_layers import (  # noqa: F401  (the names this trunk's tests and callers know it by)
+    DENSE_TOKENS, INIT_STD, WEIGHT_SUM_EPS, _gmm_tpu, _warn_dense_groups, grouped_matmul, kernel_passes,
+    attend, matmul_passes, rms_core, rope, stack_routes, swiglu, tile_fill,
+)
 
-INIT_STD = 0.02
 EXPERT_BIAS_STD = 0.05
-WEIGHT_SUM_EPS = 1e-20
 
 
 @dataclass(frozen=True)
@@ -60,6 +65,9 @@ class LFM2Spec:
     norm_eps: float = 1e-5
     rope_theta: float = 1e6
     max_seq_len: int = 256
+    # the expert layer's properties (`lm_layers.expert_layer`): LFM2's router, no shared expert
+    router_scoring: str = "sigmoid_bias"
+    shared_expert: bool = False
 
     def __post_init__(self):
         e0, n = self.experts_held
@@ -149,7 +157,7 @@ def init_carry(spec: LFM2Spec, batch: int) -> Dict[str, Any]:
 # layers
 # ---------------------------------------------------------------------------------
 def rms_norm(x, weight, eps):
-    return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * weight
+    return rms_core(x, eps) * weight
 
 
 def short_conv(p, u):
@@ -171,20 +179,6 @@ def short_conv_step(p, state, u):
     return (c * y) @ p["w_out"], state
 
 
-def _rotate_half(x):
-    a, b = jnp.split(x, 2, axis=-1)
-    return jnp.concatenate([-b, a], axis=-1)
-
-
-def rope(x, positions, theta):
-    """``x`` ``[..., T, heads, d]`` at ``positions`` ``[T]``: rotate-half over the whole head."""
-    d = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    angles = positions.astype(jnp.float32)[:, None] * inv[None]
-    angles = jnp.concatenate([angles, angles], axis=-1)[:, None, :]
-    return x * jnp.cos(angles) + _rotate_half(x) * jnp.sin(angles)
-
-
 def _qkv(p, u, positions, spec: LFM2Spec):
     """``u`` ``[B, T, H]`` -> q ``[B, T, nq, d]``, k and v ``[B, T, nkv, d]``, normed and rotated."""
     bsz, t, _ = u.shape
@@ -195,21 +189,10 @@ def _qkv(p, u, positions, spec: LFM2Spec):
     return rope(q, positions, spec.rope_theta), rope(k, positions, spec.rope_theta), v
 
 
-def _attend(q, k, v, mask, spec: LFM2Spec):
-    """Grouped-query attention: query head ``i`` reads key/value head ``i // group``;
-    ``mask`` ``[Tq, Tk]`` is True where a query may look."""
-    bsz, tq, nq, d = q.shape
-    nkv = spec.num_key_value_heads
-    q = q.reshape(bsz, tq, nkv, nq // nkv, d)
-    scores = jnp.einsum("bqkgd,bskd->bkgqs", q, k) / jnp.sqrt(jnp.float32(d))
-    probs = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
-    return jnp.einsum("bkgqs,bskd->bqkgd", probs, v).reshape(bsz, tq, nq * d)
-
-
 def attention(p, u, spec: LFM2Spec):
     t = u.shape[1]
     q, k, v = _qkv(p, u, jnp.arange(t), spec)
-    return _attend(q, k, v, jnp.tril(jnp.ones((t, t), bool)), spec) @ p["wo"]
+    return attend(q, k, v, jnp.tril(jnp.ones((t, t), bool)), spec.num_key_value_heads) @ p["wo"]
 
 
 def attention_step(p, cache, u, t, spec: LFM2Spec):
@@ -219,202 +202,19 @@ def attention_step(p, cache, u, t, spec: LFM2Spec):
     keys = jax.lax.dynamic_update_slice_in_dim(cache[0], k, t, axis=1)
     values = jax.lax.dynamic_update_slice_in_dim(cache[1], v, t, axis=1)
     mask = (jnp.arange(keys.shape[1]) <= t)[None]
-    return _attend(q, keys, values, mask, spec)[:, 0] @ p["wo"], (keys, values)
+    return attend(q, keys, values, mask, spec.num_key_value_heads)[:, 0] @ p["wo"], (keys, values)
 
 
-def swiglu(p, u):
-    return (jax.nn.silu(u @ p["w1"]) * (u @ p["w3"])) @ p["w2"]
-
-
-# -- the expert layer ------------------------------------------------------------------
+# -- the expert layer (`models/lm_layers.py`), under the names this trunk is known by -----
 def route(p, u, spec: LFM2Spec):
-    """``u`` ``[N, H]`` -> the chosen experts ``[N, k]`` (top-k of ``s + b``) and their
-    weights (``s`` without ``b``, over the sum of all k chosen). The published
-    ``use_expert_bias`` and ``norm_topk_prob`` are both true and ``routed_scaling_factor``
-    is 1: the one model there is has no other value, so none is an option here."""
-    s = jax.nn.sigmoid(u @ p["router"])
-    ids = jax.lax.top_k(s + jax.lax.stop_gradient(p["bias"]), spec.num_experts_per_tok)[1]
-    w = jnp.take_along_axis(s, ids, axis=-1)
-    return ids, w / (w.sum(axis=-1, keepdims=True) + WEIGHT_SUM_EPS)
-
-
-@jax.custom_vjp
-def _permute(rows, perm, inverse):
-    """``rows[perm]`` for a permutation whose inverse is known: the transpose is the gather
-    by the inverse, where a gather's own transpose would be a scatter-add."""
-    return rows[perm]
-
-
-def _permute_fwd(rows, perm, inverse):
-    return rows[perm], (perm, inverse)
-
-
-def _permute_bwd(res, g):
-    perm, inverse = res
-    return g[inverse], None, None
-
-
-_permute.defvjp(_permute_fwd, _permute_bwd)
-
-
-# bf16 passes of a float32 product at each ambient matmul precision (`jax.default_matmul_precision`,
-# which `cli.py` sets from `float32_matmul_precision`): what XLA:TPU gives every `@` of this model
-_PASSES = {None: 1, "default": 1, "high": 3, "highest": 6}
-
-
-def matmul_passes() -> int:
-    """How many bf16 passes the grouped products take: as many as the precision in force
-    when the program is traced gives every other product (`high` three, `highest` six)."""
-    precision = jax.config.jax_default_matmul_precision
-    if precision not in _PASSES:
-        raise ValueError(f"lfm2: no count of bf16 passes is known for jax_default_matmul_precision={precision!r}")
-    return _PASSES[precision]
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _gmm_tpu(rows, weights, group_sizes, passes):
-    """The repo's grouped matmul kernels (`ops/grouped_matmul.py`) in float32 at `passes`
-    bf16 passes, forward and backward: a tile is read from HBM once and split in VMEM.
-    Off the chip (tests) the same kernels run in Pallas' interpreter."""
-    (m, k), n = rows.shape, weights.shape[2]
-    return gmm(rows, weights, group_sizes, gmm_tiling(m, k, n), passes, interpret=_interpret())
-
-
-def _gmm_tpu_fwd(rows, weights, group_sizes, passes):
-    return _gmm_tpu(rows, weights, group_sizes, passes), (rows, weights, group_sizes)
-
-
-def _gmm_tpu_bwd(passes, res, g):
-    rows, weights, group_sizes = res
-    (m, k), n = rows.shape, weights.shape[2]
-    d_rows = gmm(g, weights, group_sizes, gmm_tiling(m, n, k), passes, transpose_rhs=True, interpret=_interpret())
-    d_weights = tgmm(rows, g, group_sizes, tgmm_tiling(m, k, n), passes, interpret=_interpret())
-    return d_rows, d_weights, None
-
-
-_gmm_tpu.defvjp(_gmm_tpu_fwd, _gmm_tpu_bwd)
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def tile_fill(group_sizes, tm: int):
-    """Pairs landed over the rows of the ``tm``-row tiles the grouped products visit for
-    them: under 1 by the tiles that straddle a group's end, each computed in full."""
-    rows = row_tiles_visited(group_sizes, tm) * tm
-    return group_sizes.sum().astype(jnp.float32) / jnp.maximum(rows, 1).astype(jnp.float32)
-
-
-def kernel_passes(m: int, k: int, n: int) -> int:
-    """The bf16 passes the grouped kernels take for ``[m, k] x [groups, k, n]`` in the program
-    being traced, or 0 where they are not taken: off the TPU, or at a size they cannot tile."""
-    if jax.default_backend() != "tpu" or any(size % 128 for size in (m, k, n)):
-        return 0
-    return matmul_passes()
-
-
-def grouped_matmul(rows, weights, group_sizes, valid):
-    """``rows`` ``[M, K]`` sorted by group, ``weights`` ``[G, K, N]``: row ``i`` of group
-    ``g`` times ``weights[g]``. Rows past ``sum(group_sizes)`` (``valid`` False) belong to
-    no group here: they are not computed and read as 0, both ways. Where the TPU is the
-    default backend the products are the repo's grouped matmul kernels, which visit only
-    the tiles in use and take the bf16 passes of the ambient matmul precision
-    (`matmul_passes`); elsewhere ``lax.ragged_dot``. XLA:TPU expands a ``ragged_dot`` to
-    one dense product per group (8 times the FLOPs at 8 groups), so a TPU run whose widths
-    the kernel cannot tile says so, once, rather than be measured on that path with
-    nothing said."""
-    rows = jnp.where(valid[:, None], rows, 0.0)
-    sizes = rows.shape[0], rows.shape[1], weights.shape[2]
-    passes = kernel_passes(*sizes)
-    if passes:
-        out = _gmm_tpu(rows, weights, group_sizes, passes)
-    else:
-        if jax.default_backend() == "tpu":
-            _warn_dense_groups(sizes, weights.shape[0])
-        out = jax.lax.ragged_dot(rows, weights, group_sizes)
-    return jnp.where(valid[:, None], out, 0.0)
-
-
-@lru_cache(maxsize=None)
-def _warn_dense_groups(sizes, groups: int) -> None:
-    warnings.warn(
-        f"lfm2: grouped products of [M, K, N] = {list(sizes)} have a size that is no multiple of 128, so the "
-        f"Pallas grouped matmul cannot tile them: on this TPU they run as `lax.ragged_dot`, which XLA expands "
-        f"to one dense product for each of the {groups} groups",
-        RuntimeWarning, stacklevel=3,
-    )
-
-
-# at or under this many tokens every held expert takes every token (weight 0 where it was
-# not chosen): a group's tile in the grouped products is 128 rows at the least, so the
-# sort would buy nothing, and a decode step is bound by reading the weights either way
-DENSE_TOKENS = 128
-
-
-def _experts_dense(p, u, ids, w, spec: LFM2Spec):
-    """Few tokens (a decode step): every held expert over all of them, as batched products."""
-    e0, held = spec.experts_held
-    with jax.named_scope("router"):
-        chosen = ids[:, :, None] == (e0 + jnp.arange(held))[None, None]  # [N, k, held]
-        weight = jnp.sum(jnp.where(chosen, w[:, :, None], 0.0), axis=1)  # [N, held]
-        group_sizes = chosen.sum(axis=(0, 1)).astype(jnp.int32)
-    with jax.named_scope("experts"):
-        hidden = jax.nn.silu(jnp.einsum("nh,ehf->enf", u, p["w1"])) * jnp.einsum("nh,ehf->enf", u, p["w3"])
-        y = jnp.einsum("enf,efh,ne->nh", hidden, p["w2"], weight)
-    return y, group_sizes, group_sizes.sum(), {}
-
-
-def _experts_grouped(p, u, ids, w, spec: LFM2Spec):
-    """Many tokens (the update): one sort of the (token, expert) pairs by held expert, the
-    absent experts' pairs last, and grouped products over the rows in use."""
-    n_tokens, k = ids.shape
-    e0, held = spec.experts_held
-    with jax.named_scope("router"):
-        flat = ids.reshape(-1)
-        here = (flat >= e0) & (flat < e0 + held)
-        group = jnp.where(here, flat - e0, held)
-        order = jnp.argsort(group, stable=True)
-        inverse = jnp.argsort(order)
-        group_sizes = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
-        landed = group_sizes.sum()
-        pad = (-n_tokens * k) % 128
-        valid = jnp.arange(n_tokens * k + pad) < landed
-        rows = _permute(jnp.repeat(u, k, axis=0), order, inverse)
-        if pad:
-            rows = jnp.pad(rows, ((0, pad), (0, 0)))
-    with jax.named_scope("experts"):
-        hidden = jax.nn.silu(grouped_matmul(rows, p["w1"], group_sizes, valid)) * grouped_matmul(
-            rows, p["w3"], group_sizes, valid)
-        out = grouped_matmul(hidden, p["w2"], group_sizes, valid)
-    with jax.named_scope("router"):
-        out = _permute(out[:n_tokens * k], inverse, order).reshape(n_tokens, k, -1)
-        y = jnp.sum(out * w[..., None], axis=1)
-        sizes = rows.shape[0], rows.shape[1], p["w1"].shape[2]
-        of_the_form = {"tile_fill": tile_fill(group_sizes, gmm_tiling(*sizes)[0]),
-                       "grouped_product_passes": jnp.float32(kernel_passes(*sizes))}
-    return y, group_sizes, valid.sum(), of_the_form
+    """LFM2's router: sigmoid scores, the top-k of ``s + b`` (``spec.router_scoring``)."""
+    return lm_layers.route(p, u, spec)
 
 
 def expert_layer(p, u, spec: LFM2Spec):
-    """``u`` ``[N, H]`` -> the held experts' part of the layer ``[N, H]``, the chosen ids
-    ``[N, k]`` and the counters (pairs on held experts, the fullest held expert's load
-    over the mean, pairs dropped: those on held experts that no product computed; where
-    the products are grouped, the share of their row tiles that pairs fill and the bf16
-    passes a product takes in the kernels, 0 where `lax.ragged_dot` takes it)."""
-    e0, held = spec.experts_held
-    with jax.named_scope("router"):
-        ids, w = route(p, u, spec)
-    experts = _experts_dense if u.shape[0] <= DENSE_TOKENS else _experts_grouped
-    y, group_sizes, computed, of_the_form = experts(p, u, ids, w, spec)
-    landed = jnp.sum((ids >= e0) & (ids < e0 + held))
-    counters = {
-        "pairs_held": landed.astype(jnp.float32),
-        "max_load": group_sizes.max().astype(jnp.float32) * held / jnp.maximum(landed, 1).astype(jnp.float32),
-        "pairs_dropped": (landed - computed).astype(jnp.float32),
-        **of_the_form,
-    }
-    return y, ids, counters
+    """`lm_layers.expert_layer` behind this module's `route` (looked up when the layer is
+    traced: a fault planted under that name is the router the layer takes)."""
+    return lm_layers.expert_layer(p, u, spec, route)
 
 
 # ---------------------------------------------------------------------------------
@@ -428,26 +228,8 @@ def _ffn(p, u, ffn: str, spec: LFM2Spec):
     return expert_layer(p, u, spec)
 
 
-def _stack_routes(routes):
-    """Per-layer (ids ``[N, k]``, counters) -> ids ``[N, layers, k]`` and counters summed
-    (``max_load``, ``tile_fill`` and ``grouped_product_passes``: the mean over the layers)."""
-    if not routes:
-        return None, None
-    ids = jnp.stack([r[0] for r in routes], axis=1)
-    counters = {name: sum(r[1][name] for r in routes) for name in routes[0][1]}
-    for name in ("max_load", "tile_fill", "grouped_product_passes"):  # in this order: a set's changes from run to run
-        if name in counters:
-            counters[name] = counters[name] / len(routes)
-    return ids, counters
-
-
 def heads(params, x, spec: LFM2Spec):
-    x = rms_norm(x, params["norm"], spec.norm_eps)
-    with jax.named_scope("lm_head"):
-        logits = x @ params["lm_head"]
-    with jax.named_scope("value_head"):
-        value = (x @ params["value_head"])[..., 0]
-    return logits, value
+    return lm_layers.heads(params, rms_norm(x, params["norm"], spec.norm_eps))
 
 
 def forward(params, spec: LFM2Spec, tokens):
@@ -477,7 +259,7 @@ def forward(params, spec: LFM2Spec, tokens):
         if ids is not None:
             routes.append((ids, counters))
     logits, value = heads(params, x, spec)
-    ids, counters = _stack_routes(routes)
+    ids, counters = stack_routes(routes)
     return logits, value, None if ids is None else ids.reshape(bsz, t, *ids.shape[1:]), counters
 
 
@@ -505,11 +287,10 @@ def step(params, spec: LFM2Spec, carry, tokens):
         if ids is not None:
             routes.append((ids, counters))
     logits, value = heads(params, x, spec)
-    ids, counters = _stack_routes(routes)
+    ids, counters = stack_routes(routes)
     return logits, value, new_carry, ids, counters
 
 
 def parameter_count(spec: LFM2Spec) -> int:
-    shapes = jax.eval_shape(lambda: init_params(spec, jax.random.PRNGKey(0)))
-    return sum(int(x.size) for x in jax.tree_util.tree_leaves(shapes))
+    return lm_layers.parameter_count(init_params, spec)
 

@@ -159,23 +159,33 @@ def test_a_traced_run_after_an_untraced_one_writes_no_second_cache_entry(tmp_pat
     assert len(read["keyed_by_names"]) > len(read["untraced"])
 
 
-def _lowered_expert_layer(counting: bool) -> str:
+def _lowered_expert_layer(counting: bool, trunk: str = "lfm2") -> str:
     """The grouped expert layer's value and gradient, lowered for the TPU (its kernels
-    and all) with the timer, and so the counters, on or off."""
+    and all) with the timer, and so the counters, on or off: LFM2's on buffers of
+    ``tokens x k`` rows, or the `qwen3_next` trunk's bounded dispatch (rounds and all)."""
     import jax.numpy as jnp
 
-    from sheeprl_tpu.models import lfm2
+    from sheeprl_tpu.models import lfm2, qwen3_next
     from sheeprl_tpu.utils.timer import timer
 
-    spec = lfm2.LFM2Spec(
-        vocab_size=32, hidden_size=128, intermediate_size=128, moe_intermediate_size=128, num_attention_heads=2,
-        num_key_value_heads=1, layer_types=("conv",), num_dense_layers=0, num_experts=4, num_experts_per_tok=2,
-        experts_held=(0, 2), max_seq_len=8)
-    p = jax.eval_shape(lambda: lfm2.init_params(spec, jax.random.PRNGKey(0))["layer_0"]["ffn"])
+    if trunk == "lfm2":
+        model = lfm2
+        spec = lfm2.LFM2Spec(
+            vocab_size=32, hidden_size=128, intermediate_size=128, moe_intermediate_size=128, num_attention_heads=2,
+            num_key_value_heads=1, layer_types=("conv",), num_dense_layers=0, num_experts=4, num_experts_per_tok=2,
+            experts_held=(0, 2), max_seq_len=8)
+    else:
+        model = qwen3_next
+        spec = qwen3_next.Qwen3NextSpec(
+            vocab_size=32, hidden_size=128, moe_intermediate_size=128, shared_expert_intermediate_size=128,
+            num_attention_heads=2, num_key_value_heads=1, head_dim=16, linear_num_key_heads=1, linear_num_value_heads=2,
+            linear_key_head_dim=8, linear_value_head_dim=8, layer_types=("linear_attention",), num_experts=16,
+            num_experts_per_tok=2, experts_held=(0, 4), max_seq_len=8)
+    p = jax.eval_shape(lambda: model.init_params(spec, jax.random.PRNGKey(0))["layer_0"]["ffn"])
     u = jax.ShapeDtypeStruct((192, spec.hidden_size), jnp.float32)
 
     def layer(p, u):
-        y, _, counters = lfm2.expert_layer(p, u, spec)
+        y, _, counters = model.expert_layer(p, u, spec)
         return jnp.sum(y), counters
 
     was, timer.disabled = timer.disabled, not counting
@@ -187,7 +197,8 @@ def _lowered_expert_layer(counting: bool) -> str:
 
 
 @pytest.mark.parametrize("exp, telemetry", [("dreamer_v3", "true"), ("dreamer_v3", "false"),
-                                            ("ppo_anakin_lfm2", "true"), ("ppo_anakin_lfm2", "false")])
+                                            ("ppo_anakin_lfm2", "true"), ("ppo_anakin_lfm2", "false"),
+                                            ("ppo_anakin_qwen3_next", "true"), ("ppo_anakin_qwen3_next", "false")])
 def test_composing_with_telemetry_leaves_the_cache_key_alone(exp, telemetry, monkeypatch):
     from sheeprl_tpu import cli
     from sheeprl_tpu.config import compose
@@ -209,6 +220,11 @@ def test_composing_with_telemetry_leaves_the_cache_key_alone(exp, telemetry, mon
             monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
             text = _lowered_expert_layer(counting=telemetry == "true")
             assert "tpu_custom_call" in text and text == _lowered_expert_layer(counting=telemetry != "true")
+        if exp == "ppo_anakin_qwen3_next":  # and so are the bounded dispatch's, `moe/update_dispatch_fill` among them
+            monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+            text = _lowered_expert_layer(telemetry == "true", "qwen3_next")
+            assert "tpu_custom_call" in text and "while" in text  # the kernels, inside the rounds' loops
+            assert text == _lowered_expert_layer(telemetry != "true", "qwen3_next")
     finally:
         for knob, value in before.items():
             jax.config.update(knob, value)

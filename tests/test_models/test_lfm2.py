@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from sheeprl_tpu.models import lfm2
+from sheeprl_tpu.models import lfm2, lm_layers
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -213,7 +213,7 @@ def test_grouped_products_choose_their_path_from_the_default_backend(backend, si
     m, k, n = sizes_mkn
     took = []
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    monkeypatch.setattr(lfm2, "_gmm_tpu", lambda rows, w, sizes, passes: took.append("kernel") or jax.lax.ragged_dot(rows, w, sizes))
+    monkeypatch.setattr(lm_layers, "_gmm_tpu", lambda rows, w, sizes, passes: took.append("kernel") or jax.lax.ragged_dot(rows, w, sizes))
     lfm2._warn_dense_groups.cache_clear()
     rows, weights = jnp.ones((m, k)), jnp.ones((2, k, n))
     group_sizes, valid = jnp.array([100, 60], jnp.int32), jnp.arange(m) < 160

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from sheeprl_tpu.models import lfm2
+from sheeprl_tpu.models import lfm2, lm_layers
 from sheeprl_tpu.ops import grouped_matmul as gm
 
 # [M, K, N] of the cell's three products (w1 and w3: hidden -> expert width; w2: back), 8 groups
@@ -51,7 +51,7 @@ def test_a_product_refuses_a_number_of_passes_it_does_not_know():
 def through_the_kernels(monkeypatch):
     """`grouped_matmul` as a TPU run takes it, with the kernels in Pallas' interpreter."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(lfm2, "_interpret", lambda: True)
+    monkeypatch.setattr(lm_layers, "_interpret", lambda: True)
 
 
 @pytest.mark.parametrize("passes, precision, bound", [(3, "high", THREE_PASS_BOUND), (6, "highest", 2e-6)])
@@ -121,7 +121,7 @@ def test_the_passes_follow_the_ambient_matmul_precision(precision, passes, monke
     """`jax.default_matmul_precision` decides, as for every `@` of the model, when the product
     is traced; the layer returns the count among its counters (0 where `ragged_dot` takes it)."""
     took = []
-    monkeypatch.setattr(lfm2, "_gmm_tpu", lambda rows, w, sizes, passes: took.append(passes) or jax.lax.ragged_dot(rows, w, sizes))
+    monkeypatch.setattr(lm_layers, "_gmm_tpu", lambda rows, w, sizes, passes: took.append(passes) or jax.lax.ragged_dot(rows, w, sizes))
     rows, weights = jnp.ones((128, 128)), jnp.ones((2, 128, 128))
     step = jax.jit(lambda rows: lfm2.grouped_matmul(rows, weights, jnp.array([100, 20], jnp.int32), jnp.arange(128) < 120))
     with jax.default_matmul_precision(precision):
