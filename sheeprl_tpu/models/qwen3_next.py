@@ -13,8 +13,9 @@ The gated delta rule, per value head and token, with ``S_0 = 0``:
 ``S <- exp(g_t) S``; ``r = S^T k_t``; ``S <- S + k_t (beta_t (v_t - r))^T``; ``o_t = S^T q_t``.
 The step form is those four lines. The whole-sequence form is chunked (`chunk_delta_rule`):
 inside a chunk of ``chunk_size`` tokens the rule's pseudo-values solve one unit lower
-triangular system, and a scan over the chunks carries ``S``; it is exact, with no
-approximation the recurrence does not have, and the update differentiates through it.
+triangular system, whose inverse is formed by products for all chunks at once, and a scan
+over the chunks carries ``S``; it is exact, with no approximation the recurrence does not
+have, and the update differentiates through it.
 
 The expert layer is `lm_layers.expert_layer` with this trunk's properties: a float32
 softmax over all ``num_experts``, ``num_experts_per_tok`` of them a token, a shared expert
@@ -42,6 +43,7 @@ CONV_TAP_STD = 0.3
 L2_EPS = 1e-6
 DECAY_RANGE = (1.0, 16.0)  # A ~ U: `A_log = log(A)`
 DT_RANGE = (1e-3, 1e-1)  # dt ~ logU: `dt_bias` is its inverse softplus
+CHUNKS_A_TRIP = 8  # of the chunked rule's scan: straight-line code needs no slicing and stacking of the chunks' arrays
 
 
 @dataclass(frozen=True)
@@ -218,15 +220,64 @@ def delta_rule_step(state, q, k, v, g, beta):
     return jnp.einsum("bhkv,bhk->bhv", state, q), state
 
 
+def unit_lower_inverse(a):
+    """``(I + a)^-1`` of a strictly lower triangular ``a`` ``[..., c, c]`` by products of its
+    diagonal blocks, whose size doubles from 1 to ``c``:
+    ``[[L11, 0], [L21, L22]]^-1 = [[M11, 0], [-M22 L21 M11, M22]]``, exact because every block is
+    unit lower triangular. The blocks are at most ``c / 2`` wide, too small for the matrix unit,
+    so the batch lies in the lanes and a product is an elementwise multiply and a sum, in float32."""
+    lead, c = a.shape[:-2], a.shape[-1]
+    size = 1 << (c - 1).bit_length()  # padded to a power of two: ``[[I + a, 0], [0, I]]``
+    x = jnp.moveaxis(jnp.pad(a.reshape(-1, c, c), ((0, 0), (0, size - c), (0, size - c))), 0, -1)  # [size, size, N]
+
+    def product(u, v):  # [pairs, s, s, N] each
+        return jnp.sum(u[:, :, :, None] * v[:, None], axis=2)
+
+    inverse, s = jnp.ones((size, 1, 1, x.shape[-1]), a.dtype), 1  # a diagonal block each
+    while s < size:
+        below = jnp.stack([x[i + s:i + 2 * s, i:i + s] for i in range(0, size, 2 * s)])  # every L21
+        first, second = inverse[0::2], inverse[1::2]
+        corner = -product(second, product(below, first))
+        inverse = jnp.concatenate([jnp.concatenate([first, jnp.zeros_like(first)], axis=2),
+                                   jnp.concatenate([corner, second], axis=2)], axis=1)
+        s *= 2
+    return jnp.moveaxis(inverse[0], -1, 0)[:, :c, :c].reshape(*lead, c, c)
+
+
+@jax.custom_vjp
+def unit_lower_solve(a, rhs):
+    """``(I + a)^-1 rhs``, ``a`` strictly lower triangular ``[..., c, c]``, ``rhs`` ``[..., c, m]``.
+    The backward pass solves nothing either: it keeps the inverse, ``d rhs = inverse^T ct`` and
+    ``d a = -tril(d rhs . solved^T, -1)``."""
+    return unit_lower_inverse(a) @ rhs
+
+
+def _unit_lower_solve_fwd(a, rhs):
+    inverse = unit_lower_inverse(a)
+    solved = inverse @ rhs
+    return solved, (inverse, solved)
+
+
+def _unit_lower_solve_bwd(kept, ct):
+    inverse, solved = kept
+    d_rhs = jnp.swapaxes(inverse, -1, -2) @ ct
+    return -jnp.tril(d_rhs @ jnp.swapaxes(solved, -1, -2), -1), d_rhs
+
+
+unit_lower_solve.defvjp(_unit_lower_solve_fwd, _unit_lower_solve_bwd)
+
+
 def chunk_delta_rule(q, k, v, g, beta, chunk: int):
     """Whole sequences: ``q``, ``k`` ``[B, T, H, dk]``, ``v`` ``[B, T, H, dv]``, ``g``, ``beta``
     ``[B, T, H]`` -> ``o`` ``[B, T, H, dv]``, from ``S_0 = 0``. Inside a chunk, with ``G_t`` the
     decay accumulated since the chunk began and ``S_0`` the state it began with, the
     pseudo-values ``d_t = beta_t (v_t - S~_t^T k_t)`` solve ``(I + A) D = beta V - (beta G K) S_0``,
-    ``A_tj = beta_t (G_t / G_j) (k_t . k_j)`` for ``j < t``: one unit lower triangular solve a
-    chunk gives both right-hand sides' solutions, and a scan over the chunks carries ``S``.
-    A chunk's own intermediates are recomputed in the backward pass (``jax.checkpoint`` on the
-    scan's body): what the scan keeps is ``S`` at every chunk's start."""
+    ``A_tj = beta_t (G_t / G_j) (k_t . k_j)`` for ``j < t``. What does not need ``S_0`` is done
+    once for all chunks together: ``U = (I + A)^-1 beta V``, ``W = (I + A)^-1 beta G K``
+    (`unit_lower_solve`), the decayed ``q k^T`` inside a chunk, ``G q`` and the keys decayed to
+    the chunk's end. The scan over the chunks carries ``S`` alone, three products a chunk:
+    ``D = U - W S``; ``o = (G q) S + inside D``; ``S <- G_end S + k_to_end^T D``. It takes
+    `CHUNKS_A_TRIP` chunks a trip, so up to that many chunks there is no loop at all."""
     bsz, t, heads, dk = q.shape
     dv = v.shape[-1]
     pad = (-t) % chunk  # a padded token writes nothing (k, v, beta 0) and decays nothing (g 0)
@@ -237,25 +288,27 @@ def chunk_delta_rule(q, k, v, g, beta, chunk: int):
         x = x.reshape(bsz, n, chunk, *x.shape[2:])
         return jnp.moveaxis(jnp.swapaxes(x, 2, 3), 1, 0)
 
+    q, k, v, g, beta = map(chunks, (q, k, v, g, beta))
     lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    since = jnp.cumsum(g, axis=-1)  # log G_t
+    # masked before the exponential: above the diagonal the exponent is positive and may overflow
+    decay = jnp.exp(jnp.where(lower, since[..., :, None] - since[..., None, :], -jnp.inf))
+    from_start = jnp.exp(since)[..., None]
+    k_beta = k * beta[..., None]
+    a = jnp.where(jnp.tril(lower, -1), jnp.einsum("...ik,...jk->...ij", k_beta, k) * decay, 0.0)
+    solved = unit_lower_solve(a, jnp.concatenate([v * beta[..., None], k_beta * from_start], axis=-1))
+    inside = jnp.einsum("...ik,...jk->...ij", q, k) * decay  # (G_t / G_j) (q_t . k_j), j <= t
+    k_to_end = k * jnp.exp(since[..., -1:] - since)[..., None]
+    whole = jnp.exp(since[..., -1])[..., None, None]
 
-    @jax.checkpoint
     def one_chunk(state, xs):
-        q, k, v, g, beta = xs  # [B, H, chunk, ...]
-        since = jnp.cumsum(g, axis=-1)  # log G_t
-        # masked before the exponential: above the diagonal the exponent is positive and may overflow
-        decay = jnp.exp(jnp.where(lower, since[..., :, None] - since[..., None, :], -jnp.inf))
-        k_beta = k * beta[..., None]
-        a = jnp.where(jnp.tril(lower, -1), jnp.einsum("...ik,...jk->...ij", k_beta, k) * decay, 0.0)
-        rhs = jnp.concatenate([v * beta[..., None], k_beta * jnp.exp(since)[..., None]], axis=-1)
-        solved = jax.scipy.linalg.solve_triangular(a + jnp.eye(chunk, dtype=a.dtype), rhs, lower=True, unit_diagonal=True)
-        pseudo = solved[..., :dv] - solved[..., dv:] @ state
-        inside = jnp.einsum("...ik,...jk->...ij", q, k) * decay  # (G_t / G_j) (q_t . k_j), j <= t
-        out = (q * jnp.exp(since)[..., None]) @ state + inside @ pseudo
-        k_to_end = k * jnp.exp(since[..., -1:] - since)[..., None]
-        return state * jnp.exp(since[..., -1])[..., None, None] + jnp.swapaxes(k_to_end, -1, -2) @ pseudo, out
+        u, w, q_decayed, inside, k_to_end, whole = xs  # [B, H, chunk, ...]
+        pseudo = u - w @ state
+        out = q_decayed @ state + inside @ pseudo
+        return whole * state + jnp.swapaxes(k_to_end, -1, -2) @ pseudo, out
 
-    _, out = jax.lax.scan(one_chunk, jnp.zeros((bsz, heads, dk, dv), q.dtype), tuple(map(chunks, (q, k, v, g, beta))))
+    xs = (solved[..., :dv], solved[..., dv:], q * from_start, inside, k_to_end, whole)
+    _, out = jax.lax.scan(one_chunk, jnp.zeros((bsz, heads, dk, dv), q.dtype), xs, unroll=CHUNKS_A_TRIP)
     out = jnp.swapaxes(jnp.moveaxis(out, 0, 1), 2, 3).reshape(bsz, t + pad, heads, dv)
     return out[:, :t]
 

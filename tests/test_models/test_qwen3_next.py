@@ -104,7 +104,8 @@ def _delta_inputs(key, t=T, heads=3, dk=8, dv=6):
     return q, k, v, g, beta
 
 
-@pytest.mark.parametrize("t, chunk", [(T, 8), (16, 8), (5, 8), (T, 64)], ids=["ragged", "whole", "short", "one_chunk"])
+@pytest.mark.parametrize("t, chunk", [(T, 8), (16, 8), (5, 8), (T, 64), (76, 8)],
+                         ids=["ragged", "whole", "short", "one_chunk", "more_chunks_than_a_trip"])
 def test_the_chunked_delta_rule_is_the_recurrence_in_values_and_gradients(t, chunk):
     inputs = _delta_inputs(jax.random.PRNGKey(3), t=t)
     cotangent = jax.random.normal(jax.random.PRNGKey(4), (2, t, 3, 6))
@@ -126,6 +127,68 @@ def test_the_chunked_rule_stays_finite_where_a_chunk_forgets_everything():
     grads = jax.grad(loss, argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
     assert all(np.all(np.isfinite(x)) for x in grads)
     close(qwen3_next.chunk_delta_rule(q, k, v, g, beta, 8), ref.delta_rule(q, k, v, g, beta))
+
+
+@pytest.mark.parametrize("chunk", [8, 64, 12], ids=["chunk8", "chunk64", "no_power_of_two"])
+def test_the_inverse_by_products_is_the_triangular_solve_in_values_and_gradients(chunk):
+    keys = jax.random.split(jax.random.PRNGKey(6), 3)
+    a = jnp.tril(jax.random.normal(keys[0], (2, 3, chunk, chunk)) * 0.3, -1)
+    rhs = jax.random.normal(keys[1], (2, 3, chunk, 5))
+    cotangent = jax.random.normal(keys[2], rhs.shape)
+    eye = jnp.eye(chunk)
+
+    def substituted(a, rhs):  # `a` enters through its strictly lower triangle alone, as the rule's does
+        return jax.scipy.linalg.solve_triangular(eye + jnp.tril(a, -1), rhs, lower=True, unit_diagonal=True)
+
+    close(qwen3_next.unit_lower_inverse(a) @ (eye + a), jnp.broadcast_to(eye, a.shape), 1e-4)
+    close(qwen3_next.unit_lower_solve(a, rhs), substituted(a, rhs), 1e-4)
+    mine = jax.grad(lambda *x: jnp.sum(qwen3_next.unit_lower_solve(*x) * cotangent), argnums=(0, 1))(a, rhs)
+    theirs = jax.grad(lambda *x: jnp.sum(substituted(*x) * cotangent), argnums=(0, 1))(a, rhs)
+    for x, y in zip(mine, theirs):
+        close(x, y, 1e-4 * float(jnp.max(jnp.abs(y))))
+    assert not np.any(np.triu(np.asarray(mine[0])))  # nothing flows to what `a` is not
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold, at any depth."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for inner in (value if isinstance(value, (tuple, list)) else (value,)):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner)
+
+
+@pytest.mark.parametrize("t, chunk", [(T, 8), (76, 8)], ids=["ragged", "more_chunks_than_a_trip"])
+def test_the_rules_gradient_solves_nothing_and_its_loops_carry_the_state_alone(t, chunk):
+    """The mechanism's counter, read from the program's text: no triangular solve in the value or
+    the gradient, and each loop (the scan forward, its transpose backward) carries one array of
+    ``S``'s shape: the chunks' own work is outside them."""
+    inputs = _delta_inputs(jax.random.PRNGKey(7), t=t)
+    loss = lambda *x: jnp.sum(jnp.square(qwen3_next.chunk_delta_rule(*x, chunk)))  # noqa: E731
+    equations = list(_equations(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(*inputs).jaxpr))
+    names = {eqn.primitive.name for eqn in equations}
+    assert "triangular_solve" not in names and "custom_linear_solve" not in names
+    state = (inputs[0].shape[0], inputs[0].shape[2], inputs[0].shape[3], inputs[2].shape[3])  # [B, H, dk, dv]
+    loops = [eqn for eqn in equations if eqn.primitive.name in ("scan", "while")]
+    assert len(loops) == 2  # forward and backward
+    for eqn in loops:
+        consts, carried = eqn.params["num_consts"], eqn.params["num_carry"]
+        assert [v.aval.shape for v in eqn.invars[consts:consts + carried]] == [state]
+        assert eqn.params["length"] == -(-t // chunk)
+
+
+def test_heads_that_forget_everything_beside_heads_that_forget_nothing_stay_finite():
+    q, k, v, g, beta = _delta_inputs(jax.random.PRNGKey(8))
+    g = jnp.broadcast_to(jnp.linspace(-20.0, 0.0, g.shape[-1]), g.shape)  # a head each: from exp(-20) a token to no decay
+    loss = lambda *x: jnp.sum(jnp.square(qwen3_next.chunk_delta_rule(*x, 8)))  # noqa: E731
+    grads = jax.grad(loss, argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+    assert all(np.all(np.isfinite(x)) for x in grads)
+    close(qwen3_next.chunk_delta_rule(q, k, v, g, beta, 8), ref.delta_rule(q, k, v, g, beta))
+    theirs = jax.grad(lambda *x: jnp.sum(jnp.square(ref.delta_rule(*x))), argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+    for a, b in zip(grads, theirs):
+        close(a, b, 1e-4)
 
 
 def test_the_mixer_full_agrees_with_step_by_step_through_its_state():
