@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """The quickest proof that the system still starts on the chip.
 
-One process, five phases, through the entry points a user calls:
+One process, six phases, through the entry points a user calls:
 
 1. ``kernel``: the fused Pallas LayerNorm-GRU step, compiled, at the row counts
    the S run sends it (1 from the player's step, which runs on the chip since
@@ -30,6 +30,13 @@ One process, five phases, through the entry points a user calls:
    ``exp=ppo_anakin_qwen3_next`` at widths the kernels tile, whose update's
    bounded dispatch must drop no pair, fill its buffers by a share in (0, 1]
    and count three bf16 passes.
+6. ``deepseek_v3``: the third trunk (``models/deepseek_v3.py``) at
+   Moonlight-16B-A3B's published widths, the dense layer and two expert layers:
+   tokens decoded one a step in the absorbed form through the latent caches
+   against the expanded whole-sequence forward; then ``sheeprl_tpu.cli.run`` on
+   ``exp=ppo_anakin_deepseek_v3`` with experts of the published width 1408, whose
+   grouped products must take the kernels at three passes (and not
+   ``lax.ragged_dot``) and drop no pair.
 
 Every check reads what the run itself recorded (its telemetry stream, its
 checkpoint) or what JAX reports; a failed check raises, so any failed phase is a
@@ -138,6 +145,39 @@ Q3N_PUBLISHED = dict(
     num_attention_heads=16, num_key_value_heads=2, head_dim=256, linear_num_key_heads=16, linear_num_value_heads=32,
     linear_key_head_dim=128, linear_value_head_dim=128, num_experts=512, num_experts_per_tok=10, experts_held=(0, 8),
     layer_types=("linear_attention", "linear_attention", "linear_attention", "full_attention"),
+)
+# the same loop on the `deepseek_v3` trunk: latent attention at the published head sizes and experts
+# of the published width 1408 = 11 x 128 (8 of 64 held, 6 a token), which the grouped kernels take whole
+DSV3_OVERRIDES = [
+    "exp=ppo_anakin_deepseek_v3",
+    "fabric.accelerator=tpu",
+    "fabric.devices=1",
+    "env.num_envs=16",
+    "algo.rollout_steps=128",
+    "algo.per_rank_batch_size=8",
+    "algo.total_steps=6144",
+    "algo.lm.vocab_size=1024",
+    "algo.lm.hidden_size=512",
+    "algo.lm.intermediate_size=1024",
+    "algo.lm.moe_intermediate_size=1408",
+    "algo.lm.num_attention_heads=4",
+    "algo.lm.qk_nope_head_dim=128",
+    "algo.lm.qk_rope_head_dim=64",
+    "algo.lm.v_head_dim=128",
+    "algo.lm.kv_lora_rank=512",
+    "algo.lm.num_experts=64",
+    "algo.lm.num_experts_per_tok=6",
+    "algo.lm.experts_held=[0,8]",
+    "checkpoint.every=0",
+    "checkpoint.save_last=False",
+    "metric.log_level=0",
+]
+# Moonlight-16B-A3B's published widths (perfbench/configs/moonlight_16b_a3b_ep8.json), the leading
+# dense layer and two expert layers with 8 of the 64 experts held: what the decode-against-forward check runs
+DSV3_PUBLISHED = dict(
+    vocab_size=4096, hidden_size=2048, intermediate_size=11264, moe_intermediate_size=1408, num_attention_heads=16,
+    qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128, kv_lora_rank=512, num_hidden_layers=3, first_k_dense_replace=1,
+    num_experts=64, num_experts_per_tok=6, experts_held=(0, 8), n_shared_experts=2, routed_scaling_factor=2.446,
 )
 DECODE_GAP_BOUND = 2e-3  # of the logits' spread, at `high`: PERF.md section 2 has the cell's readings
 # [M, K, N] of the LFM2 cell's w1/w3 product and its 8 held experts; the bound of
@@ -578,38 +618,33 @@ def experts_phase(
     return result
 
 
-def qwen3_next_phase(
-    overrides: Sequence[str], *, platform: str, out_dir: str, widths: Dict[str, Any] = Q3N_PUBLISHED,
-    batch: int = 4, steps: int = 96
-) -> Dict[str, Any]:
-    """The `qwen3_next` trunk on this platform. Alone, at ``widths``: ``steps`` tokens decoded
-    one a step through the three kinds of state (KV cache, convolution columns, matrix
-    state) against the chunked whole-sequence forward over the same tokens, logits and
-    values. Inside: ``cli.run`` of the sequence-policy PPO loop with telemetry on, then the
-    run's own counters: no pair dropped, the bounded dispatch's fill in (0, 1] and, on a
-    TPU, three bf16 passes in the grouped kernels under `high`."""
+def _trunk_phase(name: str, trunk, spec, overrides: Sequence[str], *, platform: str, out_dir: str, batch: int) -> Dict[str, Any]:
+    """A trunk of the sequence policy on this platform. Alone, at ``spec``'s widths:
+    ``spec.max_seq_len`` tokens decoded one a step through the trunk's state against its
+    whole-sequence forward over the same tokens, logits and values. Inside: ``cli.run`` of
+    the sequence-policy PPO loop with telemetry on, then the run's own counters: no pair
+    dropped, the bounded dispatch's fill in (0, 1] and, on a TPU, three bf16 passes in the
+    grouped kernels under `high` (0 would be `lax.ragged_dot`)."""
     import jax
     import jax.numpy as jnp
 
     from sheeprl_tpu.cli import run
-    from sheeprl_tpu.models import qwen3_next
 
-    spec = qwen3_next.Qwen3NextSpec(**widths, max_seq_len=steps)
     with jax.default_matmul_precision("high"):  # what the CLI sets for a run
-        params = jax.jit(lambda key: qwen3_next.init_params(spec, key))(jax.random.PRNGKey(0))
-        tokens = jax.random.randint(jax.random.PRNGKey(1), (batch, steps), 0, spec.vocab_size)
+        params = jax.jit(lambda key: trunk.init_params(spec, key))(jax.random.PRNGKey(0))
+        tokens = jax.random.randint(jax.random.PRNGKey(1), (batch, spec.max_seq_len), 0, spec.vocab_size)
 
         @jax.jit
         def decode(params, tokens):
             def one(carry, column):
-                logits, value, carry, _, counters = qwen3_next.step(params, spec, carry, column)
+                logits, value, carry, _, counters = trunk.step(params, spec, carry, column)
                 return carry, (logits, value, counters["pairs_dropped"])
 
-            _, (logits, values, dropped) = jax.lax.scan(one, qwen3_next.init_carry(spec, batch), tokens.T)
+            _, (logits, values, dropped) = jax.lax.scan(one, trunk.init_carry(spec, batch), tokens.T)
             return jnp.swapaxes(logits, 0, 1), values.T, dropped.sum()
 
         logits, values, dropped = decode(params, tokens)
-        full_logits, full_values, _, counters = jax.jit(lambda p, t: qwen3_next.forward(p, spec, t))(params, tokens)
+        full_logits, full_values, _, counters = jax.jit(lambda p, t: trunk.forward(p, spec, t))(params, tokens)
     spread = float(jnp.std(full_logits))
     gaps = {"logits": float(jnp.max(jnp.abs(logits - full_logits))) / spread,
             "values": float(jnp.max(jnp.abs(values - full_values))) / max(float(jnp.std(full_values)), 1e-30)}
@@ -620,7 +655,7 @@ def qwen3_next_phase(
     _check(float(dropped) == 0 and float(counters["pairs_dropped"]) == 0, "a pair on a held expert was dropped")
     del params
 
-    run_dir = os.path.join(out_dir, "qwen3_next")
+    run_dir = os.path.join(out_dir, name)
     t0 = time.perf_counter()
     run(list(overrides) + [f"hydra.run.dir={run_dir}", "metric.telemetry.enabled=true", "metric.telemetry.every=1"])
     wall = time.perf_counter() - t0
@@ -641,8 +676,34 @@ def qwen3_next_phase(
         "compile": summary["compile"],
         "wall_seconds": round(wall, 1),
     }
-    print(f"[chip-smoke] qwen3_next: {json.dumps(result)}", flush=True)
+    print(f"[chip-smoke] {name}: {json.dumps(result)}", flush=True)
     return result
+
+
+def qwen3_next_phase(
+    overrides: Sequence[str], *, platform: str, out_dir: str, widths: Dict[str, Any] = Q3N_PUBLISHED,
+    batch: int = 4, steps: int = 96
+) -> Dict[str, Any]:
+    """The `qwen3_next` trunk (`_trunk_phase`): decoding through its three kinds of state (KV
+    cache, convolution columns, matrix state) against the chunked whole-sequence forward."""
+    from sheeprl_tpu.models import qwen3_next
+
+    spec = qwen3_next.Qwen3NextSpec(**widths, max_seq_len=steps)
+    return _trunk_phase("qwen3_next", qwen3_next, spec, overrides, platform=platform, out_dir=out_dir, batch=batch)
+
+
+def deepseek_v3_phase(
+    overrides: Sequence[str], *, platform: str, out_dir: str, widths: Dict[str, Any] = DSV3_PUBLISHED,
+    batch: int = 4, steps: int = 96
+) -> Dict[str, Any]:
+    """The `deepseek_v3` trunk (`_trunk_phase`): decoding in the absorbed form through the latent
+    caches (``spec.cache_bytes_per_sequence`` of state a sequence) against the expanded
+    whole-sequence forward; the run's grouped products are at the published width 1408."""
+    from sheeprl_tpu.models import deepseek_v3
+
+    spec = deepseek_v3.DeepseekV3Spec(**widths, max_seq_len=steps)
+    result = _trunk_phase("deepseek_v3", deepseek_v3, spec, overrides, platform=platform, out_dir=out_dir, batch=batch)
+    return {**result, "latent_cache_bytes_per_sequence": spec.cache_bytes_per_sequence}
 
 
 def main() -> int:
@@ -661,6 +722,7 @@ def main() -> int:
     )
     experts = experts_phase(LM_OVERRIDES, platform="tpu", out_dir=WORK_DIR)
     trunk = qwen3_next_phase(Q3N_OVERRIDES, platform="tpu", out_dir=WORK_DIR)
+    latent = deepseek_v3_phase(DSV3_OVERRIDES, platform="tpu", out_dir=WORK_DIR)
     verdict = {"ok": True, "device": {k: device[k] for k in ("platform", "kind", "count")}}
     result = {
         **verdict,
@@ -671,9 +733,10 @@ def main() -> int:
         "serve": served,
         "experts": experts,
         "qwen3_next": trunk,
+        "deepseek_v3": latent,
         "claim": None,
     }
-    streams = (("train", train), ("serve", served), ("experts", experts), ("qwen3_next", trunk))
+    streams = (("train", train), ("serve", served), ("experts", experts), ("qwen3_next", trunk), ("deepseek_v3", latent))
     for name, stream in ((name, phase["telemetry"]) for name, phase in streams):
         shutil.copy(stream, os.path.join(REPORT_DIR, f"{name}.telemetry.jsonl"))
     with open(os.path.join(REPORT_DIR, "result.json"), "w") as fh:

@@ -1,16 +1,16 @@
-"""What the sequence-model trunks (``models/lfm2.py``, ``models/qwen3_next.py``) share: the
-RMS norm's core, RoPE, SwiGLU, the heads, and the sparse expert layer that is TOLD which
+"""What the sequence-model trunks (``models/lfm2.py``, ``models/qwen3_next.py``, ``models/deepseek_v3.py``)
+share: the RMS norm's core, RoPE, SwiGLU, the heads, and the sparse expert layer that is TOLD which
 experts it holds, with its dense and grouped paths and its counters.
 
 The expert layer reads its properties from the trunk's spec: ``num_experts``,
 ``num_experts_per_tok``, ``experts_held = (e0, n)``, ``router_scoring`` (``sigmoid_bias``:
 sigmoid scores, the top-k of ``s + b``; ``softmax``: a float32 softmax over all experts),
-``shared_expert`` (a SwiGLU every token takes, behind a sigmoid gate, which every chip of
-the deployment computes alike). It
+``routed_scaling_factor`` (times the normalised weights; 1 where a spec states none),
+``shared_expert`` (a SwiGLU every token takes, as wide as its weights, behind a sigmoid gate
+unless the spec says ``shared_expert_gate`` False; every chip of the deployment computes it alike). It
 routes over all ``num_experts``, normalises the k chosen weights over all k, and computes
-the part of the result its own experts give. What absent experts would add is left out
-(another chip's part; on one chip the layer runs without its exchange). No token is
-dropped and there is no capacity.
+the part of the result its own experts give. What absent experts would add is left out (another chip's
+part; on one chip the layer runs without its exchange). No token is dropped and there is no capacity.
 
 Two paths, one result. A decode step's few tokens go through every held expert
 (`DENSE_TOKENS`). The update sorts its (token, expert) pairs by held expert and runs
@@ -90,18 +90,18 @@ def heads(params, x):
 # the expert layer
 # ---------------------------------------------------------------------------------
 def route(p, u, spec: Any):
-    """``u`` ``[N, H]`` -> the chosen experts ``[N, k]`` and their weights over the sum of
-    all k chosen. ``sigmoid_bias``: the top-k of ``s + b``, weights ``s`` without ``b``
-    (LFM2's ``use_expert_bias`` and ``norm_topk_prob``, ``routed_scaling_factor`` 1);
-    ``softmax``: a float32 softmax over all experts, its k largest (``norm_topk_prob``)."""
+    """``u`` ``[N, H]`` -> the chosen experts ``[N, k]`` and their weights over the sum of all k chosen (``norm_topk_prob``),
+    times ``routed_scaling_factor`` where it is not 1. ``sigmoid_bias``: the top-k of ``s + b``, weights ``s`` without ``b``
+    (LFM2's ``use_expert_bias``; the DeepSeek-V3 block's ``noaux_tc`` with one group); ``softmax``: float32, over all experts."""
     if spec.router_scoring == "softmax":
         s = jax.nn.softmax((u @ p["router"]).astype(jnp.float32), axis=-1)
         ids = jax.lax.top_k(s, spec.num_experts_per_tok)[1]
     else:
         s = jax.nn.sigmoid(u @ p["router"])
         ids = jax.lax.top_k(s + jax.lax.stop_gradient(p["bias"]), spec.num_experts_per_tok)[1]
-    w = jnp.take_along_axis(s, ids, axis=-1)
-    return ids, w / (w.sum(axis=-1, keepdims=True) + WEIGHT_SUM_EPS)
+    w, scale = jnp.take_along_axis(s, ids, axis=-1), getattr(spec, "routed_scaling_factor", 1.0)
+    w = w / (w.sum(axis=-1, keepdims=True) + WEIGHT_SUM_EPS)
+    return ids, w if scale == 1.0 else w * scale
 
 
 @jax.custom_vjp
@@ -423,7 +423,10 @@ def expert_layer(p, u, spec: Any, route=route):
         y, group_sizes, computed, of_the_form = _experts_bounded(p, u, ids, w, spec, dispatch_rows(spec, u.shape[0]))
     if spec.shared_expert:
         with jax.named_scope("shared_expert"):
-            y = y + jax.nn.sigmoid(u @ p["shared_gate"]) * swiglu(p["shared"], u)
+            if getattr(spec, "shared_expert_gate", True):
+                y = y + jax.nn.sigmoid(u @ p["shared_gate"]) * swiglu(p["shared"], u)
+            else:
+                y = y + swiglu(p["shared"], u)
     landed = jnp.sum((ids >= e0) & (ids < e0 + held))
     counters = {
         "pairs_held": landed.astype(jnp.float32),

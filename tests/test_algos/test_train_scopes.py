@@ -165,10 +165,16 @@ def _lowered_expert_layer(counting: bool, trunk: str = "lfm2") -> str:
     ``tokens x k`` rows, or the `qwen3_next` trunk's bounded dispatch (rounds and all)."""
     import jax.numpy as jnp
 
-    from sheeprl_tpu.models import lfm2, qwen3_next
+    from sheeprl_tpu.models import deepseek_v3, lfm2, qwen3_next
     from sheeprl_tpu.utils.timer import timer
 
-    if trunk == "lfm2":
+    if trunk == "deepseek_v3":  # a sigmoid router with a scale, an ungated shared expert, the bounded dispatch
+        model = deepseek_v3
+        spec = deepseek_v3.DeepseekV3Spec(
+            vocab_size=32, hidden_size=128, intermediate_size=128, moe_intermediate_size=128, num_attention_heads=2,
+            qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, kv_lora_rank=32, num_hidden_layers=1, first_k_dense_replace=0,
+            num_experts=16, num_experts_per_tok=2, experts_held=(0, 4), n_shared_experts=2, routed_scaling_factor=2.446, max_seq_len=8)
+    elif trunk == "lfm2":
         model = lfm2
         spec = lfm2.LFM2Spec(
             vocab_size=32, hidden_size=128, intermediate_size=128, moe_intermediate_size=128, num_attention_heads=2,
@@ -198,7 +204,8 @@ def _lowered_expert_layer(counting: bool, trunk: str = "lfm2") -> str:
 
 @pytest.mark.parametrize("exp, telemetry", [("dreamer_v3", "true"), ("dreamer_v3", "false"),
                                             ("ppo_anakin_lfm2", "true"), ("ppo_anakin_lfm2", "false"),
-                                            ("ppo_anakin_qwen3_next", "true"), ("ppo_anakin_qwen3_next", "false")])
+                                            ("ppo_anakin_qwen3_next", "true"), ("ppo_anakin_qwen3_next", "false"),
+                                            ("ppo_anakin_deepseek_v3", "true"), ("ppo_anakin_deepseek_v3", "false")])
 def test_composing_with_telemetry_leaves_the_cache_key_alone(exp, telemetry, monkeypatch):
     from sheeprl_tpu import cli
     from sheeprl_tpu.config import compose
@@ -225,6 +232,11 @@ def test_composing_with_telemetry_leaves_the_cache_key_alone(exp, telemetry, mon
             text = _lowered_expert_layer(telemetry == "true", "qwen3_next")
             assert "tpu_custom_call" in text and "while" in text  # the kernels, inside the rounds' loops
             assert text == _lowered_expert_layer(telemetry != "true", "qwen3_next")
+        if exp == "ppo_anakin_deepseek_v3":  # the third trunk's layer: the same counters, returned either way
+            monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+            text = _lowered_expert_layer(telemetry == "true", "deepseek_v3")
+            assert "tpu_custom_call" in text and "while" in text
+            assert text == _lowered_expert_layer(telemetry != "true", "deepseek_v3")
     finally:
         for knob, value in before.items():
             jax.config.update(knob, value)

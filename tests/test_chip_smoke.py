@@ -70,6 +70,22 @@ def test_the_qwen3_next_phase_runs_on_cpu_at_small_widths(tmp_path):
     assert 0 < counters["moe/update_dispatch_fill"] <= 1
 
 
+def test_the_deepseek_v3_phase_runs_on_cpu_at_small_widths(tmp_path):
+    """Decoding in the absorbed form through the latent caches against the expanded forward, then
+    the sequence-policy loop on the `deepseek_v3` trunk: the update's bounded dispatch drops nothing."""
+    small = [o for o in chip_smoke.DSV3_OVERRIDES if not o.startswith(("fabric.accelerator", "algo.lm.", "env.num_envs", "algo.total_steps"))]
+    small += ["fabric.accelerator=cpu", "env.num_envs=4", "algo.total_steps=1536", "algo.per_rank_batch_size=2"]
+    widths = dict(chip_smoke.DSV3_PUBLISHED, vocab_size=64, hidden_size=32, intermediate_size=48, moe_intermediate_size=16,
+                  num_attention_heads=4, qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8, kv_lora_rank=16,
+                  num_experts=32, num_experts_per_tok=4, experts_held=(0, 8))
+    trunk = chip_smoke.deepseek_v3_phase(small, platform="cpu", out_dir=str(tmp_path), widths=widths, batch=2, steps=70)
+    assert max(trunk["decode_gaps_to_the_full_forward"].values()) < chip_smoke.DECODE_GAP_BOUND
+    assert trunk["latent_cache_bytes_per_sequence"] == 3 * 70 * (16 + 4) * 4
+    counters = trunk["counters"]
+    assert counters["moe/update_pairs_dropped"] == 0 and counters["moe/rollout_pairs_dropped"] == 0
+    assert 0 < counters["moe/update_dispatch_fill"] <= 1
+
+
 def test_script_refuses_to_pass_without_the_chip(tmp_path, monkeypatch, capsys):
     # `python chip_smoke.py` is sys.exit(main()): what main() raises is a non-zero exit
     monkeypatch.setattr(chip_smoke, "WORK_DIR", str(tmp_path / "work"))
@@ -88,7 +104,7 @@ def test_last_line_is_the_verdict_and_nothing_else(tmp_path, monkeypatch, capsys
     monkeypatch.setattr(chip_smoke, "WORK_DIR", str(tmp_path / "work"))
     monkeypatch.setattr(chip_smoke, "REPORT_DIR", str(tmp_path / "report"))
     monkeypatch.setattr(chip_smoke, "device_report", lambda platform: device)
-    for name in ("kernel_phase", "train_phase", "serve_phase", "experts_phase", "qwen3_next_phase"):
+    for name in ("kernel_phase", "train_phase", "serve_phase", "experts_phase", "qwen3_next_phase", "deepseek_v3_phase"):
         monkeypatch.setattr(chip_smoke, name, lambda *a, **k: phase)
     assert chip_smoke.main() == 0
     *_, full, last = capsys.readouterr().out.splitlines()

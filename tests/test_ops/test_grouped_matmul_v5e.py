@@ -1,5 +1,5 @@
 """The grouped matmul kernels compiled for a v5e that is described and not attached, at the
-LFM2 cell's shapes and the tilings `ops/grouped_matmul.py` picks for them: what Pallas'
+LFM2 and Moonlight cells' shapes and the tilings `ops/grouped_matmul.py` picks for them: what Pallas'
 interpreter cannot show (a tile Mosaic refuses, more VMEM than the kernels are given).
 The topology is described inside a fixture, so every worker collects the same tests and
 only the one that is given this file loads the TPU's library."""
@@ -12,6 +12,8 @@ from jax.sharding import SingleDeviceSharding
 from sheeprl_tpu.ops import grouped_matmul as gm
 
 M, GROUPS = 32768, 8
+# [rows of the update's buffers, hidden, expert width] of the three trunks' cells (`lm_layers.dispatch_rows`)
+CELLS = {"lfm2": (16384, 2048, 1792), "qwen3_next": (10240, 2048, 512), "moonlight": (12288, 2048, 1408)}
 
 
 @pytest.fixture(scope="module")
@@ -32,20 +34,54 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-@pytest.mark.parametrize("k, n", [(2048, 1792), (1792, 2048)], ids=["w1_w3", "w2"])
-@pytest.mark.parametrize("product", ["forward", "input_gradient", "weight_gradient"])
-def test_the_cells_products_compile_for_the_chip_at_their_tilings(product, k, n, one_chip):
+def _compiles(product, m, k, n, one_chip):
     def shape(*dims, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     sizes = shape(GROUPS, dtype=jnp.int32)
     if product == "forward":
-        fn, args = (lambda l, r, s: gm.gmm(l, r, s, gm.gmm_tiling(M, k, n), 3)), (shape(M, k), shape(GROUPS, k, n), sizes)
+        fn, args = (lambda l, r, s: gm.gmm(l, r, s, gm.gmm_tiling(m, k, n), 3)), (shape(m, k), shape(GROUPS, k, n), sizes)
     elif product == "input_gradient":
-        fn = lambda g, r, s: gm.gmm(g, r, s, gm.gmm_tiling(M, n, k), 3, transpose_rhs=True)  # noqa: E731
-        args = (shape(M, n), shape(GROUPS, k, n), sizes)
+        fn = lambda g, r, s: gm.gmm(g, r, s, gm.gmm_tiling(m, n, k), 3, transpose_rhs=True)  # noqa: E731
+        args = (shape(m, n), shape(GROUPS, k, n), sizes)
     else:
-        fn, args = (lambda l, g, s: gm.tgmm(l, g, s, gm.tgmm_tiling(M, k, n), 3)), (shape(M, k), shape(M, n), sizes)
+        fn, args = (lambda l, g, s: gm.tgmm(l, g, s, gm.tgmm_tiling(m, k, n), 3)), (shape(m, k), shape(m, n), sizes)
     with jax.default_matmul_precision("high"):  # what a run's program is traced under
         compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("k, n", [(2048, 1792), (1792, 2048)], ids=["w1_w3", "w2"])
+@pytest.mark.parametrize("product", ["forward", "input_gradient", "weight_gradient"])
+def test_the_cells_products_compile_for_the_chip_at_their_tilings(product, k, n, one_chip):
+    _compiles(product, M, k, n, one_chip)
+
+
+@pytest.mark.parametrize("k, n", [(2048, 1408), (1408, 2048)], ids=["w1_w3", "w2"])
+@pytest.mark.parametrize("product", ["forward", "input_gradient", "weight_gradient"])
+def test_the_moonlight_cells_products_compile_for_the_chip_at_the_whole_width(product, k, n, one_chip):
+    """Width 1408 = 11 x 128: an output tile of the whole width, 54.7 MB of the kernels' VMEM."""
+    _compiles(product, CELLS["moonlight"][0], k, n, one_chip)
+
+
+# (gmm forward, gmm input gradient, tgmm) of the `w1`/`w3` products, then of `w2`'s: LFM2's and Qwen3-Next's as
+# on the commit before the third trunk (841ea4e), Moonlight's as measured on the chip (PERF.md, section 5)
+TILINGS = {
+    "lfm2": (((128, 2048, 896), (128, 1792, 1024), (128, 1024, 1792)), ((128, 1792, 1024), (128, 2048, 896), (128, 896, 2048))),
+    "qwen3_next": (((128, 2048, 512), (128, 512, 1024), (128, 2048, 512)), ((128, 512, 1024), (128, 2048, 512), (128, 512, 2048))),
+    "moonlight": (((128, 2048, 1408), (128, 1408, 1024), (128, 2048, 1408)), ((128, 1408, 1024), (128, 2048, 1408), (128, 1408, 2048))),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+@pytest.mark.parametrize("product", ["w1_w3", "w2"])
+def test_the_tilings_of_the_three_trunks_grouped_shapes(cell, product):
+    m, hidden, width = CELLS[cell]
+    k, n = (hidden, width) if product == "w1_w3" else (width, hidden)
+    tilings = gm.gmm_tiling(m, k, n), gm.gmm_tiling(m, n, k), gm.tgmm_tiling(m, k, n)
+    assert tilings == TILINGS[cell][product == "w2"]
+    assert all(gm.gmm_vmem_bytes(t) <= gm.VMEM_BUDGET_BYTES for t in tilings[:2]) and gm.tgmm_vmem_bytes(tilings[2]) <= gm.VMEM_BUDGET_BYTES
+    if cell == "moonlight":  # the whole width: a row tile is read once, and a step sits over the ridge
+        whole = gm.gmm_tiling(m, hidden, width)
+        assert whole[2] == width and gm.gmm_vmem_bytes(whole) == 54_657_024
+        assert gm.gmm_flops_per_byte(whole, 3, True) > gm.RIDGE_FLOPS_PER_BYTE > gm.gmm_flops_per_byte((128, 2048, 128), 3, True)
