@@ -33,10 +33,13 @@ One process, six phases, through the entry points a user calls:
 6. ``deepseek_v3``: the third trunk (``models/deepseek_v3.py``) at
    Moonlight-16B-A3B's published widths, the dense layer and two expert layers:
    tokens decoded one a step in the absorbed form through the latent caches
-   against the expanded whole-sequence forward; then ``sheeprl_tpu.cli.run`` on
+   (the latent-cache kernel, ``ops/latent_decode.py``) against the expanded
+   whole-sequence forward; then ``sheeprl_tpu.cli.run`` on
    ``exp=ppo_anakin_deepseek_v3`` with experts of the published width 1408, whose
    grouped products must take the kernels at three passes (and not
-   ``lax.ragged_dot``) and drop no pair.
+   ``lax.ragged_dot``) and drop no pair, whose every decode step must take the
+   latent-cache kernel, and whose compiled program must write or copy no whole
+   latent cache.
 
 Every check reads what the run itself recorded (its telemetry stream, its
 checkpoint) or what JAX reports; a failed check raises, so any failed phase is a
@@ -57,10 +60,11 @@ import glob
 import importlib.metadata
 import json
 import os
+import re
 import shutil
 import sys
 import time
-from typing import Any, Dict, Sequence
+from typing import Any, Dict, List, Sequence
 
 REPO = os.path.dirname(os.path.abspath(__file__))
 # run directories, the checkpoint and the lowered programs: large, stay on the machine
@@ -508,6 +512,59 @@ def _sequence_run_counters(run_dir: str, platform: str):
     return stream, summary, mean
 
 
+def _run_seeing_program(overrides: Sequence[str], run_dir: str, see: bool):
+    """``cli.run`` of the sequence-policy loop with telemetry on -> (wall seconds, the compiled
+    text of the loop's own fused program where ``see``, else None): the program is compiled
+    once more to read it."""
+    import sheeprl_tpu.algos.ppo.anakin as anakin
+    from sheeprl_tpu.cli import run
+
+    programs = []
+
+    class SeenProgram:
+        def __init__(self, fused):
+            self.fused, self.hlo = fused, None
+
+        def __call__(self, *args):
+            if self.hlo is None and see:
+                self.hlo = self.fused.lower(*args).compile().as_text()
+            return self.fused(*args)
+
+        def __getattr__(self, name):  # `lower`, for the telemetry's program analysis
+            return getattr(self.fused, name)
+
+    def seen_program(*args, **kwargs):
+        fused, *rest = original(*args, **kwargs)
+        programs.append(SeenProgram(fused))
+        return (programs[-1], *rest)
+
+    t0 = time.perf_counter()
+    original, anakin.make_anakin_program = anakin.make_anakin_program, seen_program
+    try:
+        run(list(overrides) + [f"hydra.run.dir={run_dir}", "metric.telemetry.enabled=true", "metric.telemetry.every=1"])
+    finally:
+        anakin.make_anakin_program = original
+    (program,) = programs
+    return time.perf_counter() - t0, program.hlo
+
+
+def whole_buffer_writes(hlo: str, shape: str) -> List[str]:
+    """The instructions of a compiled program, outside fusions' bodies, whose result is a whole
+    buffer of ``shape`` (``f32[64,512,576]``) and that write or copy it: a dynamic-update-slice,
+    a copy, or a fusion named for either. A kernel's aliased output is none of these."""
+    found = []
+    for block in hlo.split("\n\n"):
+        if block.lstrip().startswith(("%fused_", "%wrapped_")):
+            continue
+        for line in block.splitlines():
+            op = re.match(r"\s*(?:ROOT )?%(\S+) = (\S+) ([\w-]+)\(", line)
+            if op and op.group(2).startswith(shape + "{") and (
+                    op.group(3) in ("dynamic-update-slice", "copy", "copy-start")
+                    or (op.group(3) == "fusion" and ("dynamic-update-slice" in op.group(1) or "copy" in op.group(1)))):
+                found.append(line.strip()[:200])
+    return found
+
+
 def experts_phase(
     overrides: Sequence[str], *, platform: str, out_dir: str, product: Sequence[int] = CELL_PRODUCT
 ) -> Dict[str, Any]:
@@ -520,8 +577,6 @@ def experts_phase(
     import jax
     import jax.numpy as jnp
 
-    import sheeprl_tpu.algos.ppo.anakin as anakin
-    from sheeprl_tpu.cli import run
     from sheeprl_tpu.models import lfm2
 
     m, k, n, groups = product
@@ -555,37 +610,8 @@ def experts_phase(
     )
     _check(not bool(jnp.any(kernels[2][-1])), "an expert that no pair landed on has a weight gradient")
 
-    programs = []
-
-    class SeenProgram:  # the loop's own fused program, compiled once more to read its op names
-        def __init__(self, fused):
-            self.fused, self.hlo = fused, None
-
-        def __call__(self, *args):
-            if self.hlo is None and platform == "tpu":
-                self.hlo = self.fused.lower(*args).compile().as_text()
-            return self.fused(*args)
-
-        def __getattr__(self, name):  # `lower`, for the telemetry's program analysis
-            return getattr(self.fused, name)
-
-    def seen_program(*args, **kwargs):
-        fused, *rest = original(*args, **kwargs)
-        programs.append(SeenProgram(fused))
-        return (programs[-1], *rest)
-
     run_dir = os.path.join(out_dir, "experts")
-    t0 = time.perf_counter()
-    original, anakin.make_anakin_program = anakin.make_anakin_program, seen_program
-    try:
-        run(
-            list(overrides)
-            + [f"hydra.run.dir={run_dir}", "metric.telemetry.enabled=true", "metric.telemetry.every=1"]
-        )
-    finally:
-        anakin.make_anakin_program = original
-    wall = time.perf_counter() - t0
-
+    wall, hlo = _run_seeing_program(overrides, run_dir, see=platform == "tpu")
     stream, summary, mean = _sequence_run_counters(run_dir, platform)
     _check(
         0 < mean.get("moe/update_tile_fill", 0) <= 1,
@@ -598,8 +624,7 @@ def experts_phase(
             mean.get("moe/update_grouped_product_passes") == 3,
             f"`moe/update_grouped_product_passes` reads {mean.get('moe/update_grouped_product_passes')} under `high`, not 3",
         )
-        (program,) = programs
-        calls = [line for line in (program.hlo or "").splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+        calls = [line for line in hlo.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
         scoped = [line for line in calls if "/update/" in line and "experts" in line and "grouped_matmul" in line]
         _check(
             len(scoped) > 0 and len(scoped) == len(calls),
@@ -618,17 +643,18 @@ def experts_phase(
     return result
 
 
-def _trunk_phase(name: str, trunk, spec, overrides: Sequence[str], *, platform: str, out_dir: str, batch: int) -> Dict[str, Any]:
+def _trunk_phase(name: str, trunk, spec, overrides: Sequence[str], *, platform: str, out_dir: str, batch: int,
+                 whole_cache: str = "") -> Dict[str, Any]:
     """A trunk of the sequence policy on this platform. Alone, at ``spec``'s widths:
     ``spec.max_seq_len`` tokens decoded one a step through the trunk's state against its
     whole-sequence forward over the same tokens, logits and values. Inside: ``cli.run`` of
     the sequence-policy PPO loop with telemetry on, then the run's own counters: no pair
     dropped, the bounded dispatch's fill in (0, 1] and, on a TPU, three bf16 passes in the
-    grouped kernels under `high` (0 would be `lax.ragged_dot`)."""
+    grouped kernels under `high` (0 would be `lax.ragged_dot`) and, where ``whole_cache``
+    names a cache's shape, no instruction of the compiled program that writes or copies
+    such a whole buffer (`whole_buffer_writes`)."""
     import jax
     import jax.numpy as jnp
-
-    from sheeprl_tpu.cli import run
 
     with jax.default_matmul_precision("high"):  # what the CLI sets for a run
         params = jax.jit(lambda key: trunk.init_params(spec, key))(jax.random.PRNGKey(0))
@@ -656,9 +682,7 @@ def _trunk_phase(name: str, trunk, spec, overrides: Sequence[str], *, platform: 
     del params
 
     run_dir = os.path.join(out_dir, name)
-    t0 = time.perf_counter()
-    run(list(overrides) + [f"hydra.run.dir={run_dir}", "metric.telemetry.enabled=true", "metric.telemetry.every=1"])
-    wall = time.perf_counter() - t0
+    wall, hlo = _run_seeing_program(overrides, run_dir, see=platform == "tpu" and bool(whole_cache))
     stream, summary, mean = _sequence_run_counters(run_dir, platform)
     _check(
         0 < mean.get("moe/update_dispatch_fill", 0) <= 1,
@@ -669,10 +693,13 @@ def _trunk_phase(name: str, trunk, spec, overrides: Sequence[str], *, platform: 
             mean.get("moe/update_grouped_product_passes") == 3,
             f"`moe/update_grouped_product_passes` reads {mean.get('moe/update_grouped_product_passes')} under `high`, not 3",
         )
+        if whole_cache:
+            writes = whole_buffer_writes(hlo, whole_cache)
+            _check(not writes, f"the compiled program writes or copies a whole {whole_cache} cache: {writes}")
     result = {
         "telemetry": stream,
         "decode_gaps_to_the_full_forward": gaps,
-        "counters": {name: mean[name] for name in sorted(mean) if name.startswith("moe/")},
+        "counters": {name: mean[name] for name in sorted(mean) if name.startswith(("moe/", "mla/"))},
         "compile": summary["compile"],
         "wall_seconds": round(wall, 1),
     }
@@ -694,15 +721,26 @@ def qwen3_next_phase(
 
 def deepseek_v3_phase(
     overrides: Sequence[str], *, platform: str, out_dir: str, widths: Dict[str, Any] = DSV3_PUBLISHED,
-    batch: int = 4, steps: int = 96
+    batch: int = 4, steps: int = 128
 ) -> Dict[str, Any]:
     """The `deepseek_v3` trunk (`_trunk_phase`): decoding in the absorbed form through the latent
-    caches (``spec.cache_bytes_per_sequence`` of state a sequence) against the expanded
-    whole-sequence forward; the run's grouped products are at the published width 1408."""
+    caches (``spec.cache_bytes_per_sequence`` of state a sequence; on a TPU the latent-cache
+    kernel at ``steps`` a multiple of its chunk) against the expanded whole-sequence forward;
+    the run's grouped products are at the published width 1408. In the run every layer's
+    decode step takes the kernel on a TPU and none elsewhere (`mla/rollout_decode_kernel_share`
+    1 or 0), and on a TPU no instruction of the program writes or copies a whole latent cache
+    (the kernel writes its row in place)."""
     from sheeprl_tpu.models import deepseek_v3
 
     spec = deepseek_v3.DeepseekV3Spec(**widths, max_seq_len=steps)
-    result = _trunk_phase("deepseek_v3", deepseek_v3, spec, overrides, platform=platform, out_dir=out_dir, batch=batch)
+    given = dict(o.split("=", 1) for o in overrides if "=" in o)
+    width = int(given.get("algo.lm.kv_lora_rank", 0)) + int(given.get("algo.lm.qk_rope_head_dim", 0))
+    whole_cache = f"f32[{given.get('env.num_envs')},{given.get('algo.rollout_steps')},{width}]"
+    result = _trunk_phase("deepseek_v3", deepseek_v3, spec, overrides, platform=platform, out_dir=out_dir, batch=batch,
+                          whole_cache=whole_cache)
+    share = result["counters"].get("mla/rollout_decode_kernel_share")
+    _check(share == (1.0 if platform == "tpu" else 0.0),
+           f"`mla/rollout_decode_kernel_share` reads {share} on {platform}: the latent-cache kernel {'not ' if platform == 'tpu' else ''}taken")
     return {**result, "latent_cache_bytes_per_sequence": spec.cache_bytes_per_sequence}
 
 

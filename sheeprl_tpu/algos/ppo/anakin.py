@@ -663,6 +663,15 @@ def _build_optimizer(cfg, total_iters: int, updates_per_iter: int):
     return tx
 
 
+def _counter_name(name: str) -> str:
+    """A counter of the sequence flavour, ``<phase>_<counter>`` as the program returns it, by
+    the name the telemetry keeps: ``moe/<phase>_<counter>`` (the expert layers'), or
+    ``<space>/<phase>_<counter>`` where the trunk named its space (``mla/decode_kernel_share``)."""
+    phase, _, counter = name.partition("_")
+    space, _, counter = counter.rpartition("/")
+    return f"{space or 'moe'}/{phase}_{counter}"
+
+
 def _measure_rollout_seconds(rollout_only, args, reps: int = 2):
     """One-shot wall-time measurement of the rollout-only half of the fused
     program: compiles and runs the acting sub-program ``reps`` times on the
@@ -844,7 +853,7 @@ def run_anakin(fabric, cfg: Dict[str, Any]):
         if extras and not timer.disabled:
             # the sequence flavour's counters (scalars, behind the wait above)
             for name, value in jax.device_get(extras[0]["counters"]).items():
-                timer.count(f"moe/{name}", float(value))
+                timer.count(_counter_name(name), float(value))
 
         # split the fused call's wall time between the rollout (fused env+act)
         # and train phases by the measured rollout-only time; compile-dominated

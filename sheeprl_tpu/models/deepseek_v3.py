@@ -42,6 +42,7 @@ import jax.numpy as jnp
 
 from sheeprl_tpu.models import lm_layers
 from sheeprl_tpu.models.lm_layers import INIT_STD, rms_core, rope, stack_routes, swiglu
+from sheeprl_tpu.ops import latent_decode as decode_kernel
 
 EXPERT_BIAS_STD = 0.05
 CACHE_BLOCK = 128  # rows of the latent cache a decode step reads at a time (`cache_block`)
@@ -208,34 +209,58 @@ def cache_block(positions: int) -> int:
     return next(b for b in range(min(CACHE_BLOCK, positions), 0, -1) if positions % b == 0)
 
 
+def decode_kernel_passes(cache_shape) -> int:
+    """The bf16 passes the latent-cache kernel (`ops/latent_decode.py`) takes for a decode step
+    over a cache of ``cache_shape`` in the program being traced (`lm_layers.matmul_passes`),
+    or 0 where the step takes the XLA form: off the TPU, or a cache the kernel cannot chunk."""
+    if jax.default_backend() != "tpu" or not decode_kernel.supports(cache_shape):
+        return 0
+    return lm_layers.matmul_passes()
+
+
+def _attend_written(cache, t, row, query):
+    """The XLA form of a step's attention over the latent cache: write ``row`` ``[B, W]`` at
+    ``t``, then read the rows WRITTEN SO FAR a block at a time (as many blocks as ``t`` asks
+    for: a loop whose trip count is the position's), keeping the running maximum, sum and
+    weighted latents of a softmax of ``query`` ``[B, heads, W]`` over them. Rows past ``t``
+    are never read as what they hold: the last block's count as 0."""
+    cache = jax.lax.dynamic_update_slice_in_dim(cache, row[:, None], t, axis=1)
+    block = cache_block(cache.shape[1])
+
+    def one_block(i, so_far):
+        most, total, weighed = so_far  # [B, heads], [B, heads], [B, heads, W]
+        written = i * block + jnp.arange(block) <= t
+        rows = jnp.where(written[None, :, None], jax.lax.dynamic_slice_in_dim(cache, i * block, block, axis=1), 0.0)
+        scores = jnp.where(written[None, None], jnp.einsum("bhc,bsc->bhs", query, rows), -jnp.inf)
+        new_most = jnp.maximum(most, scores.max(axis=-1))
+        kept, weights = jnp.exp(most - new_most), jnp.exp(scores - new_most[..., None])
+        return (new_most, total * kept + weights.sum(axis=-1),
+                weighed * kept[..., None] + jnp.einsum("bhs,bsc->bhc", weights, rows))
+
+    start = (jnp.full(query.shape[:2], -jnp.inf), jnp.zeros(query.shape[:2]), jnp.zeros(query.shape))
+    _, total, weighed = jax.lax.fori_loop(0, t // block + 1, one_block, start)
+    return weighed, total, cache
+
+
 def mla_step(p, cache, u, t, spec: DeepseekV3Spec):
-    """One step ``[B, H]`` at position ``t``, the absorbed form: write this token's row
-    ``[c, k_pe]`` into the latent cache ``[B, S, rank + rope]`` (one row, in place), carry the
-    query into the latent's space, then read the rows WRITTEN SO FAR a block at a time (as many
-    blocks as ``t`` asks for: a loop whose trip count is the position's), keeping the running
-    maximum, sum and weighted latents of a softmax over them, and only then apply a head's
-    value map. Rows past ``t`` are never read as what they hold: the last block's count as 0."""
+    """One step ``[B, H]`` at position ``t``, the absorbed form: carry the query into the
+    latent's space, write this token's row ``[c, k_pe]`` into the latent cache
+    ``[B, S, rank + rope]`` and attend over the rows written so far (on the TPU one kernel,
+    `ops/latent_decode.py`, that writes the row in place and reads those rows once; else
+    `_attend_written`), and only then apply a head's value map."""
     nh, dn, r = spec.num_attention_heads, spec.qk_nope_head_dim, spec.kv_lora_rank
     q_nope, q_pe, c, k_pe = _latent_inputs(p, u[:, None], t[None], spec)
     w_kvb = p["w_kvb"].reshape(r, nh, dn + spec.v_head_dim)
     with jax.named_scope("mla_attend"):
-        cache = jax.lax.dynamic_update_slice_in_dim(cache, jnp.concatenate([c, k_pe], axis=-1), t, axis=1)
+        row = jnp.concatenate([c, k_pe], axis=-1)[:, 0]
         q_latent = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], w_kvb[..., :dn])
         query = jnp.concatenate([q_latent, q_pe[:, 0]], axis=-1) * _score_scale(spec)
-        block = cache_block(cache.shape[1])
-
-        def one_block(i, so_far):
-            most, total, weighed = so_far  # [B, heads], [B, heads], [B, heads, rank + rope]
-            written = i * block + jnp.arange(block) <= t
-            rows = jnp.where(written[None, :, None], jax.lax.dynamic_slice_in_dim(cache, i * block, block, axis=1), 0.0)
-            scores = jnp.where(written[None, None], jnp.einsum("bhc,bsc->bhs", query, rows), -jnp.inf)
-            new_most = jnp.maximum(most, scores.max(axis=-1))
-            kept, weights = jnp.exp(most - new_most), jnp.exp(scores - new_most[..., None])
-            return (new_most, total * kept + weights.sum(axis=-1),
-                    weighed * kept[..., None] + jnp.einsum("bhs,bsc->bhc", weights, rows))
-
-        start = (jnp.full(query.shape[:2], -jnp.inf), jnp.zeros(query.shape[:2]), jnp.zeros(query.shape))
-        _, total, weighed = jax.lax.fori_loop(0, t // block + 1, one_block, start)
+        passes = decode_kernel_passes(cache.shape)
+        if passes:
+            weighed, total, cache = decode_kernel.latent_decode(
+                cache, t, row, query, passes, interpret=jax.default_backend() != "tpu")
+        else:
+            weighed, total, cache = _attend_written(cache, t, row, query)
         out = jnp.einsum("bhr,rhd->bhd", weighed[..., :r] / total[..., None], w_kvb[..., dn:])
     return out.reshape(out.shape[0], -1) @ p["wo"], cache
 
@@ -297,7 +322,8 @@ def forward(params, spec: DeepseekV3Spec, tokens):
 def step(params, spec: DeepseekV3Spec, carry, tokens):
     """One token a sequence, ``tokens`` ``[B]``, through the latent caches -> logits
     ``[B, V]``, values ``[B]``, the new carry, the chosen experts ``[B, expert layers, k]``
-    and the layers' counters."""
+    and the layers' counters, with ``mla/decode_kernel_share``: the share of the layers
+    whose attention took the latent-cache kernel (`decode_kernel_passes`)."""
     t = carry["t"]
     new_carry: Dict[str, Any] = {"t": t + 1}
     with jax.named_scope("embed"):
@@ -314,6 +340,9 @@ def step(params, spec: DeepseekV3Spec, carry, tokens):
             routes.append((ids, counters))
     logits, value = heads(params, x, spec)
     ids, counters = stack_routes(routes)
+    if counters is not None:  # fixed when traced: the share of the layers whose step took the kernel
+        kernel = [bool(decode_kernel_passes(carry[f"layer_{i}"].shape)) for i in range(spec.num_hidden_layers)]
+        counters["mla/decode_kernel_share"] = jnp.float32(sum(kernel) / len(kernel))
     return logits, value, new_carry, ids, counters
 
 

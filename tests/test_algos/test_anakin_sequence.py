@@ -115,6 +115,14 @@ def test_the_fused_program_returns_its_record_and_counters_and_names_its_parts()
     assert moved["lm_head"] > 0 and moved["layer_1"]["ffn"]["w1"] > 0 and moved["layer_1"]["ffn"]["bias"] == 0.0
 
 
+def test_a_counter_is_kept_under_the_space_its_trunk_names_or_the_expert_layers():
+    from sheeprl_tpu.algos.ppo.anakin import _counter_name
+
+    assert _counter_name("rollout_pairs_held") == "moe/rollout_pairs_held"
+    assert _counter_name("update_grouped_product_passes") == "moe/update_grouped_product_passes"
+    assert _counter_name("rollout_mla/decode_kernel_share") == "mla/rollout_decode_kernel_share"
+
+
 def test_a_sequence_policy_needs_episodes_of_one_rollout():
     from types import SimpleNamespace
 

@@ -72,7 +72,8 @@ def test_the_qwen3_next_phase_runs_on_cpu_at_small_widths(tmp_path):
 
 def test_the_deepseek_v3_phase_runs_on_cpu_at_small_widths(tmp_path):
     """Decoding in the absorbed form through the latent caches against the expanded forward, then
-    the sequence-policy loop on the `deepseek_v3` trunk: the update's bounded dispatch drops nothing."""
+    the sequence-policy loop on the `deepseek_v3` trunk: the update's bounded dispatch drops nothing,
+    and no decode step takes the latent-cache kernel off the chip."""
     small = [o for o in chip_smoke.DSV3_OVERRIDES if not o.startswith(("fabric.accelerator", "algo.lm.", "env.num_envs", "algo.total_steps"))]
     small += ["fabric.accelerator=cpu", "env.num_envs=4", "algo.total_steps=1536", "algo.per_rank_batch_size=2"]
     widths = dict(chip_smoke.DSV3_PUBLISHED, vocab_size=64, hidden_size=32, intermediate_size=48, moe_intermediate_size=16,
@@ -83,6 +84,7 @@ def test_the_deepseek_v3_phase_runs_on_cpu_at_small_widths(tmp_path):
     assert trunk["latent_cache_bytes_per_sequence"] == 3 * 70 * (16 + 4) * 4
     counters = trunk["counters"]
     assert counters["moe/update_pairs_dropped"] == 0 and counters["moe/rollout_pairs_dropped"] == 0
+    assert counters["mla/rollout_decode_kernel_share"] == 0  # off the chip every step takes the XLA form
     assert 0 < counters["moe/update_dispatch_fill"] <= 1
 
 

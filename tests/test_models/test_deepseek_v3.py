@@ -96,15 +96,20 @@ def test_each_block_agrees_with_the_reference(block):
         assert ids is None and counters is None
 
 
-@pytest.mark.parametrize("cache_len, block", [(T, 128), (T + 12, 128), (T + 12, 8), (T + 10, 7)],
-                         ids=["fills_the_cache", "does_not_fill_it", "four_blocks_of_eight", "block_of_six"])
-def test_the_absorbed_step_form_through_the_latent_cache_is_the_expanded_form(cache_len, block, monkeypatch):
+@pytest.mark.parametrize("cache_len, block, kernel", [(T, 128, False), (T + 12, 128, False), (T + 12, 8, False), (T + 10, 7, False),
+                                                    (128, 128, True)],
+                         ids=["fills_the_cache", "does_not_fill_it", "four_blocks_of_eight", "block_of_six", "the_kernel"])
+def test_the_absorbed_step_form_through_the_latent_cache_is_the_expanded_form(cache_len, block, kernel, monkeypatch):
     """Token by token through the latent cache, never making a key or a value of a cached
     position, against the whole-sequence form that makes them all: values to rounding; with the
     rows past the position holding NaN, which a step must never read as what they hold; and a
-    block at a time (the running softmax over as many blocks as the position asks for)."""
+    block at a time (the running softmax over as many blocks as the position asks for), or
+    through the latent-cache kernel (`ops/latent_decode.py`, here in Pallas' interpreter)."""
     monkeypatch.setattr(deepseek_v3, "CACHE_BLOCK", block)
-    assert deepseek_v3.cache_block(cache_len) == {(T, 128): 20, (T + 12, 128): 32, (T + 12, 8): 8, (T + 10, 7): 6}[(cache_len, block)]
+    if kernel:
+        monkeypatch.setattr(deepseek_v3, "decode_kernel_passes", lambda shape: lm_layers.matmul_passes())
+    assert deepseek_v3.cache_block(cache_len) == {(T, 128): 20, (T + 12, 128): 32, (T + 12, 8): 8, (T + 10, 7): 6,
+                                                  (128, 128): 128}[(cache_len, block)]
     spec, m = sizes(max_seq_len=cache_len)
     p = dict(ref.init_params(m, 1)["layer_1"]["op"], kv_norm=1.0 + 0.3 * jax.random.normal(jax.random.PRNGKey(3), (16,)))
     u = jax.random.normal(jax.random.PRNGKey(4), (2, T, spec.hidden_size))
@@ -121,6 +126,19 @@ def test_the_absorbed_step_form_through_the_latent_cache_is_the_expanded_form(ca
     _, _, c, k_pe = deepseek_v3._latent_inputs(p, u, jnp.arange(T), spec)
     close(cache[:, :T, :16], c, 1e-6)
     close(cache[:, :T, 16:], k_pe, 1e-6)
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["xla_form", "the_kernel"])
+def test_the_step_counts_the_share_of_layers_whose_decode_took_the_kernel(kernel, monkeypatch):
+    """`mla/decode_kernel_share`, fixed when the step is traced: 0 where every layer takes the
+    XLA form (off the chip), 1 where every layer's cache takes the kernel."""
+    if kernel:
+        monkeypatch.setattr(deepseek_v3, "decode_kernel_passes", lambda shape: lm_layers.matmul_passes())
+    spec, _ = sizes(max_seq_len=128)
+    params = deepseek_v3.init_params(spec, jax.random.PRNGKey(0))
+    step = jax.jit(lambda p, c, t: deepseek_v3.step(p, spec, c, t))
+    *_, counters = step(params, deepseek_v3.init_carry(spec, 2), jnp.zeros((2,), jnp.int32))
+    assert counters["mla/decode_kernel_share"] == float(kernel)
 
 
 def _equations(jaxpr):

@@ -1,6 +1,8 @@
 """The grouped matmul kernels compiled for a v5e that is described and not attached, at the
-LFM2 and Moonlight cells' shapes and the tilings `ops/grouped_matmul.py` picks for them: what Pallas'
-interpreter cannot show (a tile Mosaic refuses, more VMEM than the kernels are given).
+LFM2 and Moonlight cells' shapes and the tilings `ops/grouped_matmul.py` picks for them, and the
+latent-cache decode kernel (`ops/latent_decode.py`) at the Moonlight cell's: what Pallas'
+interpreter cannot show (a tile Mosaic refuses, more VMEM than the kernels are given, a DMA of
+a slice that is not whole tiles).
 The topology is described inside a fixture, so every worker collects the same tests and
 only the one that is given this file loads the TPU's library."""
 
@@ -85,3 +87,29 @@ def test_the_tilings_of_the_three_trunks_grouped_shapes(cell, product):
         whole = gm.gmm_tiling(m, hidden, width)
         assert whole[2] == width and gm.gmm_vmem_bytes(whole) == 54_657_024
         assert gm.gmm_flops_per_byte(whole, 3, True) > gm.RIDGE_FLOPS_PER_BYTE > gm.gmm_flops_per_byte((128, 2048, 128), 3, True)
+
+
+@pytest.mark.parametrize("passes, precision", [(3, "high"), (6, "highest"), (1, "default")])
+def test_the_latent_decode_kernel_compiles_for_the_chip_in_the_decode_loop(passes, precision, one_chip):
+    """A ``[64, 512, 576]`` latent cache (the 576 floats a row are no whole number of lane tiles),
+    16 heads, in the loop over 512 decode steps that carries the cache: inside the loop's body
+    nothing but the kernel's aliased output is a whole cache."""
+    from sheeprl_tpu.ops import latent_decode
+
+    batch, positions, width, heads = 64, 512, 576, 16
+
+    def decode(cache, rows, queries):
+        def body(cache, x):
+            t, row, query = x
+            weighed, total, cache = latent_decode.latent_decode(cache, t, row, query, passes)
+            return cache, weighed / total[..., None]
+
+        return jax.lax.scan(body, cache, (jnp.arange(positions, dtype=jnp.int32), rows, queries))
+
+    shapes = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip) for s in
+              ((batch, positions, width), (positions, batch, width), (positions, batch, heads, width))]
+    with jax.default_matmul_precision(precision):
+        text = jax.jit(decode, donate_argnums=0).lower(*shapes).compile().as_text()
+    body = next(block for block in text.split("\n\n") if "custom_call_target=\"tpu_custom_call\"" in block)
+    whole = [line for line in body.splitlines() if " = f32[64,512,576]{" in line]
+    assert whole and all("get-tuple-element(" in line for line in whole), whole

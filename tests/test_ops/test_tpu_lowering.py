@@ -112,3 +112,30 @@ def test_tpu_lowering_compiles_nothing(monkeypatch):
     before = compile_snapshot()["count"]
     _lower(lambda x: x * 2, x)
     assert compile_snapshot()["count"] == before
+
+
+def test_the_latent_decode_kernel_lowers_for_tpu_in_a_scan_that_carries_its_cache():
+    """The latent-cache kernel at the Moonlight cell's shapes (a ``[64, 512, 576]`` cache, 16
+    heads, three passes under `high`) inside a jitted ``lax.scan`` over 512 decode steps that
+    carries the cache: a Mosaic call whose cache operand is aliased to its third output, so
+    that the loop writes its row in place."""
+    import re
+
+    from sheeprl_tpu.ops import latent_decode
+
+    batch, positions, width, heads = 64, 512, 576, 16
+
+    def decode(cache, rows, queries):
+        def body(cache, x):
+            t, row, query = x
+            weighed, total, cache = latent_decode.latent_decode(cache, t, row, query, 3)
+            return cache, weighed / total[..., None]
+
+        return jax.lax.scan(body, cache, (jnp.arange(positions, dtype=jnp.int32), rows, queries))
+
+    shapes = [jax.ShapeDtypeStruct(s, jnp.float32) for s in
+              ((batch, positions, width), (positions, batch, width), (positions, batch, heads, width))]
+    with jax.default_matmul_precision("high"):
+        text = jax.jit(decode, donate_argnums=0).trace(*shapes).lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert re.search(r"output_operand_alias<output_tuple_indices = \[2\], operand_index = 1,", text)
