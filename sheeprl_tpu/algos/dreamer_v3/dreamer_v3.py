@@ -765,9 +765,9 @@ def run_dreamer(
 
     # Optional steady-state measurement window for bench.py (see bench.py docstring)
     bench = BenchWindow()
+    bench.maybe_start(policy_step, trainer.sync_tree())  # then at the end of each iteration, for the next
 
     for iter_num in range(start_iter, total_iters + 1):
-        bench.maybe_start(policy_step, trainer.sync_tree())
         policy_step += policy_steps_per_iter
         timer.iteration = iter_num  # the spans of one iteration share it
 
@@ -805,109 +805,115 @@ def run_dreamer(
                 )
             dones = np.logical_or(terminated, truncated).astype(np.uint8)
 
-        step_data["is_first"] = np.zeros_like(step_data["terminated"])
-        if "restart_on_exception" in infos:
-            # surface the crash-restart (previously invisible): Health/env_restarts
-            # gauge + an immediate health event in telemetry.jsonl
-            telemetry.observe_env_restart(int(np.sum(infos["restart_on_exception"])))
-            # in-place ring-storage rewrite: take the sampler lock so a concurrent
-            # prefetch gather never reads a torn episode-boundary row
-            with sampler.lock:
-                for i, agent_roe in enumerate(infos["restart_on_exception"]):
-                    if agent_roe and not dones[i]:
-                        sub_rb = rb.buffer[i]
-                        last_inserted_idx = (sub_rb._pos - 1) % sub_rb.buffer_size
-                        sub_rb["terminated"][last_inserted_idx] = np.zeros_like(
-                            sub_rb["terminated"][last_inserted_idx]
-                        )
-                        sub_rb["truncated"][last_inserted_idx] = np.ones_like(
-                            sub_rb["truncated"][last_inserted_idx]
-                        )
-                        sub_rb["is_first"][last_inserted_idx] = np.zeros_like(
-                            sub_rb["is_first"][last_inserted_idx]
-                        )
-                        step_data["is_first"][:, i] = np.ones_like(step_data["is_first"][:, i])
+        # episode stats, the copies of the next obs, the reset rows and the train decision:
+        # with the spans around it, every host instant of an iteration lies in a span
+        with timer("step_bookkeeping"):
+            step_data["is_first"] = np.zeros_like(step_data["terminated"])
+            if "restart_on_exception" in infos:
+                # surface the crash-restart (previously invisible): Health/env_restarts
+                # gauge + an immediate health event in telemetry.jsonl
+                telemetry.observe_env_restart(int(np.sum(infos["restart_on_exception"])))
+                # in-place ring-storage rewrite: take the sampler lock so a concurrent
+                # prefetch gather never reads a torn episode-boundary row
+                with sampler.lock:
+                    for i, agent_roe in enumerate(infos["restart_on_exception"]):
+                        if agent_roe and not dones[i]:
+                            sub_rb = rb.buffer[i]
+                            last_inserted_idx = (sub_rb._pos - 1) % sub_rb.buffer_size
+                            sub_rb["terminated"][last_inserted_idx] = np.zeros_like(
+                                sub_rb["terminated"][last_inserted_idx]
+                            )
+                            sub_rb["truncated"][last_inserted_idx] = np.ones_like(
+                                sub_rb["truncated"][last_inserted_idx]
+                            )
+                            sub_rb["is_first"][last_inserted_idx] = np.zeros_like(
+                                sub_rb["is_first"][last_inserted_idx]
+                            )
+                            step_data["is_first"][:, i] = np.ones_like(step_data["is_first"][:, i])
 
-        ep_info = infos.get("final_info", infos)
-        if (cfg.metric.log_level > 0 or telemetry.enabled) and "episode" in ep_info:
-            ep = ep_info["episode"]
-            mask = ep.get("_r", ep_info.get("_episode", np.ones(num_envs, bool)))
-            rews, lens = ep["r"][mask], ep["l"][mask]
-            if len(rews) > 0:
-                telemetry.observe_episodes(rews, lens)
-                if aggregator and not aggregator.disabled:
-                    aggregator.update("Rewards/rew_avg", float(np.mean(rews)))
-                    aggregator.update("Game/ep_len_avg", float(np.mean(lens)))
+            ep_info = infos.get("final_info", infos)
+            if (cfg.metric.log_level > 0 or telemetry.enabled) and "episode" in ep_info:
+                ep = ep_info["episode"]
+                mask = ep.get("_r", ep_info.get("_episode", np.ones(num_envs, bool)))
+                rews, lens = ep["r"][mask], ep["l"][mask]
+                if len(rews) > 0:
+                    telemetry.observe_episodes(rews, lens)
+                    if aggregator and not aggregator.disabled:
+                        aggregator.update("Rewards/rew_avg", float(np.mean(rews)))
+                        aggregator.update("Game/ep_len_avg", float(np.mean(lens)))
 
-        # real next obs of finished episodes (reference dreamer_v3.py:701-708)
-        real_next_obs = {k: np.asarray(next_obs[k]).copy() for k in obs_keys}
-        final_obs_arr = infos.get("final_observation", infos.get("final_obs"))
-        if final_obs_arr is not None:
-            for idx in range(num_envs):
-                if final_obs_arr[idx] is not None:
-                    for k in obs_keys:
-                        real_next_obs[k][idx] = np.asarray(final_obs_arr[idx][k])
+            # real next obs of finished episodes (reference dreamer_v3.py:701-708)
+            real_next_obs = {k: np.asarray(next_obs[k]).copy() for k in obs_keys}
+            final_obs_arr = infos.get("final_observation", infos.get("final_obs"))
+            if final_obs_arr is not None:
+                for idx in range(num_envs):
+                    if final_obs_arr[idx] is not None:
+                        for k in obs_keys:
+                            real_next_obs[k][idx] = np.asarray(final_obs_arr[idx][k])
 
-        for k in obs_keys:
-            step_data[k] = np.asarray(next_obs[k])[np.newaxis]
-        obs = next_obs
-
-        rewards = np.asarray(rewards, dtype=np.float32).reshape((1, num_envs, -1))
-        step_data["terminated"] = np.asarray(terminated, np.float32).reshape((1, num_envs, -1))
-        step_data["truncated"] = np.asarray(truncated, np.float32).reshape((1, num_envs, -1))
-        step_data["rewards"] = clip_rewards_fn(rewards)
-
-        dones_idxes = dones.nonzero()[0].tolist()
-        reset_envs = len(dones_idxes)
-        if reset_envs > 0:
-            reset_data = {}
             for k in obs_keys:
-                reset_data[k] = (real_next_obs[k][dones_idxes])[np.newaxis]
-            reset_data["terminated"] = step_data["terminated"][:, dones_idxes]
-            reset_data["truncated"] = step_data["truncated"][:, dones_idxes]
-            reset_data["actions"] = np.zeros((1, reset_envs, act_dim), np.float32)
-            reset_data["rewards"] = step_data["rewards"][:, dones_idxes]
-            reset_data["is_first"] = np.zeros_like(reset_data["terminated"])
-            with timer("replay_add"):
-                sampler.add(reset_data, dones_idxes, validate_args=cfg.buffer.validate_args)
-            # the reset rows restart the episode in the *live* step_data
-            step_data["rewards"][:, dones_idxes] = 0.0
-            step_data["terminated"][:, dones_idxes] = 0.0
-            step_data["truncated"][:, dones_idxes] = 0.0
-            step_data["is_first"][:, dones_idxes] = 1.0
-            with timer("player_reset"):
-                player.init_states(act_params, dones_idxes)
+                step_data[k] = np.asarray(next_obs[k])[np.newaxis]
+            obs = next_obs
 
-        # checkpoint due? (computed BEFORE the train round so a channel trainer can
-        # ship the full state with it; a deferring trainer postpones off-round
-        # checkpoints to the next train round). A preemption forces an
-        # out-of-cadence emergency checkpoint through the same path; the flag is
-        # snapshotted once per iteration so the save and the loop-exit break can
-        # never disagree about it.
-        preempted = resilience.preempt_requested()
-        pending_ckpt = pending_ckpt or preempted or (
-            (cfg.checkpoint.every > 0 and policy_step - last_checkpoint >= cfg.checkpoint.every)
-            or cfg.dry_run
-            or (iter_num == total_iters and cfg.checkpoint.save_last)
-        )
-        trained_this_iter = False
+            rewards = np.asarray(rewards, dtype=np.float32).reshape((1, num_envs, -1))
+            step_data["terminated"] = np.asarray(terminated, np.float32).reshape((1, num_envs, -1))
+            step_data["truncated"] = np.asarray(truncated, np.float32).reshape((1, num_envs, -1))
+            step_data["rewards"] = clip_rewards_fn(rewards)
+
+            dones_idxes = dones.nonzero()[0].tolist()
+            reset_envs = len(dones_idxes)
+            if reset_envs > 0:
+                reset_data = {}
+                for k in obs_keys:
+                    reset_data[k] = (real_next_obs[k][dones_idxes])[np.newaxis]
+                reset_data["terminated"] = step_data["terminated"][:, dones_idxes]
+                reset_data["truncated"] = step_data["truncated"][:, dones_idxes]
+                reset_data["actions"] = np.zeros((1, reset_envs, act_dim), np.float32)
+                reset_data["rewards"] = step_data["rewards"][:, dones_idxes]
+                reset_data["is_first"] = np.zeros_like(reset_data["terminated"])
+                with timer("replay_add"):
+                    sampler.add(reset_data, dones_idxes, validate_args=cfg.buffer.validate_args)
+                # the reset rows restart the episode in the *live* step_data
+                step_data["rewards"][:, dones_idxes] = 0.0
+                step_data["terminated"][:, dones_idxes] = 0.0
+                step_data["truncated"][:, dones_idxes] = 0.0
+                step_data["is_first"][:, dones_idxes] = 1.0
+                with timer("player_reset"):
+                    player.init_states(act_params, dones_idxes)
+
+            # checkpoint due? (computed BEFORE the train round so a channel trainer can
+            # ship the full state with it; a deferring trainer postpones off-round
+            # checkpoints to the next train round). A preemption forces an
+            # out-of-cadence emergency checkpoint through the same path; the flag is
+            # snapshotted once per iteration so the save and the loop-exit break can
+            # never disagree about it.
+            preempted = resilience.preempt_requested()
+            pending_ckpt = pending_ckpt or preempted or (
+                (cfg.checkpoint.every > 0 and policy_step - last_checkpoint >= cfg.checkpoint.every)
+                or cfg.dry_run
+                or (iter_num == total_iters and cfg.checkpoint.save_last)
+            )
+            trained_this_iter = False
+            per_rank_gradient_steps = 0
+            if iter_num >= learning_starts:
+                ratio_steps = policy_step - prefill_steps * policy_steps_per_iter
+                per_rank_gradient_steps = ratio(ratio_steps / world_size)
 
         # train
-        if iter_num >= learning_starts:
-            ratio_steps = policy_step - prefill_steps * policy_steps_per_iter
-            per_rank_gradient_steps = ratio(ratio_steps / world_size)
-            if per_rank_gradient_steps > 0:
-                with timer("Time/train_time"):
-                    with timer("replay_sample"):
-                        data = sampler.sample(per_rank_gradient_steps)
+        if per_rank_gradient_steps > 0:
+            with timer("Time/train_time"):
+                with timer("replay_sample"):
+                    data = sampler.sample(per_rank_gradient_steps)
+                with timer("train_key"):
                     key, train_key = jax.random.split(key)
-                    act_params, host_metrics = trainer.train(
-                        data,
-                        cumulative_per_rank_gradient_steps,
-                        train_key,
-                        want_full_state=pending_ckpt,
-                        want_metrics=bool(aggregator and not aggregator.disabled),
-                    )
+                act_params, host_metrics = trainer.train(
+                    data,
+                    cumulative_per_rank_gradient_steps,
+                    train_key,
+                    want_full_state=pending_ckpt,
+                    want_metrics=bool(aggregator and not aggregator.disabled),
+                )
+                with timer("train_observe"):
                     cumulative_per_rank_gradient_steps += per_rank_gradient_steps
                     train_step += world_size * per_rank_gradient_steps
                     trained_this_iter = True
@@ -943,80 +949,83 @@ def run_dreamer(
                         for mk, mv in host_metrics.items():
                             aggregator.update(mk, float(mv))
 
-        # log
-        telemetry.step(policy_step)
-        resilience.step(policy_step)
-        if cfg.metric.log_level > 0 and (
-            policy_step - last_log >= cfg.metric.log_every or iter_num == total_iters or cfg.dry_run
-        ):
-            with timer("Time/logging_time"):
-                metrics_dict = aggregator.compute() if aggregator else {}
-                if logger is not None:
-                    logger.log_metrics(metrics_dict, policy_step)
-                    if policy_step > 0:
-                        logger.log_metrics(
-                            {
-                                "Params/replay_ratio": cumulative_per_rank_gradient_steps
-                                * world_size
-                                / max(policy_step, 1)
-                            },
-                            policy_step,
-                        )
-                    timers = timer.to_dict(reset=False)
-                    if timers.get("Time/train_time", 0) > 0:
-                        logger.log_metrics(
-                            {"Time/sps_train": (train_step - last_train) / max(timers["Time/train_time"], 1e-9)},
-                            policy_step,
-                        )
-                    if timers.get("Time/env_interaction_time", 0) > 0:
-                        logger.log_metrics(
-                            {
-                                "Time/sps_env_interaction": (
-                                    (policy_step - last_log) / world_size * cfg.env.action_repeat
-                                )
-                                / max(timers["Time/env_interaction_time"], 1e-9)
-                            },
-                            policy_step,
-                        )
-                timer.to_dict(reset=True)
-                if aggregator:
-                    aggregator.reset()
-            last_log = policy_step
-            last_train = train_step
+        # log, checkpoint, and the next iteration's bench window: the iteration's last span
+        with timer("loop_tail"):
+            telemetry.step(policy_step)
+            resilience.step(policy_step)
+            if cfg.metric.log_level > 0 and (
+                policy_step - last_log >= cfg.metric.log_every or iter_num == total_iters or cfg.dry_run
+            ):
+                with timer("Time/logging_time"):
+                    metrics_dict = aggregator.compute() if aggregator else {}
+                    if logger is not None:
+                        logger.log_metrics(metrics_dict, policy_step)
+                        if policy_step > 0:
+                            logger.log_metrics(
+                                {
+                                    "Params/replay_ratio": cumulative_per_rank_gradient_steps
+                                    * world_size
+                                    / max(policy_step, 1)
+                                },
+                                policy_step,
+                            )
+                        timers = timer.to_dict(reset=False)
+                        if timers.get("Time/train_time", 0) > 0:
+                            logger.log_metrics(
+                                {"Time/sps_train": (train_step - last_train) / max(timers["Time/train_time"], 1e-9)},
+                                policy_step,
+                            )
+                        if timers.get("Time/env_interaction_time", 0) > 0:
+                            logger.log_metrics(
+                                {
+                                    "Time/sps_env_interaction": (
+                                        (policy_step - last_log) / world_size * cfg.env.action_repeat
+                                    )
+                                    / max(timers["Time/env_interaction_time"], 1e-9)
+                                },
+                                policy_step,
+                            )
+                    timer.to_dict(reset=True)
+                    if aggregator:
+                        aggregator.reset()
+                last_log = policy_step
+                last_train = train_step
 
-        # checkpoint (a deferring trainer only has full state at train rounds; its
-        # last pending checkpoint, if any, is flushed by close() below; a trainer
-        # with external_checkpoints — the service actor, whose LEARNER owns the
-        # full state — never checkpoints from this loop at all)
-        if (
-            pending_ckpt
-            and not getattr(trainer, "external_checkpoints", False)
-            and (not trainer.defers_checkpoints or trained_this_iter)
-        ):
-            last_checkpoint = policy_step
-            pending_ckpt = False
-            ckpt_agent, ckpt_opt, ckpt_moments = trainer.checkpoint_state()
-            ckpt_state = {
-                "agent": ckpt_agent,
-                "opt_state": ckpt_opt,
-                "moments": ckpt_moments,
-                "ratio": ratio.state_dict(),
-                "iter_num": iter_num * world_size,
-                "batch_size": cfg.algo.per_rank_batch_size * world_size,
-                "last_log": last_log,
-                "last_checkpoint": last_checkpoint,
-            }
-            ckpt_path = os.path.join(log_dir, "checkpoint", f"ckpt_{policy_step}_{rank}.ckpt")
-            # quiesce the prefetch worker so the pickled buffer (incl. its RNG
-            # state) is not a torn mid-sample snapshot
-            with sampler.lock, timer("Time/checkpoint_time"):
-                fabric.call(
-                    "on_checkpoint_coupled",
-                    ckpt_path=ckpt_path,
-                    state=ckpt_state,
-                    replay_buffer=rb if cfg.buffer.checkpoint else None,
-                )
-            resilience.observe_checkpoint(ckpt_path, policy_step, preempted=preempted)
+            # checkpoint (a deferring trainer only has full state at train rounds; its
+            # last pending checkpoint, if any, is flushed by close() below; a trainer
+            # with external_checkpoints — the service actor, whose LEARNER owns the
+            # full state — never checkpoints from this loop at all)
+            if (
+                pending_ckpt
+                and not getattr(trainer, "external_checkpoints", False)
+                and (not trainer.defers_checkpoints or trained_this_iter)
+            ):
+                last_checkpoint = policy_step
+                pending_ckpt = False
+                ckpt_agent, ckpt_opt, ckpt_moments = trainer.checkpoint_state()
+                ckpt_state = {
+                    "agent": ckpt_agent,
+                    "opt_state": ckpt_opt,
+                    "moments": ckpt_moments,
+                    "ratio": ratio.state_dict(),
+                    "iter_num": iter_num * world_size,
+                    "batch_size": cfg.algo.per_rank_batch_size * world_size,
+                    "last_log": last_log,
+                    "last_checkpoint": last_checkpoint,
+                }
+                ckpt_path = os.path.join(log_dir, "checkpoint", f"ckpt_{policy_step}_{rank}.ckpt")
+                # quiesce the prefetch worker so the pickled buffer (incl. its RNG
+                # state) is not a torn mid-sample snapshot
+                with sampler.lock, timer("Time/checkpoint_time"):
+                    fabric.call(
+                        "on_checkpoint_coupled",
+                        ckpt_path=ckpt_path,
+                        state=ckpt_state,
+                        replay_buffer=rb if cfg.buffer.checkpoint else None,
+                    )
+                resilience.observe_checkpoint(ckpt_path, policy_step, preempted=preempted)
+            if not preempted and iter_num < total_iters:
+                bench.maybe_start(policy_step, trainer.sync_tree())
         if preempted:
             # still-pending emergency checkpoint (a deferring trainer without a
             # train round this iteration) is flushed by the close() path below;

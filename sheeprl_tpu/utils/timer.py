@@ -27,8 +27,10 @@ from typing import Any, ClassVar, Deque, Dict, List, Optional, Tuple
 # module imports nothing at start-up that it did not before it had spans
 TraceAnnotation: Any = None
 
-# spans kept in memory: a week-long run must not grow. At 24 spans an iteration this
-# is the last ~680 iterations, about 1.5 MB
+# spans kept in memory: a week-long run must not grow. The Dreamer-V3 loop records about
+# 9.5 spans an iteration at 4 envs and a train call every second iteration (6 every
+# iteration, 6 a train call and 1 a gradient step), so this is its last ~1,700
+# iterations, about 1.5 MB
 RING_CAPACITY = 16384
 
 Span = Tuple[str, float, float, Optional[str], int]  # name, start, end, parent, iter

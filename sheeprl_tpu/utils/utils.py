@@ -441,6 +441,9 @@ def foreach_gradient_step(train_step, state, data, train_key, cum_steps=None):
     ``train_step`` takes ``(*state, batch, key)`` — or ``(*state, batch, cum, key)``
     when ``cum_steps`` is given — and returns ``(*new_state, metrics)``.
     Returns ``(*final_state, mean_metrics)``.
+
+    Each step's call of ``train_step`` is the span ``train_dispatch.call``; the key
+    split, the slices and the step counter are made outside it.
     """
     G = int(jax.tree_util.tree_leaves(data)[0].shape[0])
     if G == 0:
@@ -451,10 +454,9 @@ def foreach_gradient_step(train_step, state, data, train_key, cum_steps=None):
     all_metrics = []
     for g in range(G):
         batch = jax.tree_util.tree_map(lambda a: a[g], data)
-        if cum is None:
-            *state, metrics = train_step(*state, batch, keys[g])
-        else:
-            *state, metrics = train_step(*state, batch, jnp.asarray(cum + g), keys[g])
+        args = (batch, keys[g]) if cum is None else (batch, jnp.asarray(cum + g), keys[g])
+        with timer("train_dispatch.call"):
+            *state, metrics = train_step(*state, *args)
         all_metrics.append(metrics)
     if len(all_metrics) > 1:
         metrics = jax.tree_util.tree_map(lambda *ms: jnp.stack(ms).mean(), *all_metrics)
