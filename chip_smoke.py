@@ -26,10 +26,13 @@ One process, six phases, through the entry points a user calls:
 5. ``qwen3_next``: the sequence policy's second trunk (``models/qwen3_next.py``)
    at Qwen3-Next-80B-A3B's published widths, one period of its layers: tokens
    decoded one a step through the three kinds of state against the chunked
-   whole-sequence forward; then ``sheeprl_tpu.cli.run`` on
+   whole-sequence forward (the matrix state through the delta-rule kernel,
+   ``ops/delta_rule_decode.py``); then ``sheeprl_tpu.cli.run`` on
    ``exp=ppo_anakin_qwen3_next`` at widths the kernels tile, whose update's
    bounded dispatch must drop no pair, fill its buffers by a share in (0, 1]
-   and count three bf16 passes.
+   and count three bf16 passes, whose every decode step must take the
+   delta-rule kernel, and whose compiled program must write or copy no whole
+   matrix state.
 6. ``deepseek_v3``: the third trunk (``models/deepseek_v3.py``) at
    Moonlight-16B-A3B's published widths, the dense layer and two expert layers:
    tokens decoded one a step in the absorbed form through the latent caches
@@ -699,7 +702,7 @@ def _trunk_phase(name: str, trunk, spec, overrides: Sequence[str], *, platform: 
     result = {
         "telemetry": stream,
         "decode_gaps_to_the_full_forward": gaps,
-        "counters": {name: mean[name] for name in sorted(mean) if name.startswith(("moe/", "mla/"))},
+        "counters": {name: mean[name] for name in sorted(mean) if name.startswith(("moe/", "mla/", "lin_attn/"))},
         "compile": summary["compile"],
         "wall_seconds": round(wall, 1),
     }
@@ -712,11 +715,22 @@ def qwen3_next_phase(
     batch: int = 4, steps: int = 96
 ) -> Dict[str, Any]:
     """The `qwen3_next` trunk (`_trunk_phase`): decoding through its three kinds of state (KV
-    cache, convolution columns, matrix state) against the chunked whole-sequence forward."""
+    cache, convolution columns, matrix state) against the chunked whole-sequence forward. In the
+    run every linear-attention layer's decode step takes the delta-rule kernel on a TPU and none
+    elsewhere (`lin_attn/rollout_decode_kernel_share` 1 or 0), and on a TPU no instruction of the
+    program writes or copies a whole matrix state (the kernel writes it in place)."""
     from sheeprl_tpu.models import qwen3_next
 
     spec = qwen3_next.Qwen3NextSpec(**widths, max_seq_len=steps)
-    return _trunk_phase("qwen3_next", qwen3_next, spec, overrides, platform=platform, out_dir=out_dir, batch=batch)
+    given = dict(o.split("=", 1) for o in overrides if "=" in o)
+    whole_state = "f32[{}]".format(",".join(given.get(key, "") for key in (
+        "env.num_envs", "algo.lm.linear_num_value_heads", "algo.lm.linear_key_head_dim", "algo.lm.linear_value_head_dim")))
+    result = _trunk_phase("qwen3_next", qwen3_next, spec, overrides, platform=platform, out_dir=out_dir, batch=batch,
+                          whole_cache=whole_state)
+    share = result["counters"].get("lin_attn/rollout_decode_kernel_share")
+    _check(share == (1.0 if platform == "tpu" else 0.0),
+           f"`lin_attn/rollout_decode_kernel_share` reads {share} on {platform}: the delta-rule kernel {'not ' if platform == 'tpu' else ''}taken")
+    return result
 
 
 def deepseek_v3_phase(

@@ -11,7 +11,8 @@ matrix state ``S`` ``[value heads, key dim, value dim]``.
 
 The gated delta rule, per value head and token, with ``S_0 = 0``:
 ``S <- exp(g_t) S``; ``r = S^T k_t``; ``S <- S + k_t (beta_t (v_t - r))^T``; ``o_t = S^T q_t``.
-The step form is those four lines. The whole-sequence form is chunked (`chunk_delta_rule`):
+The step form is those four lines (on the TPU one kernel, `ops/delta_rule_decode.py`, that
+reads and writes ``S`` once a step). The whole-sequence form is chunked (`chunk_delta_rule`):
 inside a chunk of ``chunk_size`` tokens the rule's pseudo-values solve one unit lower
 triangular system, whose inverse is formed by products for all chunks at once, and a scan
 over the chunks carries ``S``; it is exact, with no approximation the recurrence does not
@@ -38,6 +39,7 @@ import jax.numpy as jnp
 
 from sheeprl_tpu.models import lm_layers
 from sheeprl_tpu.models.lm_layers import INIT_STD, attend, rms_core, rope, stack_routes
+from sheeprl_tpu.ops import delta_rule_decode as decode_kernel
 
 CONV_TAP_STD = 0.3
 L2_EPS = 1e-6
@@ -211,9 +213,19 @@ def l2_norm(x):
 
 
 # -- the gated delta rule -----------------------------------------------------------------
+def decode_kernel_taken(state_shape) -> bool:
+    """Whether a decode step over a state of ``state_shape`` takes the kernel
+    (`ops/delta_rule_decode.py`): on the TPU, where the kernel tiles the state."""
+    return jax.default_backend() == "tpu" and decode_kernel.supports(state_shape)
+
+
 def delta_rule_step(state, q, k, v, g, beta):
     """One token: ``state`` ``[B, H, dk, dv]``, ``q``, ``k`` ``[B, H, dk]``, ``v`` ``[B, H, dv]``,
-    ``g``, ``beta`` ``[B, H]`` -> (``o`` ``[B, H, dv]``, the new state)."""
+    ``g``, ``beta`` ``[B, H]`` -> (``o`` ``[B, H, dv]``, the new state). Where
+    `decode_kernel_taken`, one kernel that reads each head's state once and writes it back in
+    place; else the rule's four lines in XLA."""
+    if decode_kernel_taken(state.shape):
+        return decode_kernel.delta_rule_decode(state, q, k, v, g, beta, interpret=jax.default_backend() != "tpu")
     state = state * jnp.exp(g)[..., None, None]
     read = jnp.einsum("bhkv,bhk->bhv", state, k)
     state = state + k[..., :, None] * (beta[..., None] * (v - read))[..., None, :]
@@ -447,7 +459,8 @@ def forward(params, spec: Qwen3NextSpec, tokens):
 def step(params, spec: Qwen3NextSpec, carry, tokens):
     """One token a sequence, ``tokens`` ``[B]``, through the carried state -> logits
     ``[B, V]``, values ``[B]``, the new carry, the chosen experts ``[B, layers, k]`` and the
-    layers' counters."""
+    layers' counters, with ``lin_attn/decode_kernel_share``: the share of the linear-attention
+    layers whose delta rule took the decode kernel (`decode_kernel_taken`)."""
     t = carry["t"]
     new_carry: Dict[str, Any] = {"t": t + 1}
     with jax.named_scope("embed"):
@@ -468,6 +481,10 @@ def step(params, spec: Qwen3NextSpec, carry, tokens):
         routes.append((ids, counters))
     logits, value = heads(params, x, spec)
     ids, counters = stack_routes(routes)
+    kernel = [decode_kernel_taken(carry[f"layer_{i}"][1].shape) for i, op in enumerate(spec.layer_types)
+              if op == "linear_attention"]
+    if kernel:  # fixed when traced
+        counters["lin_attn/decode_kernel_share"] = jnp.float32(sum(kernel) / len(kernel))
     return logits, value, new_carry, ids, counters
 
 

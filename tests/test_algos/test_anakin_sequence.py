@@ -121,6 +121,7 @@ def test_a_counter_is_kept_under_the_space_its_trunk_names_or_the_expert_layers(
     assert _counter_name("rollout_pairs_held") == "moe/rollout_pairs_held"
     assert _counter_name("update_grouped_product_passes") == "moe/update_grouped_product_passes"
     assert _counter_name("rollout_mla/decode_kernel_share") == "mla/rollout_decode_kernel_share"
+    assert _counter_name("rollout_lin_attn/decode_kernel_share") == "lin_attn/rollout_decode_kernel_share"
 
 
 def test_a_sequence_policy_needs_episodes_of_one_rollout():

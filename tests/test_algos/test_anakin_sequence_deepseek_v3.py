@@ -41,7 +41,9 @@ TOY = [
 # matmul precision pinned to `highest`, jax 0.9.0), taken on the commit before this trunk (841ea4e)
 OTHER_TRUNKS_SHA256 = {
     "ppo_anakin_lfm2": "f6c76c7bccb57de38bbc8e1816be491fde24303817b97fe8841962a412b36f70",
-    "ppo_anakin_qwen3_next": "2978b86e108ffd69eb112b92939fd859dffbb44da6bc6670cadb7ef3284085d4",
+    # with the counter `lin_attn/decode_kernel_share` its step returns (0 off the chip); without it, the text of
+    # before the counter byte for byte (2978b86e...)
+    "ppo_anakin_qwen3_next": "9cb4d5b1aa728d37887b9694e0be15902d932d02898a9b77b8d921d67c5b35dc",
 }
 
 

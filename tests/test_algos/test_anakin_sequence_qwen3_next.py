@@ -60,7 +60,10 @@ def test_cli_smoke_three_iterations_with_telemetry(tmp_path):
     counters = windows[-1]["counters"]
     assert counters["moe/update_pairs_dropped"][1] == 0 and counters["moe/rollout_pairs_dropped"][1] == 0
     # 80 tokens a gradient step: the dense form (no dispatch buffers)
-    assert "moe/update_dispatch_fill" not in counters and all(name.startswith("moe/") for name in counters)
+    assert "moe/update_dispatch_fill" not in counters
+    # the expert layers' counters, and the matrix state's own: off the chip no step takes the kernel
+    assert all(name.startswith("moe/") for name in counters if name != "lin_attn/rollout_decode_kernel_share")
+    assert counters["lin_attn/rollout_decode_kernel_share"][1] == 0
     assert not any(e["event"] == "health" and e.get("status") == "nonfinite" for e in events)
 
 
@@ -104,7 +107,7 @@ def test_the_fused_program_returns_its_record_and_counters_and_names_its_parts()
     record, counters = extras["record"], extras["counters"]
     assert record["traj"]["route_ids"].shape == (40, 8, 2, 4) and record["update_route_ids"].shape == (2, 4, 40, 2, 4)
     assert set(counters) == {
-        f"rollout_{name}" for name in ("pairs_held", "max_load", "pairs_dropped")} | {
+        f"rollout_{name}" for name in ("pairs_held", "max_load", "pairs_dropped", "lin_attn/decode_kernel_share")} | {
         f"update_{name}" for name in ("pairs_held", "max_load", "pairs_dropped", "tile_fill", "grouped_product_passes", "dispatch_fill")}
     assert all(np.ndim(v) == 0 for v in counters.values()) and float(counters["update_pairs_dropped"]) == 0.0
     assert 0.0 < float(counters["update_dispatch_fill"]) <= 1.0

@@ -67,6 +67,7 @@ def test_the_qwen3_next_phase_runs_on_cpu_at_small_widths(tmp_path):
     assert max(trunk["decode_gaps_to_the_full_forward"].values()) < chip_smoke.DECODE_GAP_BOUND
     counters = trunk["counters"]
     assert counters["moe/update_pairs_dropped"] == 0 and counters["moe/rollout_pairs_dropped"] == 0
+    assert counters["lin_attn/rollout_decode_kernel_share"] == 0  # off the chip every step takes the XLA form
     assert 0 < counters["moe/update_dispatch_fill"] <= 1
 
 

@@ -203,6 +203,58 @@ def test_the_mixer_full_agrees_with_step_by_step_through_its_state():
     close(jnp.stack(steps, axis=1), qwen3_next.linear_attention(p, u, spec))
 
 
+def _tiled_sizes():
+    """A small model whose matrix state the decode kernel tiles (key and value heads of 128)."""
+    spec, _ = sizes(layer_types=("linear_attention", "linear_attention", "full_attention"))
+    return qwen3_next.Qwen3NextSpec(**{**spec.__dict__, "linear_key_head_dim": 128, "linear_value_head_dim": 128})
+
+
+def _decode(spec, params, tokens, carry):
+    step = jax.jit(lambda p, c, t: qwen3_next.step(p, spec, c, t))
+    outs = []
+    for t in range(tokens.shape[1]):
+        logits, values, carry, _, counters = step(params, carry, tokens[:, t])
+        outs.append((logits, values))
+    return outs, carry, counters
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernel"])
+def test_the_step_counts_the_layers_whose_rule_took_the_decode_kernel(kernel, monkeypatch):
+    """`lin_attn/decode_kernel_share`, fixed when the step is traced: 0 off the TPU, where every
+    layer takes the XLA form, and 1 where every layer takes the kernel (here forced, in Pallas'
+    interpreter), whose logits, values and carried states are the XLA form's to rounding."""
+    spec = _tiled_sizes()
+    params = qwen3_next.init_params(spec, jax.random.PRNGKey(9))
+    tokens = jax.random.randint(jax.random.PRNGKey(10), (2, 6), 0, spec.vocab_size)
+    expected, expected_carry, counters = _decode(spec, params, tokens, qwen3_next.init_carry(spec, 2))
+    assert counters["lin_attn/decode_kernel_share"] == 0.0  # off the TPU: the XLA form
+    if kernel:
+        monkeypatch.setattr(qwen3_next, "decode_kernel_taken", lambda shape: True)
+        got, carry, counters = _decode(spec, params, tokens, qwen3_next.init_carry(spec, 2))
+        assert counters["lin_attn/decode_kernel_share"] == 1.0
+        for (logits, values), (want_logits, want_values) in zip(got, expected):
+            close(logits, want_logits, 1e-5)
+            close(values, want_values, 1e-5)
+        for name in ("layer_0", "layer_1"):
+            close(carry[name][1], expected_carry[name][1], 1e-5)
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernel"])
+def test_a_fault_planted_under_the_rules_step_reaches_the_decode_step(kernel, monkeypatch):
+    """The seam a planted fault uses: `step` looks `delta_rule_step` up by its module-level name
+    when it is traced, kernel or not, so a wrapper that zeroes the carried state changes what it returns."""
+    spec = _tiled_sizes()
+    monkeypatch.setattr(qwen3_next, "decode_kernel_taken", lambda shape: kernel)
+    params = qwen3_next.init_params(spec, jax.random.PRNGKey(9))
+    tokens = jax.random.randint(jax.random.PRNGKey(10), (2, 6), 0, spec.vocab_size)
+    sound, _, _ = _decode(spec, params, tokens, qwen3_next.init_carry(spec, 2))
+    rule = qwen3_next.delta_rule_step
+    monkeypatch.setattr(qwen3_next, "delta_rule_step", lambda state, *x: rule(jnp.zeros_like(state), *x))
+    zeroed, _, _ = _decode(spec, params, tokens, qwen3_next.init_carry(spec, 2))
+    close(zeroed[0][0], sound[0][0])  # the first token sees a zero state either way
+    assert float(jnp.max(jnp.abs(zeroed[-1][0] - sound[-1][0]))) > 1e-3
+
+
 def test_attention_full_agrees_with_step_by_step_through_its_cache():
     spec, m = sizes()
     p = ref.init_params(m, 1)["layer_1"]["op"]

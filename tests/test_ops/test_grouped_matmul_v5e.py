@@ -1,6 +1,7 @@
 """The grouped matmul kernels compiled for a v5e that is described and not attached, at the
 LFM2 and Moonlight cells' shapes and the tilings `ops/grouped_matmul.py` picks for them, and the
-latent-cache decode kernel (`ops/latent_decode.py`) at the Moonlight cell's: what Pallas'
+latent-cache decode kernel (`ops/latent_decode.py`) at the Moonlight cell's and the delta-rule
+decode kernel (`ops/delta_rule_decode.py`) at the Qwen3-Next cell's: what Pallas'
 interpreter cannot show (a tile Mosaic refuses, more VMEM than the kernels are given, a DMA of
 a slice that is not whole tiles).
 The topology is described inside a fixture, so every worker collects the same tests and
@@ -112,4 +113,29 @@ def test_the_latent_decode_kernel_compiles_for_the_chip_in_the_decode_loop(passe
         text = jax.jit(decode, donate_argnums=0).lower(*shapes).compile().as_text()
     body = next(block for block in text.split("\n\n") if "custom_call_target=\"tpu_custom_call\"" in block)
     whole = [line for line in body.splitlines() if " = f32[64,512,576]{" in line]
+    assert whole and all("get-tuple-element(" in line for line in whole), whole
+
+
+@pytest.mark.parametrize("batch, heads", [(64, 32), (16, 4)], ids=["cell", "smoke"])
+def test_the_delta_rule_decode_kernel_compiles_for_the_chip_in_the_decode_loop(batch, heads, one_chip):
+    """A layer's matrix state ``[batch, heads, 128, 128]`` (the Qwen3-Next cell's, and
+    `chip_smoke.py`'s four value heads) in the loop over 512 decode steps that carries it: inside
+    the loop's body nothing but the kernel's aliased output is a whole state."""
+    from sheeprl_tpu.ops import delta_rule_decode
+
+    width, steps = 128, 512
+
+    def decode(state, q, k, v, g, beta):
+        def body(state, x):
+            out, state = delta_rule_decode.delta_rule_decode(state, *x)
+            return state, out
+
+        return jax.lax.scan(body, state, (q, k, v, g, beta))
+
+    shapes = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip) for s in
+              [(batch, heads, width, width)] + [(steps, batch, heads, width)] * 3 + [(steps, batch, heads)] * 2]
+    with jax.default_matmul_precision("high"):
+        text = jax.jit(decode, donate_argnums=0).lower(*shapes).compile().as_text()
+    body = next(block for block in text.split("\n\n") if "custom_call_target=\"tpu_custom_call\"" in block)
+    whole = [line for line in body.splitlines() if f" = f32[{batch},{heads},{width},{width}]{{" in line]
     assert whole and all("get-tuple-element(" in line for line in whole), whole

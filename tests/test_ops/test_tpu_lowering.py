@@ -139,3 +139,29 @@ def test_the_latent_decode_kernel_lowers_for_tpu_in_a_scan_that_carries_its_cach
         text = jax.jit(decode, donate_argnums=0).trace(*shapes).lower(lowering_platforms=("tpu",)).as_text()
     assert text.count("tpu_custom_call") == 1
     assert re.search(r"output_operand_alias<output_tuple_indices = \[2\], operand_index = 1,", text)
+
+
+def test_the_delta_rule_decode_kernel_lowers_for_tpu_in_a_scan_that_carries_its_state():
+    """The delta-rule decode kernel at the Qwen3-Next cell's shapes (a ``[64, 32, 128, 128]``
+    state, a layer's) inside a jitted ``lax.scan`` over 512 decode steps that carries the state:
+    a Mosaic call whose state operand is aliased to its second output, so that the loop updates
+    the state in place."""
+    import re
+
+    from sheeprl_tpu.ops import delta_rule_decode
+
+    batch, heads, width, steps = 64, 32, 128, 512
+
+    def decode(state, q, k, v, g, beta):
+        def body(state, x):
+            out, state = delta_rule_decode.delta_rule_decode(state, *x)
+            return state, out
+
+        return jax.lax.scan(body, state, (q, k, v, g, beta))
+
+    shapes = [jax.ShapeDtypeStruct(s, jnp.float32) for s in
+              [(batch, heads, width, width)] + [(steps, batch, heads, width)] * 3 + [(steps, batch, heads)] * 2]
+    with jax.default_matmul_precision("high"):
+        text = jax.jit(decode, donate_argnums=0).trace(*shapes).lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert re.search(r"output_operand_alias<output_tuple_indices = \[1\], operand_index = 0,", text)
