@@ -7,14 +7,17 @@ the same shared layers (``models/lm_layers.py``).
 Multi-head latent attention (``q_lora_rank`` null: the query has no low-rank step), a token
 ``u``: ``q = W_q u``, a head's ``[q_nope, q_pe]``; ``[c, k_pe] = W_kva u``, ``c`` normed over
 its ``kv_lora_rank`` channels; rotary embedding on every head's ``q_pe`` and on the ONE
-``k_pe`` all heads share; ``[k_nope_h, v_h] = W_kvb c`` a head; causal softmax of
+``k_pe`` all heads share, unless the spec says ``mla_use_nope`` (NoPE, as Kimi-Linear's latent
+attention layers have it, ``models/kimi_linear.py``: both go unrotated, in both forms and in
+the cache's row, and a score does not depend on positions but through the causal mask);
+``[k_nope_h, v_h] = W_kvb c`` a head; causal softmax of
 ``(q_nope_h . k_nope_h + q_pe_h . k_pe) / sqrt(nope + rope dims)``; ``W_o`` on the heads'
 weighted values. It has two forms that agree to rounding:
 
 - over whole sequences ``[B, T, H]`` (the loss's teacher-forced forward) the EXPANDED form:
   per-head keys and values are made from the latent, as published;
 - one step ``[B, H]`` (the rollout) the ABSORBED form over a LATENT CACHE, the carry's state:
-  a layer keeps, a token, the normed ``c`` and the rotated ``k_pe`` side by side,
+  a layer keeps, a token, the normed ``c`` and the (rotated) ``k_pe`` side by side,
   ``[B, S, kv_lora_rank + qk_rope_head_dim]``, and nothing per head. With ``W_kvb``'s columns
   of head ``h`` split into ``W_uk_h`` and ``W_uv_h``: ``score_h(s) = (W_uk_h^T q_nope_h) . c_s
   + q_pe_h . k_pe_s`` and ``o_h = W_uv_h (sum_s p_h(s) c_s)``: no key or value of a cached
@@ -77,6 +80,7 @@ class DeepseekV3Spec:
     # the expert layer's properties (`lm_layers.expert_layer`)
     router_scoring: str = "sigmoid_bias"
     shared_expert_gate: bool = False  # the shared experts are one ungated SwiGLU
+    mla_use_nope: bool = False  # True: no rotary embedding on q_pe and k_pe (`_latent_inputs`)
 
     def __post_init__(self):
         e0, n = self.experts_held
@@ -175,12 +179,15 @@ def rms_norm(x, weight, eps):
 def _latent_inputs(p, u, positions, spec: DeepseekV3Spec):
     """``u`` ``[B, T, H]`` -> ``q_nope`` ``[B, T, heads, nope]``, ``q_pe`` ``[B, T, heads, rope]``
     (rotated), the normed latent ``c`` ``[B, T, rank]`` and the rotated key ``k_pe``
-    ``[B, T, rope]`` that every head shares."""
+    ``[B, T, rope]`` that every head shares; where ``spec.mla_use_nope``, ``q_pe`` and ``k_pe``
+    as projected."""
     bsz, t, _ = u.shape
     q = (u @ p["wq"]).reshape(bsz, t, spec.num_attention_heads, spec.qk_nope_head_dim + spec.qk_rope_head_dim)
     q_nope, q_pe = q[..., :spec.qk_nope_head_dim], q[..., spec.qk_nope_head_dim:]
     kva = u @ p["w_kva"]
     c = rms_norm(kva[..., :spec.kv_lora_rank], p["kv_norm"], spec.norm_eps)
+    if spec.mla_use_nope:
+        return q_nope, q_pe, c, kva[..., spec.kv_lora_rank:]
     k_pe = rope(kva[..., None, spec.kv_lora_rank:], positions, spec.rope_theta)[..., 0, :]
     return q_nope, rope(q_pe, positions, spec.rope_theta), c, k_pe
 

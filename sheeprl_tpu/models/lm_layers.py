@@ -1,6 +1,6 @@
-"""What the sequence-model trunks (``models/lfm2.py``, ``models/qwen3_next.py``, ``models/deepseek_v3.py``)
-share: the RMS norm's core, RoPE, SwiGLU, the heads, and the sparse expert layer that is TOLD which
-experts it holds, with its dense and grouped paths and its counters.
+"""What the sequence-model trunks (``models/lfm2.py``, ``models/qwen3_next.py``, ``models/deepseek_v3.py``,
+``models/kimi_linear.py``) share: the RMS norm's core, RoPE, SwiGLU, the heads, and the sparse expert
+layer that is TOLD which experts it holds, with its dense and grouped paths and its counters.
 
 The expert layer reads its properties from the trunk's spec: ``num_experts``,
 ``num_experts_per_tok``, ``experts_held = (e0, n)``, ``router_scoring`` (``sigmoid_bias``:

@@ -221,12 +221,13 @@ def decode_kernel_taken(state_shape) -> bool:
 
 def delta_rule_step(state, q, k, v, g, beta):
     """One token: ``state`` ``[B, H, dk, dv]``, ``q``, ``k`` ``[B, H, dk]``, ``v`` ``[B, H, dv]``,
-    ``g``, ``beta`` ``[B, H]`` -> (``o`` ``[B, H, dv]``, the new state). Where
-    `decode_kernel_taken`, one kernel that reads each head's state once and writes it back in
-    place; else the rule's four lines in XLA."""
+    ``g`` ``[B, H]`` (or ``[B, H, dk]``, a decay a key channel that scales the state's rows:
+    `models/kimi_linear.py`'s), ``beta`` ``[B, H]`` -> (``o`` ``[B, H, dv]``, the new state).
+    Where `decode_kernel_taken`, one kernel that reads each head's state once and writes it
+    back in place; else the rule's four lines in XLA."""
     if decode_kernel_taken(state.shape):
         return decode_kernel.delta_rule_decode(state, q, k, v, g, beta, interpret=jax.default_backend() != "tpu")
-    state = state * jnp.exp(g)[..., None, None]
+    state = state * (jnp.exp(g)[..., None, None] if g.ndim == 2 else jnp.exp(g)[..., None])
     read = jnp.einsum("bhkv,bhk->bhv", state, k)
     state = state + k[..., :, None] * (beta[..., None] * (v - read))[..., None, :]
     return jnp.einsum("bhkv,bhk->bhv", state, q), state

@@ -141,16 +141,18 @@ def test_the_latent_decode_kernel_lowers_for_tpu_in_a_scan_that_carries_its_cach
     assert re.search(r"output_operand_alias<output_tuple_indices = \[2\], operand_index = 1,", text)
 
 
-def test_the_delta_rule_decode_kernel_lowers_for_tpu_in_a_scan_that_carries_its_state():
-    """The delta-rule decode kernel at the Qwen3-Next cell's shapes (a ``[64, 32, 128, 128]``
-    state, a layer's) inside a jitted ``lax.scan`` over 512 decode steps that carries the state:
-    a Mosaic call whose state operand is aliased to its second output, so that the loop updates
-    the state in place."""
+@pytest.mark.parametrize("decay", ["a_head", "a_key_channel"])
+def test_the_delta_rule_decode_kernel_lowers_for_tpu_in_a_scan_that_carries_its_state(decay):
+    """The delta-rule decode kernel at the Qwen3-Next and Kimi-Linear cells' shapes (a
+    ``[64, 32, 128, 128]`` state, a layer's; the decay a head's scalar or a key channel's) inside
+    a jitted ``lax.scan`` over 512 decode steps that carries the state: a Mosaic call whose state
+    operand is aliased to its second output, so that the loop updates the state in place."""
     import re
 
     from sheeprl_tpu.ops import delta_rule_decode
 
     batch, heads, width, steps = 64, 32, 128, 512
+    decays = (steps, batch, heads, width) if decay == "a_key_channel" else (steps, batch, heads)
 
     def decode(state, q, k, v, g, beta):
         def body(state, x):
@@ -160,7 +162,7 @@ def test_the_delta_rule_decode_kernel_lowers_for_tpu_in_a_scan_that_carries_its_
         return jax.lax.scan(body, state, (q, k, v, g, beta))
 
     shapes = [jax.ShapeDtypeStruct(s, jnp.float32) for s in
-              [(batch, heads, width, width)] + [(steps, batch, heads, width)] * 3 + [(steps, batch, heads)] * 2]
+              [(batch, heads, width, width)] + [(steps, batch, heads, width)] * 3 + [decays, (steps, batch, heads)]]
     with jax.default_matmul_precision("high"):
         text = jax.jit(decode, donate_argnums=0).trace(*shapes).lower(lowering_platforms=("tpu",)).as_text()
     assert text.count("tpu_custom_call") == 1
