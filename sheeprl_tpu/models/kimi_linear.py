@@ -15,7 +15,8 @@ softplus(W_f_up W_f_down u + dt_bias)``; ``beta = sigmoid(W_b u)``; with ``S_0 =
 ``o_t = S^T q_t / sqrt(dk)``; out ``W_o (RMSNorm_head(o) * sigmoid(W_g_up W_g_down u + b_g))``.
 It has two forms that agree: one step (`kda_step`: the rule's lines, on the TPU the delta-rule
 decode kernel, `ops/delta_rule_decode.py`, with its decay a key channel) and whole sequences
-(`chunk_kda`: chunked, exact, differentiated through).
+(`chunk_kda`: chunked, exact, differentiated through; on the TPU a chunk's pair matrices by the
+pairs kernel, `ops/kda_pairs.py`, forward and backward).
 
 The latent attention is `models/deepseek_v3.py`'s, expanded over whole sequences and absorbed
 one step through a latent cache, with ``mla_use_nope``: no rotary embedding anywhere. The
@@ -46,6 +47,7 @@ import jax.numpy as jnp
 from sheeprl_tpu.models import deepseek_v3, lm_layers, qwen3_next
 from sheeprl_tpu.models.lm_layers import INIT_STD, rms_core, stack_routes, swiglu
 from sheeprl_tpu.models.qwen3_next import CHUNKS_A_TRIP, CONV_TAP_STD, DECAY_RANGE, DT_RANGE, l2_norm, unit_lower_solve
+from sheeprl_tpu.ops import kda_pairs
 
 EXPERT_BIAS_STD = deepseek_v3.EXPERT_BIAS_STD
 # tokens of a sub-chunk of the chunked rule: pairs inside one take their decay as a
@@ -268,12 +270,20 @@ def _decayed_pairs(x, y, since, sub: int):
     return jnp.swapaxes(blocks, -3, -2).reshape(*lead, c, c)
 
 
+def pairs_kernel_taken(shape, sub: int) -> bool:
+    """Whether the chunked rule over ``q``, ``k`` of ``shape`` ``[..., chunk, dk]`` in sub-chunks of
+    ``sub`` makes its two pair matrices with the pairs kernel (`ops/kda_pairs.py`): on the TPU,
+    where the kernel tiles them."""
+    return jax.default_backend() == "tpu" and kda_pairs.supports(shape, sub)
+
+
 def chunk_kda(q, k, v, g, beta, chunk: int, sub: int = SUBCHUNK):
     """Whole sequences: ``q``, ``k``, ``g`` ``[B, T, H, dk]``, ``v`` ``[B, T, H, dv]``, ``beta``
     ``[B, T, H]`` -> ``o`` ``[B, T, H, dv]``, from ``S_0 = 0``. As `qwen3_next.chunk_delta_rule`
     with the decay a key channel: inside a chunk, with ``G_t`` the decay vector accumulated since
     it began, ``A_tj = beta_t sum_c k_tc k_jc exp(G_tc - G_jc)`` (``j < t``) and ``inside_tj =
-    sum_c q_tc k_jc exp(G_tc - G_jc)`` (``j <= t``) by `_decayed_pairs`, ``U = (I + A)^-1 beta V``
+    sum_c q_tc k_jc exp(G_tc - G_jc)`` (``j <= t``) by `_decayed_pairs` (where `pairs_kernel_taken`,
+    both by one kernel that keeps the exponents in VMEM, forward and backward), ``U = (I + A)^-1 beta V``
     and ``W = (I + A)^-1 beta (K e^G)`` (`unit_lower_solve`), all chunks at once; the scan over
     the chunks carries ``S`` alone: ``D = U - W S``; ``o = (Q e^G) S + inside D``;
     ``S <- Diag(e^{G_end}) S + (K e^{G_end - G})^T D``. Exact, with nothing the recurrence lacks."""
@@ -290,8 +300,11 @@ def chunk_kda(q, k, v, g, beta, chunk: int, sub: int = SUBCHUNK):
 
     q, k, v, g, beta = map(chunks, (q, k, v, g, beta))
     since = jnp.cumsum(g, axis=-2)  # log G_t, a key channel each
-    a = jnp.where(jnp.tril(jnp.ones((chunk, chunk), bool), -1), beta[..., None] * _decayed_pairs(k, k, since, sub), 0.0)
-    inside = _decayed_pairs(q, k, since, sub)
+    if pairs_kernel_taken(k.shape, sub):
+        keys, inside = kda_pairs.decayed_pairs(q, k, since, sub, jax.default_backend() != "tpu")
+    else:
+        keys, inside = _decayed_pairs(k, k, since, sub), _decayed_pairs(q, k, since, sub)
+    a = jnp.where(jnp.tril(jnp.ones((chunk, chunk), bool), -1), beta[..., None] * keys, 0.0)
     from_start = jnp.exp(since)
     k_beta = k * beta[..., None]
     solved = unit_lower_solve(a, jnp.concatenate([v * beta[..., None], k_beta * from_start], axis=-1))
@@ -395,8 +408,9 @@ def heads(params, x, spec: KimiLinearSpec):
 
 def forward(params, spec: KimiLinearSpec, tokens):
     """Whole sequences ``tokens`` ``[B, T]`` -> logits ``[B, T, V]``, values ``[B, T]``, the
-    chosen experts ``[B, T, expert layers, k]`` and the layers' counters. Each block is
-    recomputed in a backward pass (``jax.checkpoint``)."""
+    chosen experts ``[B, T, expert layers, k]`` and the layers' counters, with
+    ``kda/pairs_kernel_share`` (the share of the KDA layers whose chunked rule took the pairs
+    kernel). Each block is recomputed in a backward pass (``jax.checkpoint``)."""
     bsz, t = tokens.shape
     with jax.named_scope("embed"):
         x = params["embed"][tokens]
@@ -416,6 +430,9 @@ def forward(params, spec: KimiLinearSpec, tokens):
             routes.append((ids, counters))
     logits, value = heads(params, x, spec)
     ids, counters = stack_routes(routes)
+    if counters is not None and spec.kda_layers:  # fixed when traced; every KDA layer's rule has the same shapes
+        taken = pairs_kernel_taken((spec.chunk_size, spec.linear_head_dim), spec.subchunk)
+        counters["kda/pairs_kernel_share"] = jnp.float32(taken)
     return logits, value, None if ids is None else ids.reshape(bsz, t, *ids.shape[1:]), counters
 
 

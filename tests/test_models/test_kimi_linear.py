@@ -18,6 +18,7 @@ import numpy as np
 import pytest
 
 from sheeprl_tpu.models import deepseek_v3, kimi_linear, lm_layers
+from sheeprl_tpu.ops import kda_pairs
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 REFERENCE = os.path.join(ROOT, "perfbench", "reference", "kimi_linear.py")
@@ -109,14 +110,29 @@ def _kda_inputs(key, t=T, heads=3, dk=8, dv=6, decay=(0.2, 0.999)):
     return q, k, v, g, beta
 
 
+def _pairs_path(kernel: bool, monkeypatch) -> int:
+    """Sends the chunked rule's pairs to the pairs kernel (in Pallas' interpreter) where ``kernel``
+    and it tiles them, else to the XLA form: -> the head width the path needs."""
+    if kernel:
+        monkeypatch.setattr(kimi_linear, "pairs_kernel_taken", kda_pairs.supports)
+        return kda_pairs.LANES
+    assert not kimi_linear.pairs_kernel_taken((16, kda_pairs.LANES), 16)  # off the TPU: the XLA form
+    return 8
+
+
 # tolerances: float32 arithmetic in another order over chunks of up to 64 tokens, the recurrence's
 # 2e-5 on values (as the scalar rule's tests), 1e-4 on gradients, which add up over the sequence
-@pytest.mark.parametrize("t, chunk, sub, decay", [
-    (T, 16, 16, (0.2, 0.999)), (T, 16, 4, (0.2, 0.999)), (5, 16, 4, (0.2, 0.999)), (70, 32, 8, (0.2, 0.999)),
-    (70, 64, 16, (1e-4, 0.9999)), (40, 16, 4, (0.9999, 1.0)), (40, 16, 4, (1e-6, 1e-3))],
-    ids=["ragged", "four_subchunks", "short", "many_chunks", "near_0_beside_near_1", "near_1", "near_0"])
-def test_the_chunked_rule_is_the_recurrence_in_values_and_gradients(t, chunk, sub, decay):
-    inputs = _kda_inputs(jax.random.PRNGKey(3), t=t, decay=decay)
+@pytest.mark.parametrize("t, chunk, sub, decay, kernel", [
+    (T, 16, 16, (0.2, 0.999), False), (T, 16, 4, (0.2, 0.999), False), (5, 16, 4, (0.2, 0.999), False),
+    (70, 32, 8, (0.2, 0.999), False), (70, 64, 16, (1e-4, 0.9999), False), (40, 16, 4, (0.9999, 1.0), False),
+    (40, 16, 4, (1e-6, 1e-3), False), (T, 16, 8, (0.2, 0.999), True), (70, 64, 16, (1e-4, 0.9999), True),
+    (40, 32, 32, (0.9999, 1.0), True), (40, 16, 8, (1e-6, 1e-3), True)],
+    ids=["ragged", "four_subchunks", "short", "many_chunks", "near_0_beside_near_1", "near_1", "near_0",
+         "kernel_ragged", "kernel_near_0_beside_near_1", "kernel_near_1", "kernel_near_0"])
+def test_the_chunked_rule_is_the_recurrence_in_values_and_gradients(t, chunk, sub, decay, kernel, monkeypatch):
+    dk = _pairs_path(kernel, monkeypatch)
+    inputs = _kda_inputs(jax.random.PRNGKey(3), t=t, dk=dk, decay=decay)
+    assert ("pallas_call" in str(jax.make_jaxpr(functools.partial(kimi_linear.chunk_kda, chunk=chunk, sub=sub))(*inputs))) == kernel
     cotangent = jax.random.normal(jax.random.PRNGKey(4), (2, t, 3, 6))
     chunked = lambda *x: jnp.sum(kimi_linear.chunk_kda(*x, chunk, sub) * cotangent)  # noqa: E731
     recurrent = lambda *x: jnp.sum(ref.delta_rule(*x) * cotangent)  # noqa: E731
@@ -143,7 +159,16 @@ def test_the_pairs_of_a_chunk_never_form_an_exponent_over_0():
     """Decays that take a chunk's state to nothing (exp(g) 1e-30 a step in some channels): the
     naive split of the pair's decay into e^{G_t} and e^{-G_j} overflows; the sub-chunked form and
     its gradient stay finite and exact."""
-    q, k, v, g, beta = _kda_inputs(jax.random.PRNGKey(7), t=32)
+    _never_over_0(_kda_inputs(jax.random.PRNGKey(7), t=32))
+
+
+def test_the_pairs_kernel_never_forms_an_exponent_over_0(monkeypatch):
+    """The same decays through the pairs kernel (forced, in Pallas' interpreter), forward and backward."""
+    _never_over_0(_kda_inputs(jax.random.PRNGKey(7), t=32, dk=_pairs_path(True, monkeypatch)))
+
+
+def _never_over_0(inputs):
+    q, k, v, g, beta = inputs
     g = g.at[..., :3].set(np.log(1e-30))
     loss = lambda *x: jnp.sum(jnp.square(kimi_linear.chunk_kda(*x, 32, 8)))  # noqa: E731
     assert not np.all(np.isfinite(np.exp(-np.cumsum(np.asarray(g), axis=1))))  # the naive factor
@@ -209,6 +234,29 @@ def test_prefill_then_decode_logits_agree_with_the_references_full_forward():
     close(full_logits, logits)
     close(full_values, values)
     assert full_ids.shape == (3, T, 3, 4)  # three expert layers: the leading one is dense
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["xla_form", "the_kernel"])
+def test_the_forward_counts_the_share_of_kda_layers_whose_pairs_took_the_kernel(kernel, monkeypatch):
+    """`kda/pairs_kernel_share`, fixed when the forward is traced: 0 off the chip, where every KDA
+    layer's chunked rule takes the XLA form, and 1 where the pairs kernel is forced (in Pallas'
+    interpreter) at shapes it tiles, heads of 128, whose logits and values are the XLA form's to
+    rounding. The loop files it as `kda/update_pairs_kernel_share`."""
+    from sheeprl_tpu.algos.ppo.anakin import _counter_name
+
+    spec = dataclasses.replace(sizes()[0], linear_head_dim=kda_pairs.LANES)
+    params = kimi_linear.init_params(spec, jax.random.PRNGKey(15))
+    tokens = jax.random.randint(jax.random.PRNGKey(16), (2, T), 0, spec.vocab_size)
+    forward = lambda: jax.jit(lambda p, x: kimi_linear.forward(p, spec, x))(params, tokens)  # noqa: E731
+    logits, values, _, counters = forward()
+    assert counters["kda/pairs_kernel_share"] == 0.0
+    if kernel:
+        _pairs_path(True, monkeypatch)
+        kernel_logits, kernel_values, _, counters = forward()
+        assert counters["kda/pairs_kernel_share"] == 1.0
+        close(kernel_logits, logits, 1e-5)
+        close(kernel_values, values, 1e-5)
+    assert _counter_name("update_kda/pairs_kernel_share") == "kda/update_pairs_kernel_share"
 
 
 def test_the_shares_of_a_layer_add_up_to_the_uncut_layer():
@@ -291,4 +339,5 @@ def test_cli_smoke_trains_through_run_anakin(tmp_path):
     assert counters["moe/update_pairs_dropped"][1] == 0 and counters["moe/rollout_pairs_dropped"][1] == 0
     # off the chip no decode step takes a kernel
     assert counters["kda/rollout_decode_kernel_share"][1] == 0 and counters["mla/rollout_decode_kernel_share"][1] == 0
+    assert counters["kda/update_pairs_kernel_share"][1] == 0  # nor the update's chunked rule the pairs kernel
     assert not any(e["event"] == "health" and e.get("status") == "nonfinite" for e in events)

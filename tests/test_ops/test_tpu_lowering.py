@@ -167,3 +167,39 @@ def test_the_delta_rule_decode_kernel_lowers_for_tpu_in_a_scan_that_carries_its_
         text = jax.jit(decode, donate_argnums=0).trace(*shapes).lower(lowering_platforms=("tpu",)).as_text()
     assert text.count("tpu_custom_call") == 1
     assert re.search(r"output_operand_alias<output_tuple_indices = \[1\], operand_index = 0,", text)
+
+
+def test_the_kda_pairs_kernels_lower_for_tpu_under_checkpoint_and_grad_in_the_rules_scope(monkeypatch):
+    """The chunked KDA rule at the Kimi-Linear cell's minibatch (16 sequences of 512 tokens, 32
+    heads of 128, chunks of 64 in sub-chunks of 16) under `jax.checkpoint` and `jax.grad`, as the
+    update runs it, with the backend the chip's (so `kimi_linear.pairs_kernel_taken` holds): one
+    Mosaic call for the recomputed pairs and one for their backward, each named under the rule's
+    `kda_rule` scope (what `perfbench/harness/kl_spans.py` files its device time by), and nothing
+    compiled."""
+    import re
+
+    from sheeprl_tpu.models import kimi_linear
+    from sheeprl_tpu.obs.compile_monitor import compile_snapshot, install_compile_monitor
+
+    batch, tokens, heads, width = 16, 512, 32, 128
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert kimi_linear.pairs_kernel_taken((64, width), 16)
+
+    def loss(*x):
+        def rule(*x):
+            with jax.named_scope("kda_rule"):
+                return kimi_linear.chunk_kda(*x, 64, 16)
+
+        return jnp.sum(jax.checkpoint(rule)(*x))
+
+    install_compile_monitor()
+    before = compile_snapshot()["count"]
+    shapes = [jax.ShapeDtypeStruct(s, jnp.float32) for s in [(batch, tokens, heads, width)] * 4 + [(batch, tokens, heads)]]
+    with jax.default_matmul_precision("high"):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).trace(*shapes).lower(
+            lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert compile_snapshot()["count"] == before
+    names = [re.search(r'#%s = loc\("([^"]*)"' % ref, text).group(1)
+             for ref in re.findall(r"tpu_custom_call.*loc\(#(loc\d+)\)\s*$", text, re.M)]
+    assert len(names) == 2
+    assert sum("/kda_rule/kda_pairs/" in n for n in names) == 1 and sum("/kda_rule/kda_pairs_bwd/" in n for n in names) == 1
