@@ -12,17 +12,16 @@ a step and keeps its own state over the episode. The fused loop asks three thing
 ``aux`` is ``{"route_ids", "counters"}`` where the trunk has expert layers (the experts
 each token chose, and the layers' counters), else empty.
 
-There are four trunks, and ``algo.lm.model_type`` names one (`TRUNKS`): ``lfm2_moe`` (the default; ``models/lfm2.py``,
+There are five trunks, and ``algo.lm.model_type`` names one (`TRUNKS`): ``lfm2_moe`` (the default; ``models/lfm2.py``,
 LFM2-8B-A1B's block: gated short convolutions and grouped-query attention, a sigmoid router with a bias), ``qwen3_next``
-(``models/qwen3_next.py``, Qwen3-Next-80B-A3B's block: gated delta-rule linear attention 3:1 with gated attention, a
-softmax router and a gated shared expert), ``deepseek_v3`` (``models/deepseek_v3.py``, Moonlight-16B-A3B's block:
-multi-head latent attention, decoded in its absorbed form through a latent cache, a sigmoid router with a bias and a
-scale, ungated shared experts) and ``kimi_linear`` (``models/kimi_linear.py``, Kimi-Linear-48B-A3B's block: Kimi delta
-attention, a gated delta rule with a decay a key channel, 3:1 with NoPE latent attention, and that router and shared
-expert). A trunk is a module with ``init_params``, ``init_carry``, ``step`` and ``forward`` and a spec class with
-``from_cfg``; ``algo.lm`` holds its sizes (the published ones are in ``perfbench/configs/``: ``lfm2_8b_a1b_ep4.json``,
-``qwen3_next_80b_a3b_ep16.json``, ``moonlight_16b_a3b_ep8.json``, ``kimi_linear_48b_a3b_ep32.json``). They share
-``models/lm_layers.py``.
+(Qwen3-Next-80B-A3B's: gated delta-rule linear attention 3:1 with gated attention, a softmax router, a gated shared
+expert), ``deepseek_v3`` (Moonlight-16B-A3B's: latent attention decoded absorbed through a latent cache, a sigmoid router
+with a bias and a scale, ungated shared experts), ``kimi_linear`` (Kimi-Linear-48B-A3B's: Kimi delta attention, a gated
+delta rule with a decay a key channel, 3:1 with NoPE latent attention) and ``laguna`` (Laguna-XS.2's: grouped-query
+attention over a sliding window, decoded through a ring cache, 3:1 with YaRN full attention, a gate a head). A trunk is
+a module of ``models/`` (``lfm2.py``, ``qwen3_next.py``, ``deepseek_v3.py``, ``kimi_linear.py``, ``laguna.py``) with
+``init_params``, ``init_carry``, ``step`` and ``forward`` and a spec class with ``from_cfg``; ``algo.lm`` holds its sizes
+(the published ones are in ``perfbench/configs/``). They share ``models/lm_layers.py``.
 """
 
 from __future__ import annotations
@@ -32,10 +31,11 @@ from typing import Any, Dict, Tuple
 
 import jax
 
-from sheeprl_tpu.models import deepseek_v3, kimi_linear, lfm2, qwen3_next
+from sheeprl_tpu.models import deepseek_v3, kimi_linear, laguna, lfm2, qwen3_next
 # `algo.lm.model_type` -> (the trunk's module, its spec)
 TRUNKS = {"lfm2_moe": (lfm2, lfm2.LFM2Spec), "qwen3_next": (qwen3_next, qwen3_next.Qwen3NextSpec),
-          "deepseek_v3": (deepseek_v3, deepseek_v3.DeepseekV3Spec), "kimi_linear": (kimi_linear, kimi_linear.KimiLinearSpec)}
+          "deepseek_v3": (deepseek_v3, deepseek_v3.DeepseekV3Spec), "kimi_linear": (kimi_linear, kimi_linear.KimiLinearSpec),
+          "laguna": (laguna, laguna.LagunaSpec)}
 
 
 @dataclass(frozen=True)

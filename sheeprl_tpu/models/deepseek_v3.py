@@ -44,7 +44,7 @@ import jax
 import jax.numpy as jnp
 
 from sheeprl_tpu.models import lm_layers
-from sheeprl_tpu.models.lm_layers import INIT_STD, rms_core, rope, stack_routes, swiglu
+from sheeprl_tpu.models.lm_layers import INIT_STD, default_frequencies, rms_core, rope, stack_routes, swiglu
 from sheeprl_tpu.ops import latent_decode as decode_kernel
 
 EXPERT_BIAS_STD = 0.05
@@ -188,8 +188,9 @@ def _latent_inputs(p, u, positions, spec: DeepseekV3Spec):
     c = rms_norm(kva[..., :spec.kv_lora_rank], p["kv_norm"], spec.norm_eps)
     if spec.mla_use_nope:
         return q_nope, q_pe, c, kva[..., spec.kv_lora_rank:]
-    k_pe = rope(kva[..., None, spec.kv_lora_rank:], positions, spec.rope_theta)[..., 0, :]
-    return q_nope, rope(q_pe, positions, spec.rope_theta), c, k_pe
+    turn = lambda x: rope(x, positions, default_frequencies(spec.rope_theta, spec.qk_rope_head_dim))  # noqa: E731
+    k_pe = turn(kva[..., None, spec.kv_lora_rank:])[..., 0, :]
+    return q_nope, turn(q_pe), c, k_pe
 
 
 def _score_scale(spec: DeepseekV3Spec) -> float:

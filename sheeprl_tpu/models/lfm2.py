@@ -37,7 +37,7 @@ import jax.numpy as jnp
 from sheeprl_tpu.models import lm_layers
 from sheeprl_tpu.models.lm_layers import (  # noqa: F401  (the names this trunk's tests and callers know it by)
     DENSE_TOKENS, INIT_STD, WEIGHT_SUM_EPS, _gmm_tpu, _warn_dense_groups, grouped_matmul, kernel_passes,
-    attend, matmul_passes, rms_core, rope, stack_routes, swiglu, tile_fill,
+    attend, default_frequencies, matmul_passes, rms_core, rope, stack_routes, swiglu, tile_fill,
 )
 
 EXPERT_BIAS_STD = 0.05
@@ -186,7 +186,8 @@ def _qkv(p, u, positions, spec: LFM2Spec):
     q = rms_norm((u @ p["wq"]).reshape(bsz, t, nq, d), p["q_norm"], spec.norm_eps)
     k = rms_norm((u @ p["wk"]).reshape(bsz, t, nkv, d), p["k_norm"], spec.norm_eps)
     v = (u @ p["wv"]).reshape(bsz, t, nkv, d)
-    return rope(q, positions, spec.rope_theta), rope(k, positions, spec.rope_theta), v
+    turn = lambda x: rope(x, positions, default_frequencies(spec.rope_theta, d))  # noqa: E731
+    return turn(q), turn(k), v
 
 
 def attention(p, u, spec: LFM2Spec):
@@ -197,12 +198,12 @@ def attention(p, u, spec: LFM2Spec):
 
 def attention_step(p, cache, u, t, spec: LFM2Spec):
     """One step ``[B, H]`` at position ``t``: write this step's key and value into the
-    cache ``[B, S, nkv, d]``, attend over the positions up to ``t``."""
+    cache ``[B, S, nkv, d]``, attend over the positions up to ``t``. The cache's step is
+    `lm_layers.kv_cache_step`, which the full-attention layers of the `laguna` trunk take
+    too."""
     q, k, v = _qkv(p, u[:, None], t[None], spec)
-    keys = jax.lax.dynamic_update_slice_in_dim(cache[0], k, t, axis=1)
-    values = jax.lax.dynamic_update_slice_in_dim(cache[1], v, t, axis=1)
-    mask = (jnp.arange(keys.shape[1]) <= t)[None]
-    return attend(q, keys, values, mask, spec.num_key_value_heads)[:, 0] @ p["wo"], (keys, values)
+    out, cache = lm_layers.kv_cache_step(cache, q, k, v, t, spec.num_key_value_heads)
+    return out[:, 0] @ p["wo"], cache
 
 
 # -- the expert layer (`models/lm_layers.py`), under the names this trunk is known by -----

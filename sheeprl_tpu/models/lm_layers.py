@@ -1,5 +1,6 @@
 """What the sequence-model trunks (``models/lfm2.py``, ``models/qwen3_next.py``, ``models/deepseek_v3.py``,
-``models/kimi_linear.py``) share: the RMS norm's core, RoPE, SwiGLU, the heads, and the sparse expert
+``models/kimi_linear.py``, ``models/laguna.py``) share: the RMS norm's core, RoPE with its two frequency laws
+(the default and YaRN's), attention and its KV cache's decode step, SwiGLU, the heads, and the sparse expert
 layer that is TOLD which experts it holds, with its dense and grouped paths and its counters.
 
 The expert layer reads its properties from the trunk's spec: ``num_experts``,
@@ -49,17 +50,49 @@ def rotate_half(x):
     return jnp.concatenate([-b, a], axis=-1)
 
 
-def rope(x, positions, theta, rotary_dim=None):
-    """``x`` ``[..., T, heads, d]`` at ``positions`` ``[T]``: rotate-half over the first
-    ``rotary_dim`` of each head (the whole head: None), the rest untouched."""
-    d = x.shape[-1] if rotary_dim is None else rotary_dim
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    angles = positions.astype(jnp.float32)[:, None] * inv[None]
+def default_frequencies(theta, dim: int):
+    """RoPE's inverse frequencies over ``dim`` rotated channels: ``theta^(-2i / dim)`` a channel pair ``i``."""
+    return 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+
+
+def yarn_frequencies(rope_parameters, dim: int):
+    """YaRN over ``dim`` rotated channels, from a layer kind's ``rope_parameters`` (``rope_theta``,
+    ``factor``, ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``, ``attention_factor``)
+    -> (the inverse frequencies ``[dim / 2]``, the attention factor). As the transformers library's
+    ``_compute_yarn_parameters``: ``inv = inv_interp (1 - e) + inv_extrap e`` with ``inv_extrap`` the
+    default law's and ``inv_interp = inv_extrap / factor``, ``e = 1 - ramp(low, high)`` over the pairs,
+    ``low`` and ``high`` the floor and the ceiling of the channels at which ``beta_fast`` and
+    ``beta_slow`` rotations fit in ``original_max_position_embeddings`` positions; the factor, where
+    none is given, is ``0.1 ln(factor) + 1``."""
+    theta, factor = float(rope_parameters["rope_theta"]), float(rope_parameters["factor"])
+    positions = float(rope_parameters["original_max_position_embeddings"])
+
+    def correction(rotations):
+        return dim * math.log(positions / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(correction(float(rope_parameters.get("beta_fast", 32)))), 0)
+    high = min(math.ceil(correction(float(rope_parameters.get("beta_slow", 1)))), dim - 1)
+    pos_freqs = theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low) / ((high - low) or 0.001), 0.0, 1.0)
+    extrapolated = 1.0 - ramp
+    inv = 1.0 / (factor * pos_freqs) * (1.0 - extrapolated) + 1.0 / pos_freqs * extrapolated
+    attention_factor = rope_parameters.get("attention_factor") or 0.1 * math.log(factor) + 1.0
+    return inv, float(attention_factor)
+
+
+def rope(x, positions, inv_freq, attention_factor=1.0):
+    """``x`` ``[..., T, heads, d]`` at ``positions`` ``[T]``: rotate-half over the first ``2 len(inv_freq)``
+    channels of each head at the inverse frequencies ``inv_freq`` (`default_frequencies` for the default law,
+    `yarn_frequencies` for YaRN's), the rest untouched; ``attention_factor`` (YaRN's; 1 for the default law)
+    scales the rotated channels' cos and sin, and only theirs."""
+    d = 2 * inv_freq.shape[0]
+    angles = positions.astype(jnp.float32)[:, None] * inv_freq[None]
     angles = jnp.concatenate([angles, angles], axis=-1)[:, None, :]
+    scaled = (lambda c: c) if attention_factor == 1.0 else (lambda c: c * attention_factor)  # noqa: E731
     if d == x.shape[-1]:
-        return x * jnp.cos(angles) + rotate_half(x) * jnp.sin(angles)
+        return x * scaled(jnp.cos(angles)) + rotate_half(x) * scaled(jnp.sin(angles))
     turned, kept = x[..., :d], x[..., d:]
-    return jnp.concatenate([turned * jnp.cos(angles) + rotate_half(turned) * jnp.sin(angles), kept], axis=-1)
+    return jnp.concatenate([turned * scaled(jnp.cos(angles)) + rotate_half(turned) * scaled(jnp.sin(angles)), kept], axis=-1)
 
 
 def attend(q, k, v, mask, num_kv_heads: int):
@@ -71,6 +104,16 @@ def attend(q, k, v, mask, num_kv_heads: int):
     scores = jnp.einsum("bqkgd,bskd->bkgqs", q, k) / jnp.sqrt(jnp.float32(d))
     probs = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
     return jnp.einsum("bkgqs,bskd->bqkgd", probs, v).reshape(bsz, tq, nq * d)
+
+
+def kv_cache_step(cache, q, k, v, t, num_kv_heads: int):
+    """One decode step at position ``t`` through a KV cache ``(keys, values)`` ``[B, S, nkv, d]``:
+    this step's ``k``, ``v`` ``[B, 1, nkv, d]`` written at row ``t``, ``q`` ``[B, 1, nq, d]``
+    attending over the rows up to ``t`` -> (``[B, 1, nq * d]``, the new cache)."""
+    keys = jax.lax.dynamic_update_slice_in_dim(cache[0], k, t, axis=1)
+    values = jax.lax.dynamic_update_slice_in_dim(cache[1], v, t, axis=1)
+    mask = (jnp.arange(keys.shape[1]) <= t)[None]
+    return attend(q, keys, values, mask, num_kv_heads), (keys, values)
 
 
 def swiglu(p, u):

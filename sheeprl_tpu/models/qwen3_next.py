@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from sheeprl_tpu.models import lm_layers
-from sheeprl_tpu.models.lm_layers import INIT_STD, attend, rms_core, rope, stack_routes
+from sheeprl_tpu.models.lm_layers import INIT_STD, attend, default_frequencies, rms_core, rope, stack_routes
 from sheeprl_tpu.ops import delta_rule_decode as decode_kernel
 
 CONV_TAP_STD = 0.3
@@ -387,7 +387,7 @@ def _qkv_gate(p, u, positions, spec: Qwen3NextSpec):
     q = rms_norm(q, p["q_norm"], spec.norm_eps)
     k = rms_norm((u @ p["wk"]).reshape(bsz, t, nkv, d), p["k_norm"], spec.norm_eps)
     v = (u @ p["wv"]).reshape(bsz, t, nkv, d)
-    turn = lambda x: rope(x, positions, spec.rope_theta, spec.rotary_dim)  # noqa: E731
+    turn = lambda x: rope(x, positions, default_frequencies(spec.rope_theta, spec.rotary_dim))  # noqa: E731
     return turn(q), turn(k), v, gate.reshape(bsz, t, nq * d)
 
 

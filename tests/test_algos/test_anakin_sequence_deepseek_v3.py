@@ -136,7 +136,7 @@ def test_the_policy_takes_the_trunk_its_configuration_names():
     from sheeprl_tpu.config import compose
     from sheeprl_tpu.models import deepseek_v3
 
-    assert sorted(sequence_policy.TRUNKS) == ["deepseek_v3", "kimi_linear", "lfm2_moe", "qwen3_next"]
+    assert sorted(sequence_policy.TRUNKS) == ["deepseek_v3", "kimi_linear", "laguna", "lfm2_moe", "qwen3_next"]
     policy, params = anakin.build_sequence_policy(compose(TOY), 32, jax.random.PRNGKey(0))
     assert policy.trunk is deepseek_v3 and policy.spec.routed_scaling_factor == 2.446 and policy.spec.max_seq_len == 40
     assert policy.spec.shared_expert and not policy.spec.shared_expert_gate and "shared_gate" not in params["layer_1"]["ffn"]

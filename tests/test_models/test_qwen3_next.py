@@ -269,10 +269,10 @@ def test_attention_full_agrees_with_step_by_step_through_its_cache():
 def test_rope_turns_a_quarter_of_each_head_and_the_gate_is_the_heads_second_half():
     spec, m = sizes()
     x = jax.random.normal(jax.random.PRNGKey(6), (1, T, 4, 16))
-    turned = lm_layers.rope(x, jnp.arange(T), spec.rope_theta, spec.rotary_dim)
+    turned = lm_layers.rope(x, jnp.arange(T), lm_layers.default_frequencies(spec.rope_theta, spec.rotary_dim))
     assert np.array_equal(turned[..., 4:], x[..., 4:]) and not np.allclose(turned[:, 1:, :, :4], x[:, 1:, :, :4])
     close(turned, ref.rope(x, m["rope_theta"], 4))
-    close(lm_layers.rope(x, jnp.arange(T), 1e6), lm_layers.rope(x, jnp.arange(T), 1e6, 16))  # the whole head: LFM2's
+    close(lm_layers.rope(x, jnp.arange(T), lm_layers.default_frequencies(1e6, 16)), ref.rope(x, 1e6, 16))  # the whole head: LFM2's
 
 
 def test_prefill_then_decode_logits_agree_with_the_references_full_forward():
